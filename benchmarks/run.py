@@ -13,42 +13,9 @@ import os
 import time
 from typing import Callable, List
 
+from compile_cache import CACHE_DIR, setup_compile_cache
+
 RESULTS = os.path.join(os.path.dirname(__file__), "results")
-
-#: persistent XLA compilation cache shared by the harness and the mega
-#: subprocess lanes; repeat bench runs (and CI re-runs restoring the dir
-#: from the actions cache) skip recompilation entirely
-CACHE_DIR = os.environ.get(
-    "BENCH_COMPILE_CACHE_DIR",
-    os.environ.get(                 # honor a pre-set jax cache knob so the
-        "JAX_COMPILATION_CACHE_DIR",  # hit/miss accounting counts the dir
-        os.path.join(os.path.dirname(__file__), ".jax_cache")))  # in use
-
-
-def _compile_cache_env(env: dict) -> dict:
-    """Child-process env wiring for the persistent compilation cache.
-
-    The cache dir is forced (not defaulted) so children always compile
-    into the SAME directory the parent's hit/miss accounting counts,
-    even when the surrounding environment already exports a different
-    ``JAX_COMPILATION_CACHE_DIR`` (which ``CACHE_DIR`` honors anyway
-    when ``BENCH_COMPILE_CACHE_DIR`` is unset).
-    """
-    env["JAX_COMPILATION_CACHE_DIR"] = CACHE_DIR
-    env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
-    env.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")
-    return env
-
-
-def _setup_compile_cache() -> None:
-    """Point this process's jax at the persistent compilation cache."""
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:  # noqa: BLE001 - cache is an optimization only
-        pass
 
 
 def _cache_entries() -> int:
@@ -323,7 +290,7 @@ def read_history(bench: str = None) -> List[dict]:
 
 # grid for the mega_sweep bench: ~1.57e6 points per structural variant,
 # ~1.26e7 across the 5 Ed-Gaze + 3 Rhythmic variants
-_MEGA_GRIDS = {
+MEGA_GRIDS = {
     "cis_node": [130., 110., 90., 80., 65., 55., 45., 40., 32., 28., 22.,
                  16., 14.],
     "soc_node": [14., 22., 28.],
@@ -338,55 +305,70 @@ _MEGA_GRIDS = {
 _MEGA_CHILD = r"""
 import json, os, sys
 n_dev = int(sys.argv[1])
-# the lanes measure HOST-CPU device scaling by design, so pin the cpu
-# platform (accelerators ignore the forced host count); keep any other
-# operator XLA flags, replacing only a stale forced count
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# the forced-host-device lanes measure HOST-CPU device scaling by design,
+# so pin the cpu platform (they run only where no accelerator is in
+# use); keep any other operator XLA flags, replacing only a stale
+# forced count
+os.environ["JAX_PLATFORMS"] = "cpu"
 flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
          if not f.startswith("--xla_force_host_platform_device_count")]
 os.environ["XLA_FLAGS"] = " ".join(
     flags + [f"--xla_force_host_platform_device_count={n_dev}"])
+sys.path.insert(0, sys.argv[2])
 import jax
-from repro.core.shard_sweep import stream_cache_info
-from repro.explore import DesignSpace, explore
-assert len(jax.devices()) == n_dev, (
-    f"lane wants {n_dev} host devices, jax sees {jax.devices()}; "
-    f"is JAX_PLATFORMS overridden to an accelerator?")
-grids = json.loads(os.environ["MEGA_GRIDS_JSON"])
-# ONE banked call: every Ed-Gaze + Rhythmic variant rides one fused
-# step+merge executable (PlanBank + on-device grid decode)
-s = explore(DesignSpace(["edgaze", "rhythmic"], grids), engine="fused",
-            chunk_size=1 << 18, k=3)
-info = stream_cache_info()
-best = {}
-for r in s.topk:                       # full rows, global top-k order
-    best.setdefault(r["algorithm"], r)
-for algo, rec in s.best_by_algorithm().items():
-    # an algorithm may miss the global top-k entirely
-    sm = rec["summary"]
-    if algo in best or sm["argmin_point"] is None:
-        continue
-    # re-score the argmin point through the per-plan evaluator so the
-    # fallback row carries the same full output schema as top-k rows
-    from repro.core.batch import evaluate_batch, make_points
-    from repro.core.sweep import lower_variant
-    plan = lower_variant(algo, rec["variant"])
-    out = evaluate_batch(plan, make_points(
-        plan, 1, **{ax: [val] for ax, val in sm["argmin_point"].items()}))
-    best[algo] = dict(variant=rec["variant"], algorithm=algo,
-                      index=sm["argmin_index"], **sm["argmin_point"],
-                      **{key: float(val[0]) for key, val in out.items()})
-out = {"n_devices": n_dev, "n_points": s.n_points,
-       "n_feasible": s.n_feasible, "n_variants": s.n_variants,
-       "eval_s": s.eval_s, "compile_s": s.compile_s,
-       "points_per_sec": s.points_per_sec,
-       "step_compiles": info["step_compiles"],
-       "engine": s.engine, "dispatches": s.dispatches,
-       "superchunk": s.superchunk, "occupancy": round(s.occupancy, 6),
-       "backend": s.backend, "kernel_mode": s.stream_result.kernel_mode,
-       "topk": list(best.values())}
-print("MEGA_JSON:" + json.dumps(out))
+from run import _mega_lane, setup_compile_cache
+assert len(jax.devices()) == n_dev, (n_dev, jax.devices())
+setup_compile_cache()
+lane = _mega_lane(json.loads(os.environ["MEGA_GRIDS_JSON"]), n_dev)
+print("MEGA_JSON:" + json.dumps(lane))
 """
+
+
+def _mega_lane(grids: dict, n_dev: int) -> dict:
+    """One mega-sweep lane on the first ``n_dev`` devices, in-process.
+
+    ONE banked call: every Ed-Gaze + Rhythmic variant rides one fused
+    step+merge executable (PlanBank + on-device grid decode).
+    """
+    import jax
+    from repro.core.batch import evaluate_batch, make_points
+    from repro.core.shard_sweep import stream_cache_info
+    from repro.core.sweep import lower_variant
+    from repro.explore import DesignSpace, explore
+    from repro.launch.mesh import make_batch_mesh
+    before = stream_cache_info()["step_compiles"]
+    s = explore(DesignSpace(["edgaze", "rhythmic"], grids),
+                engine="fused", chunk_size=1 << 18, k=3,
+                mesh=make_batch_mesh(n_dev))
+    best = {}
+    for r in s.topk:                       # full rows, global top-k order
+        best.setdefault(r["algorithm"], r)
+    for algo, rec in s.best_by_algorithm().items():
+        # an algorithm may miss the global top-k entirely
+        sm = rec["summary"]
+        if algo in best or sm["argmin_point"] is None:
+            continue
+        # re-score the argmin point through the per-plan evaluator so the
+        # fallback row carries the same full output schema as top-k rows
+        plan = lower_variant(algo, rec["variant"])
+        out = evaluate_batch(plan, make_points(
+            plan, 1,
+            **{ax: [val] for ax, val in sm["argmin_point"].items()}))
+        best[algo] = dict(variant=rec["variant"], algorithm=algo,
+                          index=sm["argmin_index"], **sm["argmin_point"],
+                          **{key: float(val[0]) for key, val in out.items()})
+    dev = jax.devices()[0]
+    return {"n_devices": n_dev, "platform": dev.platform,
+            "device_kind": dev.device_kind, "n_points": s.n_points,
+            "n_feasible": s.n_feasible, "n_variants": s.n_variants,
+            "eval_s": s.eval_s, "compile_s": s.compile_s,
+            "points_per_sec": s.points_per_sec,
+            "step_compiles": stream_cache_info()["step_compiles"] - before,
+            "engine": s.engine, "dispatches": s.dispatches,
+            "superchunk": s.superchunk,
+            "occupancy": round(s.occupancy, 6), "backend": s.backend,
+            "kernel_mode": s.stream_result.kernel_mode,
+            "topk": list(best.values())}
 
 
 #: tcmalloc locations probed by the tuned host-CPU lane (Debian/Ubuntu
@@ -433,40 +415,51 @@ def _tuned_host_env(env: dict) -> bool:
 def mega_sweep(emit_json: bool = True) -> List[str]:
     """Streaming mega-sweep: >=1e7 Ed-Gaze + Rhythmic points, sharded.
 
-    Runs the full grid twice in subprocesses — once on 1 device and once
-    on 8 forced-host devices (the device-count XLA flag must precede jax
-    init) — and records warm points/sec, the device-scaling ratio, the
-    one-executable compile split (``mega_step_compiles`` must stay 1) and
-    the persistent compilation-cache traffic.  Scale down with
-    MEGA_SWEEP_GRIDS_JSON for smoke runs.
+    On a TPU every lane runs in this process, because a chip belongs to
+    one process at a time: one lane on 1 chip and, where more are
+    visible, one on all of them.  Elsewhere the grid runs in two CPU
+    subprocesses, once on 1 device and once on 8 forced host devices
+    (the device-count XLA flag must precede jax init); those host lanes
+    are skipped on a TPU, with the reason printed.  Records warm
+    points/sec, the device-scaling ratio, the one-executable compile
+    split (``mega_step_compiles`` must stay 1) and the persistent
+    compilation-cache traffic.  Scale down with MEGA_SWEEP_GRIDS_JSON
+    for smoke runs.
 
     Every history row is backend-tagged (``backend`` / ``kernel_mode``
-    from the children's resolved sweep backend — ``REPRO_SWEEP_BACKEND``
-    propagates to the lanes), and when the resolved lane is XLA an extra
-    1-device Pallas-lane child runs for the cross-backend speedup column
+    from the lanes' resolved sweep backend — ``REPRO_SWEEP_BACKEND``
+    propagates to the host lanes) and names its platform and device
+    kind.  When the resolved host lane is XLA an extra 1-device
+    Pallas-lane child runs for the cross-backend speedup column
     (``mega_xla_speedup_1dev``).  ``BENCH_TUNED_HOST=1`` applies the
     tuned host-CPU recipe (tcmalloc LD_PRELOAD + pinned 32-bit dtype;
-    see ``_tuned_host_env``) to all lanes, recorded as ``tuned_host``.
+    see ``_tuned_host_env``) to the host lanes, recorded as
+    ``tuned_host``.
     """
     import subprocess
     import sys
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
-    env = _compile_cache_env(dict(
+    import jax
+    from repro.kernels import on_tpu
+    grids = json.loads(os.environ.get("MEGA_SWEEP_GRIDS_JSON",
+                                      json.dumps(MEGA_GRIDS)))
+    here = os.path.dirname(os.path.abspath(__file__))
+    src = os.path.join(here, "..", "src")
+    env = dict(
         os.environ,
         PYTHONPATH=os.pathsep.join([src, os.environ.get("PYTHONPATH", "")]),
-        MEGA_GRIDS_JSON=os.environ.get("MEGA_SWEEP_GRIDS_JSON",
-                                       json.dumps(_MEGA_GRIDS))))
+        MEGA_GRIDS_JSON=json.dumps(grids))
     tuned = False
     if os.environ.get("BENCH_TUNED_HOST", "") not in ("", "0"):
-        tuned = _tuned_host_env(env)
+        tuned = not on_tpu() and _tuned_host_env(env)
         if not tuned:
-            print("mega_sweep: BENCH_TUNED_HOST set but no libtcmalloc "
-                  "found; lanes run untuned", flush=True)
+            print("mega_sweep: BENCH_TUNED_HOST set but the host lanes do "
+                  "not run here (TPU) or no libtcmalloc was found; lanes "
+                  "run untuned", flush=True)
 
     def _lane(n_dev, extra_env=None):
         lane_env = dict(env, **(extra_env or {}))
         proc = subprocess.run([sys.executable, "-c", _MEGA_CHILD,
-                               str(n_dev)], env=lane_env,
+                               str(n_dev), here], env=lane_env,
                               capture_output=True, text=True, timeout=3600)
         assert proc.returncode == 0, proc.stderr[-3000:]
         line = [ln for ln in proc.stdout.splitlines()
@@ -474,41 +467,50 @@ def mega_sweep(emit_json: bool = True) -> List[str]:
         return json.loads(line[len("MEGA_JSON:"):])
 
     lanes = {}
+    pallas_ref = None
     cache = {"dir": CACHE_DIR, "entries_before": _cache_entries()}
-    for n_dev in (1, 8):
-        lanes[n_dev] = _lane(n_dev)
-    # cross-backend reference: when the resolved lane is XLA, time the
-    # Pallas lane once (1 device) so the history quantifies the compiled
-    # backend's win on THIS host/grid instead of asserting it blind
-    pallas_ref = (_lane(1, {"REPRO_SWEEP_BACKEND": "pallas"})
-                  if lanes[1]["backend"] == "xla" else None)
+    if on_tpu():
+        print("mega_sweep: the forced 8-host-device lanes are skipped: "
+              "they pin JAX_PLATFORMS=cpu in child processes, and on a "
+              "TPU the chip stays with this process", flush=True)
+        n_all = len(jax.devices())
+        for n_dev in sorted({1, n_all}):
+            lanes[n_dev] = _mega_lane(grids, n_dev)
+    else:
+        for n_dev in (1, 8):
+            lanes[n_dev] = _lane(n_dev)
+        # cross-backend reference: when the resolved lane is XLA, time
+        # the Pallas lane once (1 device) so the history quantifies the
+        # compiled backend's win on THIS host/grid
+        if lanes[1]["backend"] == "xla":
+            pallas_ref = _lane(1, {"REPRO_SWEEP_BACKEND": "pallas"})
     cache["entries_after"] = _cache_entries()
     cache["new_entries"] = cache["entries_after"] - cache["entries_before"]
     # 0 new entries on a re-run == every XLA compile was a cache hit
     cache["hit"] = bool(cache["entries_before"]
                         and cache["new_entries"] == 0)
-    scaling = lanes[8]["points_per_sec"] / lanes[1]["points_per_sec"]
-    rec = {"backend": lanes[8]["backend"],
-           "kernel_mode": lanes[8]["kernel_mode"],
+    top = max(lanes)
+    lane = lanes[top]
+    rec = {"backend": lane["backend"], "kernel_mode": lane["kernel_mode"],
+           "platform": lane["platform"], "device_kind": lane["device_kind"],
            "tuned_host": tuned,
-           "mega_n_points": lanes[8]["n_points"],
-           "mega_n_feasible": lanes[8]["n_feasible"],
-           "mega_n_variants": lanes[8]["n_variants"],
-           "mega_points_per_sec_1dev": round(lanes[1]["points_per_sec"]),
-           "mega_points_per_sec_8dev": round(lanes[8]["points_per_sec"]),
-           "mega_eval_s_1dev": round(lanes[1]["eval_s"], 2),
-           "mega_eval_s_8dev": round(lanes[8]["eval_s"], 2),
-           "mega_compile_s_1dev": round(lanes[1]["compile_s"], 2),
-           "mega_compile_s_8dev": round(lanes[8]["compile_s"], 2),
-           "mega_step_compiles": lanes[8]["step_compiles"],
-           "mega_engine": lanes[8]["engine"],
-           "mega_dispatches_1dev": lanes[1]["dispatches"],
-           "mega_dispatches_8dev": lanes[8]["dispatches"],
-           "mega_superchunk_8dev": lanes[8]["superchunk"],
-           "mega_occupancy_8dev": lanes[8]["occupancy"],
-           "mega_device_scaling_8v1": round(scaling, 2),
-           "mega_compile_cache": cache,
-           "mega_best": lanes[8]["topk"]}
+           "mega_n_points": lane["n_points"],
+           "mega_n_feasible": lane["n_feasible"],
+           "mega_n_variants": lane["n_variants"]}
+    for n_dev, ln in lanes.items():
+        rec[f"mega_points_per_sec_{n_dev}dev"] = round(ln["points_per_sec"])
+        rec[f"mega_eval_s_{n_dev}dev"] = round(ln["eval_s"], 2)
+        rec[f"mega_compile_s_{n_dev}dev"] = round(ln["compile_s"], 2)
+        rec[f"mega_dispatches_{n_dev}dev"] = ln["dispatches"]
+    rec.update({"mega_step_compiles": lane["step_compiles"],
+                "mega_engine": lane["engine"],
+                f"mega_superchunk_{top}dev": lane["superchunk"],
+                f"mega_occupancy_{top}dev": lane["occupancy"]})
+    scaling = None
+    if top > 1:
+        scaling = lane["points_per_sec"] / lanes[1]["points_per_sec"]
+        rec[f"mega_device_scaling_{top}v1"] = round(scaling, 2)
+    rec.update({"mega_compile_cache": cache, "mega_best": lane["topk"]})
     if pallas_ref is not None:
         xla_speedup = (lanes[1]["points_per_sec"]
                        / pallas_ref["points_per_sec"])
@@ -522,20 +524,21 @@ def mega_sweep(emit_json: bool = True) -> List[str]:
                         {k: v for k, v in rec.items()
                          if k not in ("mega_best", "mega_compile_cache")},
                         devices=sorted(lanes))
-    n = lanes[8]["n_points"]
     xla_col = (f" xla_speedup={rec['mega_xla_speedup_1dev']:.2f}x"
                if pallas_ref is not None else "")
-    return [f"mega_sweep,{lanes[8]['eval_s']*1e6:.0f},points={n}"
+    scaling_col = f" scaling={scaling:.2f}x" if scaling is not None else ""
+    return [f"mega_sweep,{lane['eval_s']*1e6:.0f},points={lane['n_points']}"
+            f" device={lane['platform']}:{lane['device_kind']}"
             f" backend={rec['backend']}"
             f" mode={rec['kernel_mode']}"
             f" tuned_host={tuned}"
-            f" pps_1dev={lanes[1]['points_per_sec']:,.0f}"
-            f" pps_8dev={lanes[8]['points_per_sec']:,.0f}"
-            f" scaling={scaling:.2f}x{xla_col}"
-            f" compile_8dev={lanes[8]['compile_s']:.2f}s"
-            f" executables={lanes[8]['step_compiles']}"
-            f" dispatches={lanes[8]['dispatches']}"
-            f" occupancy={lanes[8]['occupancy']:.3f}"
+            + "".join(f" pps_{n}dev={ln['points_per_sec']:,.0f}"
+                      for n, ln in lanes.items())
+            + f"{scaling_col}{xla_col}"
+            f" compile_{top}dev={lane['compile_s']:.2f}s"
+            f" executables={lane['step_compiles']}"
+            f" dispatches={lane['dispatches']}"
+            f" occupancy={lane['occupancy']:.3f}"
             f" cache_hit={cache['hit']}"]
 
 
@@ -714,7 +717,13 @@ def campaign_parallel(emit_json: bool = True) -> List[str]:
     from repro.campaign import CampaignOptions, run_campaign
     from repro.core.shard_sweep import stream_cache_clear
     from repro.explore import DesignSpace, explore
+    from repro.kernels import on_tpu
 
+    if on_tpu():
+        reason = ("workers=2 spawns a process per worker, and a TPU "
+                  "belongs to one process")
+        print(f"campaign_parallel: skipped: {reason}", flush=True)
+        return [f"campaign_parallel,0,skipped ({reason})"]
     grids = json.loads(os.environ.get("CAMPAIGN_PARALLEL_GRIDS_JSON",
                                       json.dumps(_PARALLEL_GRIDS)))
     space = DesignSpace(["edgaze"], grids)
@@ -998,14 +1007,18 @@ environment knobs:
                          aggregate served-requests/s floor over the
                          sequential solo baseline (default 1.2),
                          asserted only on the default lane.
-  BENCH_COMPILE_CACHE_DIR
-                         persistent XLA compile cache location.
+  JAX_COMPILATION_CACHE_DIR
+                         persistent XLA compile cache location (default
+                         benchmarks/.jax_cache).
 """
 
 
 def main(argv: List[str] = None) -> None:
     """Run all benches, or only those named on the command line
-    (``python benchmarks/run.py mega_sweep design_sweep``)."""
+    (``python benchmarks/run.py mega_sweep design_sweep``).
+
+    A bench that raises prints an ``ERROR`` row and the others still
+    run, but the process then exits 1."""
     import argparse
     parser = argparse.ArgumentParser(
         description=__doc__.splitlines()[0],
@@ -1019,14 +1032,18 @@ def main(argv: List[str] = None) -> None:
     unknown = [n for n in names if n not in by_name]
     if unknown:
         parser.error(f"unknown benches {unknown}; valid: {sorted(by_name)}")
-    _setup_compile_cache()
+    setup_compile_cache()
     print("name,us_per_call,derived")
+    failed = []
     for bench in ([by_name[n] for n in names] or BENCHES):
         try:
             for row in bench():
                 print(row)
         except Exception as e:  # noqa: BLE001
+            failed.append(bench.__name__)
             print(f"{bench.__name__},0,ERROR {type(e).__name__}: {e}")
+    if failed:
+        raise SystemExit(f"benches raised: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

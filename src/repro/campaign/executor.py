@@ -322,10 +322,13 @@ def _worker_main(conn, init: Dict) -> None:
     """
     try:
         from ..core.shard_sweep import _prepare_stream, stream_cache_info
-        from ..kernels.runtime import init_worker_process
+        from ..kernels.runtime import reset_backend_cache
         from ..launch.mesh import make_batch_mesh
         from .manifest import CampaignManifest, CampaignMismatchError
-        init_worker_process(init.get("compile_cache_dir"))
+        # a spawned worker carries a fresh JAX runtime: re-probe the
+        # platform in-process (a persistent compile cache, if any, comes
+        # with the inherited JAX_COMPILATION_CACHE_DIR)
+        reset_backend_cache()
         manifest = CampaignManifest.load(init["directory"])
         if manifest.space_sig != init["space_sig"]:
             raise CampaignMismatchError(
@@ -441,7 +444,6 @@ class ProcessShardExecutor:
             "sweep": dict(sweep),
             "n_devices": n_devices,
             "timeout_s": timeout_s,
-            "compile_cache_dir": _compile_cache_dir(),
         }
         self._workers: List[_WorkerHandle] = []
         self._pending: Deque[ShardOutcome] = deque()
@@ -604,13 +606,3 @@ class ProcessShardExecutor:
                 w.proc.join(timeout=5)
             w.conn.close()
 
-
-def _compile_cache_dir() -> Optional[str]:
-    """The parent's persistent XLA compilation cache dir, if configured,
-    so each worker's single compile is a disk hit instead of cold."""
-    try:
-        import jax
-        value = jax.config.jax_compilation_cache_dir
-        return str(value) if value else None
-    except Exception:  # noqa: BLE001 - cache reuse is best-effort
-        return None

@@ -45,8 +45,8 @@ from typing import Callable, Dict, List, Optional, Set, Tuple
 
 from ..ckpt import atomic_write_json
 from ..core.shard_sweep import (_DEFAULT_SUPERCHUNK, StreamResult,
-                                _prepare_stream)
-from ..kernels.runtime import explicit_backend, resolve_backend
+                                _prepare_stream, stream_index_dtype)
+from ..kernels.runtime import explicit_backend, on_tpu, resolve_backend
 from .executor import (CheckpointWriter, ProcessShardExecutor,
                        SerialShardExecutor, ShardTask, _dispatch,
                        resolve_workers)
@@ -146,6 +146,12 @@ def run_campaign(space, checkpoint_dir: str, *, k: int = 16,
             f"CampaignOptions.workers={opts.workers} — set one")
     n_workers = resolve_workers(
         workers if workers is not None else opts.workers)
+    if n_workers > 1 and on_tpu():
+        raise RuntimeError(
+            f"workers={n_workers} would spawn {n_workers} processes that "
+            f"each need the TPU, but a chip belongs to one process at a "
+            f"time (this one); run the campaign with workers=1 — one "
+            f"process drives every chip through the mesh")
     t0 = time.perf_counter()
 
     # ----- plan: create or verify the manifest ----------------------------
@@ -185,6 +191,8 @@ def run_campaign(space, checkpoint_dir: str, *, k: int = 16,
         else:
             resolved_backend = resolve_backend(backend)
         chunk = int(chunk_size or _DEFAULT_CHUNK)
+        # refuse a sweep the device cannot index before planning shards
+        stream_index_dtype(space.n_points, chunk, resolved_backend)
         sweep = {"k": int(k), "metric": metric, "engine": engine,
                  "chunk_size": chunk,
                  # FIXED scan length: the default would shrink with the

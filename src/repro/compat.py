@@ -1,60 +1,21 @@
-"""Version-drift shims for jax APIs used across the repo.
+"""Small wrappers over the jax APIs the repo uses in several places.
 
-The repo targets recent jax (``jax.make_mesh(..., axis_types=...)`` with
-``jax.sharding.AxisType``) but must also run on older installs where the
-``AxisType`` enum and the ``axis_types`` keyword do not exist yet.  Callers
-import :data:`AxisType` and :func:`make_mesh` from here instead of touching
-``jax.sharding`` directly; on old jax the axis types are accepted and
-silently dropped (meshes are implicitly all-Auto there anyway).
+They target the installed jax (0.9): meshes carry explicit axis types,
+64-bit mode is switched by the scoped ``jax.enable_x64`` context and
+``shard_map`` lives at the top level with its ``check_vma`` switch.
 """
 from __future__ import annotations
 
 import contextlib
-import enum
-import inspect
 
 import jax
 
-try:  # jax >= 0.5: real enum, meshes carry explicit/auto axis semantics
-    AxisType = jax.sharding.AxisType
-    HAS_AXIS_TYPES = True
-except AttributeError:  # older jax: stand-in so call sites stay uniform
-    class AxisType(enum.Enum):
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-    HAS_AXIS_TYPES = False
-
-_MAKE_MESH_TAKES_AXIS_TYPES = (
-    "axis_types" in inspect.signature(jax.make_mesh).parameters)
+AxisType = jax.sharding.AxisType
 
 
 def auto_axis_types(n: int):
     """``(AxisType.Auto,) * n`` — the repo's only axis-type usage."""
     return (AxisType.Auto,) * n
-
-
-def make_mesh(axis_shapes, axis_names, *, devices=None, axis_types=None):
-    """``jax.make_mesh`` that tolerates jax versions without ``axis_types``."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if axis_types is not None and _MAKE_MESH_TAKES_AXIS_TYPES:
-        kwargs["axis_types"] = axis_types
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
-
-
-def cost_analysis(compiled) -> dict:
-    """Normalize ``Compiled.cost_analysis()`` across jax versions.
-
-    Old jax returns a one-element list of per-platform dicts; new jax
-    returns the dict directly (and may return None on some backends).
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
 
 
 def x64_context(enable: bool):
@@ -63,27 +24,12 @@ def x64_context(enable: bool):
     The streaming sweep widens its flat design-point indices to int64 only
     when the grid actually crosses 2**31 points; everything else in the
     repo stays in the default 32-bit world, so the switch must be scoped
-    (``jax.experimental.enable_x64``), never the global x64 flag.
+    (``jax.enable_x64(True)``), never the global x64 flag.
     """
-    if not enable:
-        return contextlib.nullcontext()
-    from jax.experimental import enable_x64
-    return enable_x64()
+    return jax.enable_x64(True) if enable else contextlib.nullcontext()
 
 
 def shard_map(fn, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checks off, on any jax version.
-
-    The implementation moved to the top level and its check kwarg was
-    renamed ``check_rep`` -> ``check_vma`` at different times, so both the
-    location and the kwarg are detected from the actual signature.
-    """
-    if hasattr(jax, "shard_map"):
-        impl = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as impl
-    params = inspect.signature(impl).parameters
-    check = ({"check_vma": False} if "check_vma" in params
-             else {"check_rep": False} if "check_rep" in params else {})
-    return impl(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                **check)
+    """``jax.shard_map`` with the varying-manual-axes check off."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

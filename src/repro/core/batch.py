@@ -44,6 +44,11 @@ from .constants import (MIPI_CSI2_ENERGY_PER_BYTE, DYNAMIC_ENERGY_SCALE,
 from .fom import fom_table_points
 from .plan import CATEGORIES, EnergyPlan, _EXTRA_CACHES
 
+#: matmuls that stand in for gathers and segment sums must not round
+#: their f32 operands: at default precision a TPU multiplies in one bf16
+#: pass, while HIGHEST reproduces every f32 one-hot product exactly
+_EXACT = jax.lax.Precision.HIGHEST
+
 
 class DesignPoints(NamedTuple):
     """Struct-of-arrays batch of design points (all fields shape (B,)).
@@ -524,7 +529,7 @@ def build_banked_eval(dims):
             g("utsv_bytes") * UTSV_ENERGY_PER_BYTE,
             g("mipi_bytes") * MIPI_CSI2_ENERGY_PER_BYTE]))
         unit_e = jnp.concatenate(rows)
-        red = unit_e @ g("weights")
+        red = jnp.dot(unit_e, g("weights"), precision=_EXACT)
 
         # ----- Sec. 6.2 power density -------------------------------------
         analog_area = g("n_pixels") * (pt.pixel_pitch_um * 1e-3) ** 2
@@ -623,7 +628,7 @@ def _take_rows(x, idx, n, exact: bool):
         return jnp.take(x, idx, axis=0)
     lane = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], n), 1)
     onehot = (idx[:, None] == lane).astype(jnp.float32)
-    return jnp.dot(onehot, x)
+    return jnp.dot(onehot, x, precision=_EXACT)
 
 
 def _scatter_add_rows(x, idx, n, exact: bool):
@@ -633,7 +638,7 @@ def _scatter_add_rows(x, idx, n, exact: bool):
         return jnp.zeros((n, x.shape[1]), jnp.float32).at[idx].add(x)
     lane = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], n), 1)
     onehot = (idx[:, None] == lane).astype(jnp.float32)
-    return jnp.dot(onehot.T, x)
+    return jnp.dot(onehot.T, x, precision=_EXACT)
 
 
 def build_coeff_compute(dims, *, exact: bool = True):
@@ -670,6 +675,13 @@ def build_coeff_compute(dims, *, exact: bool = True):
 
     def compute(row, pt):
         g = row_getter(row, layout)
+
+        def flat(name):
+            # a matrix coefficient as its flat row-major slice: a kernel
+            # cannot reshape a 1-D vector into a 2-D one
+            off, shape = layout[name]
+            return row[off:off + int(np.prod(shape))]
+
         b = pt["frame_rate"].shape[0]
         cis = pt["cis_node"][None, :]
         soc = pt["soc_node"][None, :]
@@ -695,14 +707,17 @@ def build_coeff_compute(dims, *, exact: bool = True):
                 + (pt["sys_rows"] + pt["sys_cols"])[None, :],
                 g("d_cycles")[:, None])
             durs = cycles / g("d_clock")[:, None]            # (D, B)
-            edge_w = g("d_edge_w")
-            edge_m = g("d_edge_mask") > 0.5
+            edge_w = flat("d_edge_w")
+            # the mask stays f32 until each scalar is read out: a kernel
+            # can only extract 32-bit scalars from a vector
+            edge_m = flat("d_edge_mask")
             starts = []
             for i in range(D):      # static unroll; DAG edges go backward
                 s_i = jnp.zeros((b,), jnp.float32)
                 for j in range(i):
                     s_i = jnp.maximum(s_i, jnp.where(
-                        edge_m[i, j], starts[j] + edge_w[i, j] * durs[j],
+                        edge_m[i * D + j] > 0.5,
+                        starts[j] + edge_w[i * D + j] * durs[j],
                         0.0))
                 starts.append(s_i)
             starts = jnp.stack(starts)                       # (D, B)
@@ -767,16 +782,16 @@ def build_coeff_compute(dims, *, exact: bool = True):
             write_e = jnp.where(is_stt,
                                 STT_WRITE_ENERGY_PER_BIT_65 * bits * s_m,
                                 sram_access)
-            read_e = jnp.where(jnp.isnan(g("m_read_x"))[:, None],
+            read_e = jnp.where(jnp.isnan(g("m_read_x")[:, None]),
                                read_e, g("m_read_x")[:, None])
-            write_e = jnp.where(jnp.isnan(g("m_write_x"))[:, None],
+            write_e = jnp.where(jnp.isnan(g("m_write_x")[:, None]),
                                 write_e, g("m_write_x")[:, None])
             leak_bit = jnp.where(
                 is_stt, jnp.float32(STT_LEAKAGE_PER_BIT),
                 jnp.where(tech == 1, hp_scale(node_m),
                           leak_scale(node_m)))
             leak = leak_bit * g("m_bits_total")[:, None]
-            leak = jnp.where(jnp.isnan(g("m_leak_x"))[:, None],
+            leak = jnp.where(jnp.isnan(g("m_leak_x")[:, None]),
                              leak, g("m_leak_x")[:, None])
             reads = (g("m_reads_fixed")[:, None]
                      + g("m_reads_dnn2")[:, None]
@@ -793,7 +808,14 @@ def build_coeff_compute(dims, *, exact: bool = True):
             jnp.broadcast_to(g("mipi_bytes") * MIPI_CSI2_ENERGY_PER_BYTE,
                              (b,))]))
         unit_e = jnp.concatenate(rows, axis=0)               # (U, B)
-        red = jnp.dot(g("weights").T, unit_e)                # (C+2, B)
+        # category reduction (C+2, B) as one rank-1 update per unit, on
+        # the VPU in exact f32 (a TPU matmul at default precision would
+        # round the energies to bf16)
+        n_w = n_c + 2
+        w = flat("weights")
+        red = w[:n_w][:, None] * unit_e[0][None, :]
+        for u in range(1, unit_e.shape[0]):
+            red = red + w[u * n_w:(u + 1) * n_w][:, None] * unit_e[u][None, :]
 
         # ----- Sec. 6.2 power density -------------------------------------
         analog_area = g("n_pixels") * (pt["pixel_pitch_um"] * 1e-3) ** 2

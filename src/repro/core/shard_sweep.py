@@ -99,6 +99,29 @@ _POINT_SPECS = DesignPoints(*([_BATCH_SPEC] * len(DesignPoints._fields)))
 #: slots and compile time stays flat
 _DEFAULT_SUPERCHUNK = 16
 
+
+def stream_index_dtype(total: int, chunk: int, backend: str):
+    """The flat-index dtype of a ``total``-point sweep in ``chunk``-point
+    chunks: int32, or int64 once int32 cannot hold ``start + chunk - 1``
+    BEFORE tail clamping/masking (at ``total`` in ``(2**31 - chunk,
+    2**31)`` the tail additions would wrap negative and sneak past the
+    validity mask otherwise).
+
+    The compiled Mosaic kernel holds no 64-bit values, so a wide sweep on
+    the compiled Pallas lane raises here, before anything is traced.
+    """
+    if total + chunk < 2 ** 31:
+        return jnp.int32
+    if backend == "pallas" and not resolve_interpret(None):
+        raise NotImplementedError(
+            f"a {total}-point sweep needs int64 flat indices, and the "
+            f"compiled Pallas megakernel cannot hold 64-bit values on a "
+            f"TPU (Mosaic: '64-bit types are not supported'); grids of "
+            f">= 2**31 points wait on the int32 (variant, chunk, offset) "
+            f"index rework, ROADMAP item B1")
+    return jnp.int64
+
+
 # the on-device decoder emits axis rows in ChunkedGrid order == AXES order;
 # DesignPoints consumes them positionally
 assert tuple(AXES) == DesignPoints._fields, (AXES, DesignPoints._fields)
@@ -969,12 +992,8 @@ def _stream_impl(algorithm: Union[str, Sequence[str]] = "edgaze",
     chunk = -(-max(int(chunk_size), 1) // ndev) * ndev
     chunk = min(chunk, -(-n_var // ndev) * ndev)
     lo, hi = _validate_index_range(index_range, total)
-    # int32 must hold start + chunk - 1 BEFORE tail clamping/masking, so
-    # the widen decision accounts for the final chunk's overshoot — at
-    # total in (2**31 - chunk, 2**31) the tail additions would wrap
-    # negative and sneak past the validity mask otherwise
-    wide = total + chunk >= 2 ** 31
-    idx_dtype = jnp.int64 if wide else jnp.int32
+    idx_dtype = stream_index_dtype(total, chunk, backend)
+    wide = idx_dtype == jnp.int64
 
     dispatches = 0
     dispatched_points = 0

@@ -22,7 +22,9 @@ from .runtime import resolve_interpret
 def _reduce_kernel(e_ref, w_ref, o_ref):
     e = e_ref[...].astype(jnp.float32)
     w = w_ref[...].astype(jnp.float32)
-    o_ref[...] = jnp.dot(e, w).astype(o_ref.dtype)
+    # HIGHEST: one bf16 pass (the TPU default) would round the energies
+    o_ref[...] = jnp.dot(e, w, precision=jax.lax.Precision.HIGHEST
+                         ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_points", "interpret"))

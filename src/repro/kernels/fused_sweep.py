@@ -31,6 +31,12 @@ Masking follows the streaming driver's contract: a point is valid iff
 ``low <= flat < limit`` AND it lies inside this call's ``chunk`` span
 (blocks are padded up to ``block_points``; the spillover positions would
 otherwise double-count the next shard's points).
+
+Mosaic's rules shape the layout: ``start`` / ``low`` / ``limit`` arrive
+as one SMEM vector, each block writes whole ``(1, n)`` rows of
+``(G, 1, n)`` outputs (the block's trailing dims then equal the array's),
+and the kernel holds no 64-bit value, so the compiled kernel takes int32
+indices only.  ``tests/test_tpu_compile.py`` compiles it for a TPU v5e.
 """
 from __future__ import annotations
 
@@ -39,40 +45,54 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .grid_decode import decode_axis_values, grid_strides
 from .runtime import resolve_interpret
 
 
-def _fused_kernel(start_ref, low_ref, limit_ref, table_ref, row_ref,
-                  cv_ref, cl_ref, st_ref, *, compute, metric, axis_names,
-                  shape, strides, n_var, total, chunk, block, kk,
-                  idx_dtype, n_variants, lmax, gather):
+def _fused_kernel(bounds_ref, table_ref, row_ref, cv_ref, cl_ref, st_ref,
+                  *, compute, metric, axis_names, shape, strides, n_var,
+                  total, chunk, block, kk, idx_dtype, n_variants, lmax,
+                  gather):
     i = pl.program_id(0)
     lane = jax.lax.broadcasted_iota(idx_dtype, (1, block), 1)
     pos = i * block + lane                      # position within the chunk
-    off = start_ref[0, 0] + pos
-    valid = ((off >= low_ref[0, 0]) & (off < limit_ref[0, 0])
+    off = bounds_ref[0] + pos
+    valid = ((off >= bounds_ref[1]) & (off < bounds_ref[2])
              & (pos < chunk))[0]
     offc = jnp.minimum(off, total - 1)          # clamp tail for the decode
     vals, _vid = decode_axis_values(
         offc, table_ref[...], shape=shape, strides=strides, n_var=n_var,
-        block=block, n_variants=n_variants, lmax=lmax, gather=gather)
+        n_variants=n_variants, lmax=lmax, gather=gather)
     out = compute(row_ref[0, :], dict(zip(axis_names, vals)))
-    ok = out["feasible"] & valid
-    mv = out[metric].astype(jnp.float32)
+    ok = (out["feasible"] & valid)[None, :]
+    mv = out[metric].astype(jnp.float32)[None, :]
 
-    # block-local top-k by iterative min extraction: k is tiny and static,
-    # and masking the winner with a compare keeps the loop branchless
+    # block-local top-k by iterative min extraction: k is tiny and static.
+    # Each pass takes the block minimum and the LOWEST position holding it
+    # (a min over a masked iota, which is also the tie rule of lax.top_k
+    # in the XLA twin), then masks that position; the winners accumulate
+    # into (1, kk) vectors by lane select, so the kernel stores whole
+    # vectors only
     masked = jnp.where(ok, mv, jnp.inf)
-    posi = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)[0]
+    posi = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, kk), 1)
+    cand_v = jnp.full((1, kk), jnp.inf, jnp.float32)
+    cand_l = jnp.zeros((1, kk), jnp.int32)
     for j in range(kk):
-        am = jnp.argmin(masked).astype(jnp.int32)
-        cv_ref[0, j] = jnp.min(masked)
-        cl_ref[0, j] = am
+        m = jnp.min(masked, axis=1, keepdims=True)
+        am = jnp.min(jnp.where(masked == m, posi, block), axis=1,
+                     keepdims=True)
+        cand_v = jnp.where(slot == j, m, cand_v)
+        cand_l = jnp.where(slot == j, am, cand_l)
         masked = jnp.where(posi == am, jnp.inf, masked)
-    st_ref[0, 0] = jnp.sum(jnp.where(ok, mv, 0.0))
-    st_ref[0, 1] = jnp.sum(ok.astype(jnp.float32))
+    cv_ref[...] = cand_v
+    cl_ref[...] = cand_l
+    s = jnp.sum(jnp.where(ok, mv, 0.0), axis=1, keepdims=True)
+    n = jnp.sum(ok.astype(jnp.float32), axis=1, keepdims=True)
+    st_ref[...] = jnp.where(
+        jax.lax.broadcasted_iota(jnp.int32, (1, 2), 1) == 0, s, n)
 
 
 def fused_sweep_block(table2: jax.Array, row: jax.Array, start, low, limit,
@@ -100,9 +120,11 @@ def fused_sweep_block(table2: jax.Array, row: jax.Array, start, low, limit,
     nb = -(-chunk // bp)
     interpret = resolve_interpret(interpret)
 
-    def s2(v):
-        return jnp.asarray(v, idx_dtype).reshape(1, 1)
-
+    bounds = jnp.stack([jnp.asarray(v, idx_dtype)
+                        for v in (start, low, limit)])
+    # per-block outputs are (G, 1, n) arrays whose leading block dim is
+    # squeezed: each kernel block writes one whole (1, n) row, and the
+    # block's trailing two dims equal the array's, as Mosaic requires
     cv, cl, st = pl.pallas_call(
         functools.partial(
             _fused_kernel, compute=compute, metric=metric,
@@ -112,22 +134,20 @@ def fused_sweep_block(table2: jax.Array, row: jax.Array, start, low, limit,
             n_variants=vl // lmax, lmax=lmax, gather=interpret),
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((n_axes, vl), lambda i: (0, 0)),
             pl.BlockSpec((1, row.shape[-1]), lambda i: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, kk), lambda i: (i, 0)),
-            pl.BlockSpec((1, kk), lambda i: (i, 0)),
-            pl.BlockSpec((1, 2), lambda i: (i, 0)),
+            pl.BlockSpec((None, 1, kk), lambda i: (i, 0, 0)),
+            pl.BlockSpec((None, 1, kk), lambda i: (i, 0, 0)),
+            pl.BlockSpec((None, 1, 2), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nb, kk), jnp.float32),
-            jax.ShapeDtypeStruct((nb, kk), jnp.int32),
-            jax.ShapeDtypeStruct((nb, 2), jnp.float32),
+            jax.ShapeDtypeStruct((nb, 1, kk), jnp.float32),
+            jax.ShapeDtypeStruct((nb, 1, kk), jnp.int32),
+            jax.ShapeDtypeStruct((nb, 1, 2), jnp.float32),
         ],
         interpret=interpret,
-    )(s2(start), s2(low), s2(limit), table2, row.reshape(1, -1))
-    return cv, cl, st[:, 0], st[:, 1]
+    )(bounds, table2, row.reshape(1, -1))
+    return cv[:, 0], cl[:, 0], st[:, 0, 0], st[:, 0, 1]

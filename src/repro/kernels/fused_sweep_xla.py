@@ -79,8 +79,7 @@ def fused_sweep_block_xla(table2: jax.Array, row: jax.Array, start, low,
     offc = jnp.minimum(off, total - 1)          # clamp tail; mask decides
     vals, _vid = decode_axis_values(
         offc, table2, shape=tuple(shape), strides=grid_strides(shape),
-        n_var=n_var, block=nb * bp, n_variants=vl // lmax, lmax=lmax,
-        gather=True)
+        n_var=n_var, n_variants=vl // lmax, lmax=lmax, gather=True)
     out = compute(row.reshape(-1), dict(zip(axis_names, vals)))
     ok = out["feasible"] & valid
     mv = out[metric].astype(jnp.float32)

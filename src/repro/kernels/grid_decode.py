@@ -36,8 +36,8 @@ from jax.experimental import pallas as pl
 from .runtime import resolve_interpret
 
 
-def decode_axis_values(off, table, *, shape, strides, n_var, block,
-                       n_variants, lmax, gather):
+def decode_axis_values(off, table, *, shape, strides, n_var, n_variants,
+                       lmax, gather):
     """Decode clamped flat indices into per-axis value vectors in-kernel.
 
     ``off`` is a ``(1, block)`` integer array of flat stream indices
@@ -52,7 +52,10 @@ def decode_axis_values(off, table, *, shape, strides, n_var, block,
     vid = off // n_var
     local = off - vid * n_var
     vid32 = vid.astype(jnp.int32)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, n_variants * lmax), 1)
+    # table entry of each point, as the sublane index of a (V * Lmax,
+    # block) one-hot: the lookup is then a lane-dense (1, V * Lmax) x
+    # (V * Lmax, block) matmul with no relayout of the index vector
+    entry = jax.lax.broadcasted_iota(jnp.int32, (n_variants * lmax, 1), 0)
     vals = []
     for a in range(len(shape)):
         idx_a = ((local // strides[a]) % shape[a]).astype(jnp.int32)
@@ -63,10 +66,11 @@ def decode_axis_values(off, table, *, shape, strides, n_var, block,
             vals.append(jnp.take(table[a, :], ci[0]))
         else:
             # compiled TPU path: table lookup as a one-hot matmul so the
-            # gather rides the MXU (same idiom as category_reduce)
-            onehot = (ci.reshape(block, 1) == lane).astype(jnp.float32)
-            col = table[a, :].reshape(n_variants * lmax, 1)
-            vals.append(jnp.dot(onehot, col)[:, 0])
+            # gather rides the MXU; HIGHEST precision keeps every f32
+            # table entry exact (one bf16 pass would round it)
+            onehot = (ci == entry).astype(jnp.float32)
+            vals.append(jnp.dot(table[a:a + 1, :], onehot,
+                                precision=jax.lax.Precision.HIGHEST)[0])
     return vals, vid32
 
 
@@ -79,7 +83,7 @@ def _decode_kernel(start_ref, table_ref, vals_ref, vid_ref, *, shape,
     off = jnp.minimum(off, total - 1)          # clamp tail; caller masks
     vals, vid32 = decode_axis_values(
         off, table_ref[...], shape=shape, strides=strides, n_var=n_var,
-        block=block, n_variants=n_variants, lmax=lmax, gather=gather)
+        n_variants=n_variants, lmax=lmax, gather=gather)
     for a in range(len(shape)):
         vals_ref[a, :] = vals[a]
     vid_ref[0, :] = vid32[0]

@@ -62,23 +62,6 @@ def reset_backend_cache() -> None:
     _BACKEND_IS_TPU = None
 
 
-def init_worker_process(compile_cache_dir: Optional[str] = None) -> None:
-    """Per-process runtime init for campaign worker processes.
-
-    A spawned worker carries a FRESH JAX runtime, so backend resolution
-    must re-probe in-process (the parent's memoized probe never
-    transfers, but a pre-fork'd interpreter embedding could have warmed
-    it — dropping the cache makes the contract explicit either way),
-    and the parent's persistent XLA compilation cache directory is
-    adopted so the worker's single step-executable compile is a disk
-    hit instead of a cold build.
-    """
-    reset_backend_cache()
-    if compile_cache_dir:
-        jax.config.update("jax_compilation_cache_dir",
-                          str(compile_cache_dir))
-
-
 def _env_override() -> Optional[bool]:
     raw = os.environ.get(_ENV_VAR)
     if raw is None:

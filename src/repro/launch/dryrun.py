@@ -1,15 +1,10 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-from ..kernels.runtime import reset_backend_cache
-reset_backend_cache()   # platform set changed: drop any memoized probe
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The env assignment above MUST stay the first statement of this module —
-jax locks the device count at first initialization, and the production
-meshes need 512 placeholder host devices.  The backend-probe reset keeps
-any earlier import's memoized platform answer from leaking past the
-forced device count.
+The production meshes need 512 placeholder host devices.  ``main()``
+forces that count through ``XLA_FLAGS`` before its first device use (jax
+reads the flag when its backends first initialize); importing this
+module changes nothing.  The backend-probe reset keeps an earlier
+memoized platform answer from leaking past the forced device count.
 
 Per cell this harness produces:
   * feasibility proof: full-depth scanned step compiles on the mesh;
@@ -28,6 +23,7 @@ cells unless --force.
 import argparse
 import dataclasses
 import json
+import os
 import time
 import traceback
 from typing import Any, Dict, Optional, Tuple
@@ -36,13 +32,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..compat import cost_analysis
 from ..configs import ARCH_IDS, get_config
 from ..distributed import (cache_shardings, input_shardings, param_shardings,
                            use_mesh)
 from ..energy import (collective_bytes, model_flops, roofline_terms,
                       tpu_energy_report)
 from ..energy.roofline import V5E
+from ..kernels.runtime import reset_backend_cache
 from ..models import model as M
 from ..models.config import ModelConfig
 from .mesh import make_production_mesh
@@ -178,7 +174,7 @@ def _compile(cfg, shape, mesh, unroll, vocab_chunk=0, profile="tp"):
     lowered = fn.lower(*args)
     compiled = lowered.compile()
     dt = time.time() - t0
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     ma = compiled.memory_analysis()
     coll_w, coll_ops = collective_bytes(compiled.as_text())
     # HBM-traffic proxy: every assigned buffer is written once and read once
@@ -289,6 +285,8 @@ def main() -> None:
                     help="suffix for the result key (hillclimb variants)")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    reset_backend_cache()   # platform set changed: drop any memoized probe
 
     archs = ARCH_IDS if args.arch == "all" else [args.arch]
     shapes = list(SHAPES.values()) if args.shape == "all" \

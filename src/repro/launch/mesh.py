@@ -2,9 +2,9 @@
 
 ``make_production_mesh`` is a FUNCTION (never a module-level constant) so
 importing this module touches no jax device state.  The dry-run entrypoint
-sets ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any
-jax import; everything else (smoke tests, benches) sees the real device
-count.
+sets ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` in its
+``main()`` before the first device use; everything else (smoke tests,
+benches) sees the real device count.
 
 Topology mapping (TPU v5e): the single-pod mesh is one 16x16 pod —
 (data=16, model=16); 'model' rides the fastest ICI dimension (TP traffic is
@@ -20,7 +20,7 @@ from typing import Optional
 
 import jax
 
-from ..compat import auto_axis_types, make_mesh
+from ..compat import auto_axis_types
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -33,8 +33,8 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {need} devices but only "
             f"{len(jax.devices())} visible — run under dryrun.py, which "
             f"sets XLA_FLAGS=--xla_force_host_platform_device_count=512")
-    return make_mesh(shape, axes, devices=devices,
-                     axis_types=auto_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=auto_axis_types(len(axes)))
 
 
 def make_batch_mesh(num_devices: Optional[int] = None):
@@ -54,8 +54,8 @@ def make_batch_mesh(num_devices: Optional[int] = None):
             f"batch mesh wants {n} devices but {len(devices)} are visible "
             f"— force more with "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=<n>")
-    return make_mesh((n,), ("batch",), devices=devices[:n],
-                     axis_types=auto_axis_types(1))
+    return jax.make_mesh((n,), ("batch",), devices=devices[:n],
+                         axis_types=auto_axis_types(1))
 
 
 def make_host_mesh(data: Optional[int] = None, model: int = 1):
@@ -64,6 +64,6 @@ def make_host_mesh(data: Optional[int] = None, model: int = 1):
     if data is None:
         data = max(n // model, 1)
     need = data * model
-    return make_mesh((data, model), ("data", "model"),
-                     devices=jax.devices()[:need],
-                     axis_types=auto_axis_types(2))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         devices=jax.devices()[:need],
+                         axis_types=auto_axis_types(2))

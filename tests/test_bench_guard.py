@@ -115,3 +115,22 @@ def test_campaign_rows_invisible_to_mega_guard(history):
     assert [r["bench"] for r in bench_run.read_history("mega_sweep")] \
         == ["mega_sweep"]
     assert check_regression.check() == 0
+
+
+def test_main_exits_nonzero_when_a_bench_raised(monkeypatch, capsys):
+    """A raising bench prints its ERROR row, the others still run, and
+    the process then exits non-zero instead of reporting success."""
+    def good():
+        return ["good,1,ok"]
+
+    def bad():
+        raise RuntimeError("refused by the compiler")
+
+    monkeypatch.setattr(bench_run, "BENCHES", [bad, good])
+    monkeypatch.setattr(bench_run, "setup_compile_cache", lambda: None)
+    with pytest.raises(SystemExit) as exc:
+        bench_run.main([])
+    assert exc.value.code not in (0, None)
+    out = capsys.readouterr().out
+    assert "bad,0,ERROR RuntimeError: refused by the compiler" in out
+    assert "good,1,ok" in out

@@ -210,6 +210,23 @@ def test_worker_count_conflict_and_explore_validation(space, tmp_path):
         explore(space, workers=2)
 
 
+@pytest.mark.parametrize("via_env", [False, True])
+def test_workers_on_tpu_refused_before_spawning(space, tmp_path,
+                                                monkeypatch, via_env):
+    """A TPU belongs to one process: workers>1 (argument or env) raises
+    the one-process rule before any worker or checkpoint exists."""
+    from repro.kernels import runtime
+    monkeypatch.setattr(runtime, "_BACKEND_IS_TPU", True)
+    monkeypatch.delenv(WORKERS_ENV, raising=False)
+    kw = {"workers": 2}
+    if via_env:
+        monkeypatch.setenv(WORKERS_ENV, "2")
+        kw = {}
+    with pytest.raises(RuntimeError, match="one process"):
+        run_campaign(space, str(tmp_path / "c"), **kw)
+    assert not (tmp_path / "c").exists()
+
+
 # ---------------------------------------------------------------------------
 # merge algebra under arrival order + duplicate redelivery
 # ---------------------------------------------------------------------------

@@ -427,9 +427,7 @@ def test_backend_staged_rejects_explicit_xla():
 # ---------------------------------------------------------------------------
 # coefficient-form compute == banked vmap evaluator (direct, no driver)
 # ---------------------------------------------------------------------------
-def test_coeff_compute_matches_banked_eval():
-    """The kernel-body physics matches the staged vmap evaluator on a
-    random mixed batch for every output key."""
+def _coeff_compute_case(exact):
     import jax.numpy as jnp
     from repro.core.batch import build_coeff_compute, make_points
     from repro.core.plan_bank import build_plan_bank, evaluate_bank
@@ -449,7 +447,7 @@ def test_coeff_compute_matches_banked_eval():
         frame_rate=rng.choice([15.0, 60.0, 240.0], n),
         active_fraction_scale=rng.choice([0.25, 1.0], n),
         pixel_pitch_um=rng.choice([2.0, 5.0], n))
-    compute = build_coeff_compute(bank.dims, exact=True)
+    compute = build_coeff_compute(bank.dims, exact=exact)
     for vi in range(len(plans)):
         ref = evaluate_bank(bank, np.full(n, vi, np.int32), pts)
         got = compute(bank.arrays["fused"][vi],
@@ -460,3 +458,15 @@ def test_coeff_compute_matches_banked_eval():
             np.testing.assert_allclose(np.asarray(got[key]), ref[key],
                                        rtol=_REL, atol=0,
                                        err_msg=(vi, key))
+
+
+def test_coeff_compute_matches_banked_eval():
+    """The kernel-body physics matches the staged vmap evaluator on a
+    random mixed batch for every output key."""
+    _coeff_compute_case(exact=True)
+
+
+def test_coeff_compute_one_hot_form_matches_banked_eval():
+    """The compiled-kernel form (one-hot matmul gathers and scatters in
+    place of ``take`` / ``.at[].add``) meets the same parity."""
+    _coeff_compute_case(exact=False)

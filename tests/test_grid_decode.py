@@ -50,6 +50,29 @@ def test_grid_decode_matches_chunked_grid_oracle_fixed_cases():
     _decode_case([4, 3, 2, 2], 1, 17, 31, 2)   # non-divisible blocks
 
 
+def test_decode_one_hot_lookup_is_bit_exact():
+    """The compiled path's one-hot matmul lookup (``gather=False``)
+    decodes the same f32 values as the direct gather, bit for bit: the
+    table has values that one bf16 pass would round."""
+    import jax.numpy as jnp
+    from repro.kernels.grid_decode import decode_axis_values, grid_strides
+    shape, n_variants = (5, 3, 4), 3
+    lmax = max(shape)
+    rng = np.random.default_rng(11)
+    table = jnp.asarray(rng.normal(size=(len(shape), n_variants * lmax))
+                        * 1e-9 + 1.0, jnp.float32)
+    n_var = int(np.prod(shape))
+    off = jnp.arange(n_variants * n_var, dtype=jnp.int32).reshape(1, -1)
+    kw = dict(shape=shape, strides=grid_strides(shape), n_var=n_var,
+              n_variants=n_variants, lmax=lmax)
+    ref, vid = decode_axis_values(off, table, gather=True, **kw)
+    got, vid2 = decode_axis_values(off, table, gather=False, **kw)
+    np.testing.assert_array_equal(np.asarray(vid), np.asarray(vid2))
+    for a in range(len(shape)):
+        np.testing.assert_array_equal(np.asarray(got[a]),
+                                      np.asarray(ref[a]))
+
+
 def test_grid_decode_property_vs_host_oracle():
     """Hypothesis sweep of the same oracle (skips without hypothesis)."""
     hyp = pytest.importorskip("hypothesis")

@@ -17,7 +17,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import dataclasses, jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 import repro.models.model as M
-from repro.compat import auto_axis_types, make_mesh
+from repro.compat import auto_axis_types
 from repro.configs import get_config, reduced
 from repro.distributed import param_shardings, use_mesh, cache_shardings
 from repro.distributed.sharding import batch_spec
@@ -37,8 +37,8 @@ for arch in ("qwen3_4b", "granite_moe_1b_a400m", "falcon_mamba_7b"):
     _, _, ref_metrics = ref_step(params, opt, batch, 1)
     ref_loss = float(ref_metrics["loss"])
 
-    mesh = make_mesh((4, 2), ("data", "model"),
-                     axis_types=auto_axis_types(2))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=auto_axis_types(2))
     psh = param_shardings(params, mesh)
     osh = {"m": psh, "v": psh, "count": NamedSharding(mesh, P())}
     bsh = {"tokens": NamedSharding(mesh, batch_spec(mesh, 8))}

@@ -295,6 +295,29 @@ def test_stream_int64_indices_beyond_int32_ceiling(engine):
                                rtol=1e-6)
 
 
+@pytest.mark.parametrize("campaign", [False, True])
+def test_wide_grid_on_compiled_pallas_raises(monkeypatch, tmp_path,
+                                             campaign):
+    """On a TPU the compiled megakernel cannot hold int64 indices: a
+    >=2**31-point explore() (or campaign) refuses before compiling and
+    names ROADMAP B1, instead of falling back to another lane."""
+    from repro.core.shard_sweep import stream_cache_info
+    from repro.explore import DesignSpace, explore
+    from repro.kernels import runtime
+    monkeypatch.delenv("REPRO_SWEEP_BACKEND", raising=False)
+    monkeypatch.delenv("REPRO_KERNEL_INTERPRET", raising=False)
+    monkeypatch.setattr(runtime, "_BACKEND_IS_TPU", True)
+    grids = {"variant": ["3d_in"],
+             "cis_node": list(np.linspace(28.0, 130.0, 1500)),
+             "frame_rate": list(np.linspace(15.0, 120.0, 1500)),
+             "active_fraction_scale": list(np.linspace(0.1, 1.0, 1000))}
+    before = stream_cache_info()["step_compiles"]
+    kw = dict(checkpoint_dir=str(tmp_path / "c")) if campaign else {}
+    with pytest.raises(NotImplementedError, match="B1"):
+        explore(DesignSpace("edgaze", grids), engine="fused", k=4, **kw)
+    assert stream_cache_info()["step_compiles"] == before
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("engine", ["fused", "staged"])
 def test_stream_int32_boundary_window_widens(engine):
@@ -334,8 +357,8 @@ def test_stream_int32_boundary_window_widens(engine):
 # ---------------------------------------------------------------------------
 SCRIPT = r"""
 import os
-# overwrite (not append): the parent pytest process may carry a forced
-# device count already (e.g. repro.launch.dryrun sets 512 on import)
+# overwrite (not append): the parent environment may carry a forced
+# device count already
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np, jax
 from repro.core.batch import evaluate_batch, make_points

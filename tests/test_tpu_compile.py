@@ -1,0 +1,176 @@
+"""Compile rehearsals for one TPU v5e, without the chip.
+
+The sweep's Mosaic kernels and the superchunk step compile here for a
+described ``v5e:2x2`` topology at the mega grid's real widths (10 axes,
+8 variants, ~1.26e7 points, ``block_points=4096``).  A compile that the
+TPU compiler refuses fails here at no chip time; a compile that passes
+is not a chip run and says nothing about results or speed.
+
+The topology is described inside a fixture, never at import time: only
+one process may load the TPU library, and each test worker imports every
+test file.
+"""
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from repro.compat import auto_axis_types
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
+                                "benchmarks"))
+from run import MEGA_GRIDS  # noqa: E402
+
+BLOCK = 4096
+CHUNK = 1 << 18
+K = 16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means no topology
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def prep():
+    from repro.core.shard_sweep import _prepare_stream
+    return _prepare_stream(["edgaze", "rhythmic"], MEGA_GRIDS)
+
+
+@pytest.fixture
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def steer_tpu(monkeypatch):
+    """The step factory asks the runtime for its platform; the described
+    chip is not what ``jax.default_backend()`` sees, so steer it."""
+    from repro.kernels import runtime
+    monkeypatch.delenv("REPRO_SWEEP_BACKEND", raising=False)
+    monkeypatch.delenv("REPRO_KERNEL_INTERPRET", raising=False)
+    monkeypatch.setattr(runtime, "_BACKEND_IS_TPU", True)
+
+
+def _kernel(prep, idx_dtype):
+    from repro.core.batch import build_coeff_compute
+    from repro.core.sweep import AXES
+    from repro.kernels.fused_sweep import fused_sweep_block
+    compute = build_coeff_compute(prep.bank.dims, exact=False)
+
+    def f(table2, row, start, low, limit):
+        return fused_sweep_block(
+            table2, row, start, low, limit, compute=compute,
+            metric="total_j", axis_names=tuple(AXES),
+            shape=tuple(prep.vgrids[0].shape), n_var=prep.n_var,
+            total=prep.total, chunk=CHUNK, lmax=prep.lmax,
+            block_points=BLOCK, kk=K, idx_dtype=idx_dtype,
+            interpret=False)
+    return f
+
+
+def _kernel_args(prep, sharding, idx_dtype):
+    width = prep.bank.arrays["fused"].shape[1]
+    scalar = jax.ShapeDtypeStruct((), idx_dtype, sharding=sharding)
+    return (jax.ShapeDtypeStruct(prep.table2.shape, jnp.float32,
+                                 sharding=sharding),
+            jax.ShapeDtypeStruct((1, width), jnp.float32,
+                                 sharding=sharding),
+            scalar, scalar, scalar)
+
+
+def test_megakernel_compiles_for_v5e(prep, one_chip, no_persistent_cache):
+    compiled = jax.jit(_kernel(prep, jnp.int32)).lower(
+        *_kernel_args(prep, one_chip, jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_megakernel_refuses_int64_indices(prep, one_chip,
+                                          no_persistent_cache):
+    """Mosaic holds no 64-bit values: the >=2**31-point index path cannot
+    compile for the chip (ROADMAP B1), which is why the sweep refuses
+    such grids on a TPU before tracing."""
+    with jax.enable_x64(True):
+        lowered = jax.jit(_kernel(prep, jnp.int64))
+        with pytest.raises(NotImplementedError, match="64-bit"):
+            lowered.lower(*_kernel_args(prep, one_chip, jnp.int64))
+
+
+@pytest.mark.parametrize("n_chips", [1, 4])
+def test_superchunk_step_compiles_for_v5e(prep, topo, n_chips, steer_tpu,
+                                          no_persistent_cache):
+    """The whole superchunk scan step of the mega sweep, on a mesh of the
+    described chips, as ``explore()`` builds it on a TPU."""
+    from repro.core.shard_sweep import (_DEFAULT_SUPERCHUNK, _fused_step,
+                                        _init_banked_state)
+    mesh = Mesh(np.array(topo.devices[:n_chips]), ("batch",),
+                axis_types=auto_axis_types(1))
+    cpv = -(-prep.n_var // CHUNK)
+    superchunk, out_keys = _fused_step(
+        prep.bank, mesh, "total_j", K, CHUNK, BLOCK, prep.vgrids[0].shape,
+        prep.n_var, prep.lmax, jnp.int32, _DEFAULT_SUPERCHUNK, cpv,
+        backend="pallas")
+    rep = NamedSharding(mesh, P())
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(jnp.shape(x), jnp.asarray(x).dtype,
+                                    sharding=rep)
+    state0 = _init_banked_state(K, len(out_keys), prep.n_variants,
+                                jnp.int32, with_out=False)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)
+    compiled = jax.jit(superchunk, donate_argnums=(6,)).lower(
+        scalar, scalar, scalar, scalar, spec(prep.table2),
+        jax.tree.map(spec, prep.bank.arrays),
+        jax.tree.map(spec, state0)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_grid_decode_compiles_for_v5e(prep, one_chip, no_persistent_cache):
+    """The standalone decode kernel, which shares the megakernel's
+    ``decode_axis_values`` and which the chip smoke checks bit for bit
+    against the host grid."""
+    from repro.kernels.grid_decode import grid_decode
+    compiled = jax.jit(lambda t, s: grid_decode(
+        t, s, shape=tuple(prep.vgrids[0].shape), n_var=prep.n_var,
+        total=prep.total, chunk=1 << 16, block_points=BLOCK,
+        interpret=False)).lower(
+        jax.ShapeDtypeStruct(prep.tables.shape, jnp.float32,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_category_reduce_compiles_for_v5e(one_chip, no_persistent_cache):
+    """The monolithic engine's per-category reduction kernel."""
+    from repro.kernels.category_reduce import category_reduce
+    compiled = jax.jit(
+        lambda e, w: category_reduce(e, w, interpret=False)).lower(
+        jax.ShapeDtypeStruct((1 << 15, 11), jnp.float32,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((11, 10), jnp.float32,
+                             sharding=one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
