@@ -39,11 +39,11 @@ import queue
 import signal
 import sys
 import threading
-import time
 from collections import deque
 from typing import Deque, Dict, List, Optional
 
 from ..core.shard_sweep import StreamResult, _stream_impl
+from ..spans import Span, carry, span, start
 from .faults import ShardTimeout, classify_failure
 from .manifest import shard_path, write_shard
 
@@ -89,7 +89,7 @@ class _TimeoutRunner:
         if self._pool is None:
             self._pool = concurrent.futures.ThreadPoolExecutor(
                 max_workers=1)
-        fut = self._pool.submit(fn)
+        fut = self._pool.submit(carry(fn))
         try:
             return fut.result(timeout=timeout_s)
         except concurrent.futures.TimeoutError:
@@ -165,6 +165,7 @@ class ShardOutcome:
     exc: Optional[BaseException] = None     # serial path only (kill re-raise)
     step_compiles: Optional[int] = None     # worker-process cache stat
     worker: Optional[int] = None            # worker pid (parallel only)
+    span: Optional[Span] = None             # campaign.shard (serial only)
 
 
 # ---------------------------------------------------------------------------
@@ -179,6 +180,11 @@ class CheckpointWriter:
     fsync latency overlap the next dispatch instead of serializing the
     campaign.  The queue is bounded: a slow disk backpressures the
     scheduler rather than buffering unbounded payloads.
+
+    Each write is a ``ckpt.write`` span on the writer thread, under the
+    span ``submit()`` hands it (the shard's).  ``io_s`` sums those
+    spans; ``blocked_s`` sums the ``ckpt.put`` and ``ckpt.flush`` spans
+    in which the scheduler waited on the writer.
 
     Write failures are captured, surfaced on the next ``submit()`` /
     ``raise_if_failed()``, and never deadlock the flush.  ``close()``
@@ -205,40 +211,40 @@ class CheckpointWriter:
             if item is None:
                 self._q.task_done()
                 return
-            lo, hi, payload, attempts, splits = item
-            t0 = time.perf_counter()
-            try:
-                write_shard(self.directory, lo, hi, payload,
-                            attempts=attempts, splits=splits)
-                self.n_writes += 1
-                qpath = shard_path(self.directory, lo, hi,
-                                   quarantined=True)
-                if os.path.exists(qpath):   # range recovered on this run
-                    os.remove(qpath)
-            except BaseException as exc:  # noqa: BLE001 - surfaced on flush
-                if self._error is None:
-                    self._error = exc
-            finally:
-                self.io_s += time.perf_counter() - t0
-                self._q.task_done()
+            lo, hi, payload, attempts, splits, parent = item
+            with span("ckpt.write", parent=parent, lo=lo, hi=hi) as sp:
+                try:
+                    write_shard(self.directory, lo, hi, payload,
+                                attempts=attempts, splits=splits)
+                    self.n_writes += 1
+                    qpath = shard_path(self.directory, lo, hi,
+                                       quarantined=True)
+                    if os.path.exists(qpath):  # recovered on this run
+                        os.remove(qpath)
+                except BaseException as exc:  # noqa: BLE001 - on flush
+                    if self._error is None:
+                        self._error = exc
+            self.io_s += sp.seconds
+            self._q.task_done()
 
     def submit(self, lo: int, hi: int, payload: Dict, *,
-               attempts: int = 1, splits: int = 0) -> None:
+               attempts: int = 1, splits: int = 0,
+               parent: Optional[Span] = None) -> None:
         self.raise_if_failed()
         if self._closed:
             raise RuntimeError("CheckpointWriter is closed")
-        t0 = time.perf_counter()
-        self._q.put((int(lo), int(hi), payload, int(attempts),
-                     int(splits)))
+        with span("ckpt.put") as sp:
+            self._q.put((int(lo), int(hi), payload, int(attempts),
+                         int(splits), parent))
         # a put that blocked on the bounded queue is I/O the campaign
         # did NOT overlap — counted against io_overlap_frac
-        self.blocked_s += time.perf_counter() - t0
+        self.blocked_s += sp.seconds
 
     def flush(self) -> None:
         """Barrier: block until every accepted write has completed."""
-        t0 = time.perf_counter()
-        self._q.join()
-        self.blocked_s += time.perf_counter() - t0
+        with span("ckpt.flush") as sp:
+            self._q.join()
+        self.blocked_s += sp.seconds
 
     def close(self) -> None:
         """Flush + stop the writer thread.  Idempotent; never raises."""
@@ -288,17 +294,20 @@ class SerialShardExecutor:
         return not self._done
 
     def submit(self, task: ShardTask, *, die: bool = False) -> None:
-        try:
-            st = _dispatch(self._space, task.lo, task.hi, self._sweep,
-                           self._mesh, self._timeout_s, prep=self._prep,
-                           timeouts=self._timeouts)
-        except BaseException as exc:  # noqa: BLE001 - classified for the runner
-            self._done.append(ShardOutcome(
-                task=task, ok=False, kind=classify_failure(exc),
-                error=str(exc), exc=exc))
-        else:
-            self._done.append(ShardOutcome(
-                task=task, ok=True, result=st, payload=st.to_payload()))
+        with span("campaign.shard", lo=task.lo, hi=task.hi,
+                  attempt=task.attempt) as sp:
+            try:
+                st = _dispatch(self._space, task.lo, task.hi, self._sweep,
+                               self._mesh, self._timeout_s,
+                               prep=self._prep, timeouts=self._timeouts)
+            except BaseException as exc:  # noqa: BLE001 - for the runner
+                self._done.append(ShardOutcome(
+                    task=task, ok=False, kind=classify_failure(exc),
+                    error=str(exc), exc=exc, span=sp))
+            else:
+                self._done.append(ShardOutcome(
+                    task=task, ok=True, result=st,
+                    payload=st.to_payload(), span=sp))
 
     def wait_any(self) -> ShardOutcome:
         return self._done.popleft()
@@ -459,7 +468,7 @@ class ProcessShardExecutor:
         self.startup_s = 0.0
         self._n_initial = max(int(workers), 1)
         self._n_ready = 0
-        self._t_created = time.perf_counter()
+        self._startup = start("campaign.startup")
         for _ in range(self._n_initial):
             self._spawn_one()
 
@@ -537,7 +546,9 @@ class ProcessShardExecutor:
             self._early_deaths = 0
             if self._n_ready < self._n_initial:
                 self._n_ready += 1
-                self.startup_s = time.perf_counter() - self._t_created
+                self.startup_s = self._startup.seconds
+                if self._n_ready == self._n_initial:
+                    self._startup.close()
             return
         if tag == "init-error":
             raise RuntimeError(
@@ -592,6 +603,7 @@ class ProcessShardExecutor:
 
     # ----- teardown -------------------------------------------------------
     def close(self, graceful: bool = True) -> None:
+        self._startup.close()
         workers, self._workers = self._workers, []
         for w in workers:
             if graceful and w.proc.is_alive():

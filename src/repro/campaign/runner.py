@@ -47,6 +47,7 @@ from ..ckpt import atomic_write_json
 from ..core.shard_sweep import (_DEFAULT_SUPERCHUNK, StreamResult,
                                 _prepare_stream, stream_index_dtype)
 from ..kernels.runtime import explicit_backend, on_tpu, resolve_backend
+from ..spans import span, traced
 from .executor import (CheckpointWriter, ProcessShardExecutor,
                        SerialShardExecutor, ShardTask, _dispatch,
                        resolve_workers)
@@ -134,7 +135,6 @@ def run_campaign(space, checkpoint_dir: str, *, k: int = 16,
     :class:`CampaignIntegrityError` on a checksum-failing shard file;
     ``'redispatch'`` discards it and re-runs that range.
     """
-    from ..explore.api import _stream_to_explore
     if on_corrupt not in ("refuse", "redispatch"):
         raise ValueError(f"on_corrupt must be 'refuse' or 'redispatch', "
                          f"got {on_corrupt!r}")
@@ -152,76 +152,92 @@ def run_campaign(space, checkpoint_dir: str, *, k: int = 16,
             f"each need the TPU, but a chip belongs to one process at a "
             f"time (this one); run the campaign with workers=1 — one "
             f"process drives every chip through the mesh")
-    t0 = time.perf_counter()
-
-    # ----- plan: create or verify the manifest ----------------------------
     resumed = os.path.exists(os.path.join(checkpoint_dir, "manifest.json"))
-    if resumed:
-        manifest = CampaignManifest.load(checkpoint_dir)
-        manifest.verify_space(space)
-        manifest.verify_bank(space)
-        sweep = manifest.sweep
-        # cross-backend resume refusal: shards checkpointed by one
-        # megakernel lane must not merge with shards computed by the
-        # other (parity is rel 1e-6, but campaign merges are asserted
-        # bit-compatible).  An EXPLICIT request (argument or env) that
-        # contradicts the manifest refuses; "auto" reuses the record.
-        recorded = sweep.get("backend") or "pallas"
-        requested = explicit_backend(backend)
-        if sweep["engine"] == "fused" and requested not in (None, recorded):
-            raise CampaignMismatchError(
-                f"campaign at {checkpoint_dir!r} was recorded with "
-                f"backend={recorded!r} but this resume requests "
-                f"backend={requested!r}; resuming would mix executables "
-                f"across shards — resume with backend='auto'/"
-                f"{recorded!r}, or start a fresh checkpoint_dir")
-        sweep = dict(sweep, backend=recorded)
-    else:
-        if engine == "auto":
-            engine = "fused"
-        if engine not in ("fused", "staged"):
-            raise ValueError(f"campaigns need a streaming engine ('fused' "
-                             f"or 'staged'), got {engine!r}")
-        if engine == "staged":
-            if explicit_backend(backend) == "xla":
-                raise ValueError(
-                    "backend='xla' requires engine='fused'; the staged "
-                    "parity oracle always runs the Pallas pipeline")
-            resolved_backend = "pallas"
+    with span("campaign.run", resumed=resumed) as run_sp:
+        return _run(space, checkpoint_dir, run_sp, resumed, k=k,
+                    metric=metric, engine=engine, chunk_size=chunk_size,
+                    superchunk=superchunk, block_points=block_points,
+                    mesh=mesh, backend=backend, n_workers=n_workers,
+                    opts=opts, on_corrupt=on_corrupt)
+
+
+def _run(space, checkpoint_dir, run_sp, resumed, *, k, metric, engine,
+         chunk_size, superchunk, block_points, mesh, backend, n_workers,
+         opts, on_corrupt):
+    """The body of :func:`run_campaign`, inside its ``campaign.run``
+    span; the report's ``wall_s`` is that span so far."""
+    from ..explore.api import _stream_to_explore
+    # ----- plan: create or verify the manifest ----------------------------
+    with span("campaign.plan"):
+        if resumed:
+            manifest = CampaignManifest.load(checkpoint_dir)
+            manifest.verify_space(space)
+            manifest.verify_bank(space)
+            sweep = manifest.sweep
+            # cross-backend resume refusal: shards checkpointed by one
+            # megakernel lane must not merge with shards computed by the
+            # other (parity is rel 1e-6, but campaign merges are asserted
+            # bit-compatible).  An EXPLICIT request (argument or env) that
+            # contradicts the manifest refuses; "auto" reuses the record.
+            recorded = sweep.get("backend") or "pallas"
+            requested = explicit_backend(backend)
+            if sweep["engine"] == "fused" \
+                    and requested not in (None, recorded):
+                raise CampaignMismatchError(
+                    f"campaign at {checkpoint_dir!r} was recorded with "
+                    f"backend={recorded!r} but this resume requests "
+                    f"backend={requested!r}; resuming would mix executables "
+                    f"across shards — resume with backend='auto'/"
+                    f"{recorded!r}, or start a fresh checkpoint_dir")
+            sweep = dict(sweep, backend=recorded)
         else:
-            resolved_backend = resolve_backend(backend)
-        chunk = int(chunk_size or _DEFAULT_CHUNK)
-        # refuse a sweep the device cannot index before planning shards
-        stream_index_dtype(space.n_points, chunk, resolved_backend)
-        sweep = {"k": int(k), "metric": metric, "engine": engine,
-                 "chunk_size": chunk,
-                 # FIXED scan length: the default would shrink with the
-                 # shard's chunk count and each distinct s_len is a new
-                 # executable — pinning it keeps the whole campaign
-                 # (including OOM half-shards) on ONE step executable
-                 "superchunk": int(superchunk or _DEFAULT_SUPERCHUNK),
-                 "block_points": int(block_points),
-                 # resolved lane, not "auto": the manifest records what
-                 # actually ran so resume can refuse a cross-backend mix
-                 "backend": resolved_backend}
-        shard_points = int(opts.shard_points or 4 * chunk)
-        manifest = CampaignManifest.create(space, sweep=sweep,
-                                           shard_points=shard_points)
-        manifest.save(checkpoint_dir)
+            if engine == "auto":
+                engine = "fused"
+            if engine not in ("fused", "staged"):
+                raise ValueError(f"campaigns need a streaming engine ('fused' "
+                                 f"or 'staged'), got {engine!r}")
+            if engine == "staged":
+                if explicit_backend(backend) == "xla":
+                    raise ValueError(
+                        "backend='xla' requires engine='fused'; the staged "
+                        "parity oracle always runs the Pallas pipeline")
+                resolved_backend = "pallas"
+            else:
+                resolved_backend = resolve_backend(backend)
+            chunk = int(chunk_size or _DEFAULT_CHUNK)
+            # refuse a sweep the device cannot index before planning shards
+            stream_index_dtype(space.n_points, chunk, resolved_backend)
+            sweep = {"k": int(k), "metric": metric, "engine": engine,
+                     "chunk_size": chunk,
+                     # FIXED scan length: the default would shrink with the
+                     # shard's chunk count and each distinct s_len is a new
+                     # executable — pinning it keeps the whole campaign
+                     # (including OOM half-shards) on ONE step executable
+                     "superchunk": int(superchunk or _DEFAULT_SUPERCHUNK),
+                     "block_points": int(block_points),
+                     # resolved lane, not "auto": the manifest records what
+                     # actually ran so resume can refuse a cross-backend mix
+                     "backend": resolved_backend}
+            shard_points = int(opts.shard_points or 4 * chunk)
+            manifest = CampaignManifest.create(space, sweep=sweep,
+                                               shard_points=shard_points)
+            manifest.save(checkpoint_dir)
 
     # ----- load completed shards (verified), derive the work queue --------
     results: List[StreamResult] = []
     loaded: List[Tuple[int, int]] = []
-    for (lo, hi), path in sorted(completed_shards(checkpoint_dir).items()):
-        try:
-            payload = read_shard(path)
-        except CampaignIntegrityError:
-            if on_corrupt == "refuse":
-                raise
-            os.remove(path)            # redispatch: range back to queue
-            continue
-        results.append(StreamResult.from_payload(payload["result"]))
-        loaded.append((lo, hi))
+    with span("campaign.load"):
+        for (lo, hi), path in sorted(
+                completed_shards(checkpoint_dir).items()):
+            try:
+                payload = read_shard(path)
+            except CampaignIntegrityError:
+                if on_corrupt == "refuse":
+                    raise
+                os.remove(path)        # redispatch: range back to queue
+                continue
+            results.append(StreamResult.from_payload(payload["result"]))
+            loaded.append((lo, hi))
     pending = deque(ShardTask(lo, hi) for lo, hi in
                     missing_ranges(manifest.shards, loaded))
 
@@ -241,9 +257,11 @@ def run_campaign(space, checkpoint_dir: str, *, k: int = 16,
         # campaign — every shard (and every OOM half-shard) dispatches
         # against this shared prep, so per-shard fixed cost drops to
         # executable-cache lookup + O(k) finalization
-        prep = (_prepare_stream(list(space.algorithms), space.grids,
-                                soc_node=space.soc_node)
-                if pending else None)
+        prep = None
+        if pending:
+            with span("campaign.prep"):
+                prep = _prepare_stream(list(space.algorithms), space.grids,
+                                       soc_node=space.soc_node)
         executor = SerialShardExecutor(space, sweep, mesh, prep,
                                        opts.timeout_s)
     writer = CheckpointWriter(checkpoint_dir)
@@ -304,9 +322,9 @@ def run_campaign(space, checkpoint_dir: str, *, k: int = 16,
                 executor.submit(task, die=die)
             if executor.n_inflight == 0:
                 continue                # every submission faulted
-            t0_wait = time.perf_counter()
-            out = executor.wait_any()
-            dispatch_wait_s += time.perf_counter() - t0_wait
+            with span("campaign.wait") as wait_sp:
+                out = executor.wait_any()
+            dispatch_wait_s += wait_sp.seconds
             task = out.task
             if out.ok:
                 entry = {"lo": task.lo, "hi": task.hi,
@@ -322,7 +340,8 @@ def run_campaign(space, checkpoint_dir: str, *, k: int = 16,
                     continue
                 done_ranges.add((task.lo, task.hi))
                 writer.submit(task.lo, task.hi, out.payload,
-                              attempts=task.attempt, splits=task.splits)
+                              attempts=task.attempt, splits=task.splits,
+                              parent=out.span)
                 results.append(out.result)
                 executed.append(entry)
                 n_completed += 1
@@ -354,9 +373,10 @@ def run_campaign(space, checkpoint_dir: str, *, k: int = 16,
             f"campaign produced no completed shards — all "
             f"{len(quarantined)} dispatched ranges quarantined; see "
             f"{os.path.join(checkpoint_dir, 'quarantine')} for errors")
-    merged = merge_stream_results(results, k=int(sweep["k"]))
-    coverage = merged_coverage(results)
-    missing = missing_ranges(manifest.shards, coverage)
+    with span("campaign.merge"):
+        merged = merge_stream_results(results, k=int(sweep["k"]))
+        coverage = merged_coverage(results)
+        missing = missing_ranges(manifest.shards, coverage)
     report = {
         "schema": 1, "resumed": resumed,
         "n_planned": len(manifest.shards),
@@ -366,7 +386,7 @@ def run_campaign(space, checkpoint_dir: str, *, k: int = 16,
         "quarantined": quarantined,
         "coverage": [[lo, hi] for lo, hi in coverage],
         "missing": [[lo, hi] for lo, hi in missing],
-        "partial": bool(missing), "wall_s": time.perf_counter() - t0,
+        "partial": bool(missing), "wall_s": run_sp.seconds,
         "workers": n_workers,
         "dispatch_wait_s": round(dispatch_wait_s, 6),
         "io_s": round(writer.io_s, 6),
@@ -375,10 +395,12 @@ def run_campaign(space, checkpoint_dir: str, *, k: int = 16,
         "worker_step_compiles": sorted(
             getattr(executor, "worker_step_compiles", {}).values()),
     }
-    atomic_write_json(os.path.join(checkpoint_dir, REPORT_NAME), report)
+    with span("campaign.report"):
+        atomic_write_json(os.path.join(checkpoint_dir, REPORT_NAME), report)
     return _stream_to_explore(space, merged, campaign=report)
 
 
+@traced("resume")
 def resume(manifest_path: str, *, space=None, mesh=None,
            backend: str = "auto", workers: Optional[int] = None,
            options: Optional[CampaignOptions] = None,

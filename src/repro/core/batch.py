@@ -26,7 +26,6 @@ in tests.
 from __future__ import annotations
 
 import math
-import time
 from typing import Dict, NamedTuple, Optional, Sequence
 
 import jax
@@ -34,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels.category_reduce import category_reduce
+from ..spans import span
 from .axes import (ADC_DECLARED, AXES, AXES_SPEC, AXIS_BY_NAME,
                    TECH_DECLARED, axis_default)
 from .constants import (MIPI_CSI2_ENERGY_PER_BYTE, DYNAMIC_ENERGY_SCALE,
@@ -892,12 +892,11 @@ def _compiled(plan: EnergyPlan, points: DesignPoints, keep: bool,
     hit = plan._exec_cache.get(key)
     if hit is not None:
         return hit, 0.0
-    t0 = time.perf_counter()
-    exe = eval_fn(plan).lower(points, keep_unit_energies=keep,
-                              hooks=hooks).compile()
-    compile_s = time.perf_counter() - t0
+    with span("grid.compile") as sp:
+        exe = eval_fn(plan).lower(points, keep_unit_energies=keep,
+                                  hooks=hooks).compile()
     plan._exec_cache[key] = exe
-    return exe, compile_s
+    return exe, sp.seconds
 
 
 def evaluate_batch(plan: EnergyPlan, points: DesignPoints,
@@ -918,11 +917,10 @@ def evaluate_batch(plan: EnergyPlan, points: DesignPoints,
     """
     exe, compile_s = _compiled(plan, points, bool(keep_unit_energies),
                                hooks)
-    t0 = time.perf_counter()
-    out = exe(points)
-    out = {k: np.asarray(v) for k, v in out.items()}
-    eval_s = time.perf_counter() - t0
+    with span("grid.eval") as sp:
+        out = exe(points)
+        out = {k: np.asarray(v) for k, v in out.items()}
     if timings is not None:
         timings["compile_s"] = timings.get("compile_s", 0.0) + compile_s
-        timings["eval_s"] = timings.get("eval_s", 0.0) + eval_s
+        timings["eval_s"] = timings.get("eval_s", 0.0) + sp.seconds
     return out

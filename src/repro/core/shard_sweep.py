@@ -65,7 +65,6 @@ from __future__ import annotations
 import dataclasses
 import os
 import threading
-import time
 import warnings
 from collections import OrderedDict
 from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
@@ -83,6 +82,7 @@ from ..kernels.runtime import (resolve_backend, resolve_interpret,
                                sweep_kernel_mode)
 from ..kernels.stream_reduce import block_stats
 from ..launch.mesh import make_batch_mesh
+from ..spans import count, counters, reset_counters, span
 from .batch import (DesignPoints, OUT_KEYS, _hooks_active,
                     build_banked_eval, build_coeff_compute, eval_fn,
                     make_points, points_from_axis_rows)
@@ -159,11 +159,10 @@ def _sharded_exec(plan: EnergyPlan, mesh, batch: int, keep: bool,
     if hit is not None:
         return hit, 0.0
     fn, _keys = _sharded_fn(plan, mesh, keep, hooks)
-    t0 = time.perf_counter()
-    exe = jax.jit(fn).lower(make_points(plan, batch)).compile()
-    compile_s = time.perf_counter() - t0
+    with span("grid.compile") as sp:
+        exe = jax.jit(fn).lower(make_points(plan, batch)).compile()
     plan._exec_cache[key] = exe
-    return exe, compile_s
+    return exe, sp.seconds
 
 
 def pad_points(points: DesignPoints, multiple: int
@@ -200,13 +199,12 @@ def evaluate_batch_sharded(plan: EnergyPlan, points: DesignPoints, *,
     hooks = _hooks_active(points) if hooks is None else bool(hooks)
     exe, compile_s = _sharded_exec(plan, mesh, padded.batch,
                                    bool(keep_unit_energies), hooks)
-    t0 = time.perf_counter()
-    out = exe(padded)
-    out = {k: np.asarray(v)[:b] for k, v in out.items()}
-    eval_s = time.perf_counter() - t0
+    with span("grid.eval") as sp:
+        out = exe(padded)
+        out = {k: np.asarray(v)[:b] for k, v in out.items()}
     if timings is not None:
         timings["compile_s"] = timings.get("compile_s", 0.0) + compile_s
-        timings["eval_s"] = timings.get("eval_s", 0.0) + eval_s
+        timings["eval_s"] = timings.get("eval_s", 0.0) + sp.seconds
     return out
 
 
@@ -220,10 +218,11 @@ def evaluate_batch_sharded(plan: EnergyPlan, points: DesignPoints, *,
 #: LRU-ordered: long-lived processes sweeping many distinct grid shapes
 #: evict the stalest executable instead of growing without bound.
 _STREAM_CACHE: "OrderedDict[tuple, tuple]" = OrderedDict()
-_STREAM_STATS = {"step_compiles": 0, "hits": 0, "evictions": 0}
-#: guards the executable cache + its counters: concurrent explore()
-#: calls (thread-pool tenants, the serve facade) must never observe torn
-#: counters or double-compile one key, so the whole get-or-compile
+#: its counters, kept with the process counters of ``repro.spans``
+_STREAM_COUNTERS = ("step_compiles", "hits", "evictions")
+#: guards the executable cache: concurrent explore() calls (thread-pool
+#: tenants, the serve facade) must never double-compile one key, so the
+#: whole get-or-compile
 #: section of the *_exec factories runs under this lock — the second
 #: thread to request a cold key blocks behind the first's compile and
 #: then takes the hit path.  Reentrant: a compile that re-enters a
@@ -261,16 +260,17 @@ _EXTRA_CACHES.append(_STREAM_CACHE)     # flushed by lower_cache_clear()
 def stream_cache_info() -> Dict[str, int]:
     """Executable-cache counters for the one-executable invariant tests
     (plus LRU ``size`` / ``limit`` / ``evictions`` accounting)."""
+    totals = counters()
     with _STREAM_LOCK:
-        return dict(_STREAM_STATS, size=len(_STREAM_CACHE),
-                    limit=_STREAM_CACHE_LIMIT)
+        return dict({key: int(totals.get(f"stream.{key}", 0))
+                     for key in _STREAM_COUNTERS},
+                    size=len(_STREAM_CACHE), limit=_STREAM_CACHE_LIMIT)
 
 
 def stream_cache_clear() -> None:
     with _STREAM_LOCK:
         _STREAM_CACHE.clear()
-        for key in _STREAM_STATS:
-            _STREAM_STATS[key] = 0
+        reset_counters("stream.")
 
 
 def set_stream_cache_limit(limit: int) -> int:
@@ -282,7 +282,7 @@ def set_stream_cache_limit(limit: int) -> int:
         old, _STREAM_CACHE_LIMIT = _STREAM_CACHE_LIMIT, limit
         while len(_STREAM_CACHE) > _STREAM_CACHE_LIMIT:
             _STREAM_CACHE.popitem(last=False)
-            _STREAM_STATS["evictions"] += 1
+            count("stream.evictions")
     return old
 
 
@@ -291,7 +291,7 @@ def _cache_get(key):
         hit = _STREAM_CACHE.get(key)
         if hit is not None:
             _STREAM_CACHE.move_to_end(key)
-            _STREAM_STATS["hits"] += 1
+            count("stream.hits")
         return hit
 
 
@@ -301,7 +301,7 @@ def _cache_put(key, entry) -> None:
         _STREAM_CACHE.move_to_end(key)
         while len(_STREAM_CACHE) > _STREAM_CACHE_LIMIT:
             _STREAM_CACHE.popitem(last=False)
-            _STREAM_STATS["evictions"] += 1
+            count("stream.evictions")
 
 
 def _validate_index_range(index_range, total: int) -> Tuple[int, int]:
@@ -493,21 +493,24 @@ def _banked_exec(bank: PlanBank, mesh, metric: str, k: int, chunk: int,
         hit = _cache_get(key)
         if hit is not None:
             return hit
-        chunk_step, out_keys = _banked_step(bank, mesh, metric, k, chunk,
-                                            block_points, shape, n_var,
-                                            idx_dtype)
-        zero = jnp.asarray(0, idx_dtype)
-        state0 = _init_banked_state(k, len(out_keys),
-                                    bank.dims.n_variants, idx_dtype)
-        exe = jax.jit(chunk_step, donate_argnums=(4,)).lower(
-            zero, zero, tables, bank.arrays, state0).compile(
-            compiler_options=_compiler_opts())
-        _STREAM_STATS["step_compiles"] += 1
+        with span("step.lower"):
+            chunk_step, out_keys = _banked_step(bank, mesh, metric, k,
+                                                chunk, block_points, shape,
+                                                n_var, idx_dtype)
+            zero = jnp.asarray(0, idx_dtype)
+            state0 = _init_banked_state(k, len(out_keys),
+                                        bank.dims.n_variants, idx_dtype)
+            lowered = jax.jit(chunk_step, donate_argnums=(4,)).lower(
+                zero, zero, tables, bank.arrays, state0)
+        with span("step.compile"):
+            exe = lowered.compile(compiler_options=_compiler_opts())
+        count("stream.step_compiles")
         # warm the dispatch path on a no-op chunk: limit=0 makes every
         # point invalid, so counts are 0, every candidate metric is +inf
         # and the state is semantically untouched
-        state0, counts = exe(zero, zero, tables, bank.arrays, state0)
-        jax.block_until_ready(counts)
+        with span("step.warm"):
+            state0, counts = exe(zero, zero, tables, bank.arrays, state0)
+            jax.block_until_ready(counts)
         entry = (exe, out_keys)
         _cache_put(key, entry)
         return entry
@@ -588,16 +591,17 @@ def _fused_step(bank: PlanBank, mesh, metric: str, k: int, chunk: int,
                 total=total, chunk=shard, lmax=lmax, block_points=bp,
                 kk=kk, idx_dtype=idx_dtype, interpret=interpret)
         # fold the (G, kk) block candidates to this shard's top-kk
-        neg, pos = jax.lax.top_k(-cv.reshape(-1), kk)
-        blk = (pos // kk).astype(idx_dtype)
-        cand_i = s0 + blk * bp + cl.reshape(-1)[pos].astype(idx_dtype)
-        g = jnp.argmin(cv[:, 0])
-        amin_i = s0 + (g.astype(jnp.int32) * bp
-                       + cl[g, 0]).astype(idx_dtype)
-        return dict(
-            cand_v=-neg, cand_i=cand_i,
-            mins=cv[g, 0][None], amin_i=amin_i[None],
-            sums=jnp.sum(sums)[None], counts=jnp.sum(counts)[None])
+        with jax.named_scope("topk_merge"):
+            neg, pos = jax.lax.top_k(-cv.reshape(-1), kk)
+            blk = (pos // kk).astype(idx_dtype)
+            cand_i = s0 + blk * bp + cl.reshape(-1)[pos].astype(idx_dtype)
+            g = jnp.argmin(cv[:, 0])
+            amin_i = s0 + (g.astype(jnp.int32) * bp
+                           + cl[g, 0]).astype(idx_dtype)
+            return dict(
+                cand_v=-neg, cand_i=cand_i,
+                mins=cv[g, 0][None], amin_i=amin_i[None],
+                sums=jnp.sum(sums)[None], counts=jnp.sum(counts)[None])
 
     partial_keys = ("cand_v", "cand_i", "mins", "amin_i", "sums",
                     "counts")
@@ -616,8 +620,9 @@ def _fused_step(bank: PlanBank, mesh, metric: str, k: int, chunk: int,
             row = jax.lax.dynamic_index_in_dim(
                 bank_arrays["fused"], v, 0, keepdims=True)     # (1, W)
             parts = sharded(start, low, limit, table2, row)
-            return (_merge_candidates(parts, v, st, k, False),
-                    parts["counts"])
+            with jax.named_scope("state_fold"):
+                return (_merge_candidates(parts, v, st, k, False),
+                        parts["counts"])
 
         def dead(c, st):
             # a dead slot's kernel output is all-masked (+inf candidates,
@@ -666,24 +671,26 @@ def _fused_exec(bank: PlanBank, mesh, metric: str, k: int, chunk: int,
         hit = _cache_get(key)
         if hit is not None:
             return hit
-        superchunk, out_keys = _fused_step(bank, mesh, metric, k, chunk,
-                                           block_points, shape, n_var,
-                                           lmax, idx_dtype, s_len, cpv,
-                                           backend=backend)
-        zero = jnp.asarray(0, idx_dtype)
-        state0 = _init_banked_state(k, len(out_keys),
-                                    bank.dims.n_variants, idx_dtype,
-                                    with_out=False)
-        exe = jax.jit(superchunk, donate_argnums=(6,)).lower(
-            zero, zero, zero, zero, table2, bank.arrays, state0).compile(
-            compiler_options=_compiler_opts())
-        _STREAM_STATS["step_compiles"] += 1
+        with span("step.lower"):
+            superchunk, out_keys = _fused_step(
+                bank, mesh, metric, k, chunk, block_points, shape, n_var,
+                lmax, idx_dtype, s_len, cpv, backend=backend)
+            zero = jnp.asarray(0, idx_dtype)
+            state0 = _init_banked_state(k, len(out_keys),
+                                        bank.dims.n_variants, idx_dtype,
+                                        with_out=False)
+            lowered = jax.jit(superchunk, donate_argnums=(6,)).lower(
+                zero, zero, zero, zero, table2, bank.arrays, state0)
+        with span("step.compile"):
+            exe = lowered.compile(compiler_options=_compiler_opts())
+        count("stream.step_compiles")
         # warm the dispatch path on an all-dead superchunk: c_hi=0 turns
         # every scan slot into a limit=0 no-op, leaving the state
         # untouched
-        state0, counts = exe(zero, zero, zero, zero, table2, bank.arrays,
-                             state0)
-        jax.block_until_ready(counts)
+        with span("step.warm"):
+            state0, counts = exe(zero, zero, zero, zero, table2,
+                                 bank.arrays, state0)
+            jax.block_until_ready(counts)
         entry = (exe, out_keys)
         _cache_put(key, entry)
         return entry
@@ -784,7 +791,10 @@ class StreamResult:
     argmin_index, argmin_point}`` where the mean is over feasible points
     only.  ``dispatches`` counts step-executable invocations;
     ``occupancy`` is valid points / dispatched points (masked variant
-    tails and dead superchunk slots are the difference).
+    tails and dead superchunk slots are the difference).  ``wall_s`` is
+    the sweep's ``sweep`` span, ``compile_s`` its ``sweep.prep`` and
+    ``sweep.step`` spans and ``eval_s`` its ``sweep.dispatch`` span
+    (``repro.spans``).
     """
     algorithm: str
     metric: str
@@ -960,7 +970,28 @@ def _stream_impl(algorithm: Union[str, Sequence[str]] = "edgaze",
     once for the SAME ``(algorithm, grids, soc_node)`` skips per-call
     re-lowering (callers are responsible for that match).
     """
-    t_start = time.perf_counter()
+    with span("sweep", engine=engine) as sweep_sp:
+        res = _stream_run(
+            sweep_sp, algorithm, grids, soc_node=soc_node,
+            chunk_size=chunk_size, metric=metric, k=k, mesh=mesh,
+            block_points=block_points, progress=progress,
+            index_range=index_range, pipeline_depth=pipeline_depth,
+            engine=engine, superchunk=superchunk, backend=backend,
+            on_partial=on_partial, _prepared=_prepared)
+    res.wall_s = sweep_sp.seconds
+    return res
+
+
+def _stream_run(sweep_sp, algorithm, grids, *, soc_node, chunk_size,
+                metric, k, mesh, block_points, progress, index_range,
+                pipeline_depth, engine, superchunk, backend, on_partial,
+                _prepared) -> StreamResult:
+    """The body of :func:`_stream_impl`, inside its ``sweep`` span.
+
+    ``compile_s`` is the ``sweep.prep`` and ``sweep.step`` spans,
+    ``eval_s`` the ``sweep.dispatch`` span (the dispatch loop, its
+    pacing waits and the final drain) and ``wall_s`` the ``sweep`` span.
+    """
     if engine not in ("fused", "staged"):
         raise ValueError(f"unknown engine {engine!r}; "
                          f"valid: ['fused', 'staged']")
@@ -975,29 +1006,45 @@ def _stream_impl(algorithm: Union[str, Sequence[str]] = "edgaze",
     if mesh is None:
         mesh = make_batch_mesh()
     ndev = int(mesh.devices.size)
-    timings = {"compile_s": 0.0, "eval_s": 0.0}
 
-    t0 = time.perf_counter()
-    prep = (_prepared if _prepared is not None
-            else _prepare_stream(algorithm, grids, soc_node=soc_node))
-    algos = prep.algos
-    labels, valgos, vnames = prep.labels, prep.valgos, prep.vnames
-    plans, vgrids = prep.plans, prep.vgrids
-    n_var = prep.n_var
-    n_variants = prep.n_variants
-    total = prep.total
-    # device-divisible chunk, clamped to the per-variant span: chunks are
-    # variant-uniform, so any chunk budget beyond one span is masked tail
-    # work dispatched on every single chunk of a small-variant sweep
-    chunk = -(-max(int(chunk_size), 1) // ndev) * ndev
-    chunk = min(chunk, -(-n_var // ndev) * ndev)
-    lo, hi = _validate_index_range(index_range, total)
-    idx_dtype = stream_index_dtype(total, chunk, backend)
-    wide = idx_dtype == jnp.int64
+    with span("sweep.prep") as prep_sp:
+        prep = (_prepared if _prepared is not None
+                else _prepare_stream(algorithm, grids, soc_node=soc_node))
+        algos = prep.algos
+        labels, valgos, vnames = prep.labels, prep.valgos, prep.vnames
+        plans, vgrids = prep.plans, prep.vgrids
+        n_var = prep.n_var
+        n_variants = prep.n_variants
+        total = prep.total
+        # device-divisible chunk, clamped to the per-variant span: chunks
+        # are variant-uniform, so any chunk budget beyond one span is
+        # masked tail work dispatched on every chunk of a small-variant
+        # sweep
+        chunk = -(-max(int(chunk_size), 1) // ndev) * ndev
+        chunk = min(chunk, -(-n_var // ndev) * ndev)
+        lo, hi = _validate_index_range(index_range, total)
+        idx_dtype = stream_index_dtype(total, chunk, backend)
+        wide = idx_dtype == jnp.int64
+        # fused chunk ordinals: cpv chunk slots per variant, covering the
+        # whole variant span; [c_lo, c_hi) are the ordinals that
+        # intersect [lo, hi)
+        cpv = -(-n_var // chunk)
+
+        def _ordinal(f: int) -> int:
+            vi, r = divmod(f, n_var)
+            return vi * cpv + r // chunk
+
+        c_lo = _ordinal(lo)
+        c_hi = _ordinal(hi - 1) + 1 if hi > lo else c_lo
+        n_chunks = max(c_hi - c_lo, 0)
+        s_len = 1
+        if engine == "fused":
+            s_len = (max(1, int(superchunk)) if superchunk
+                     else min(max(n_chunks, 1), _DEFAULT_SUPERCHUNK))
 
     dispatches = 0
     dispatched_points = 0
-    s_len = 1
+    compile_s = 0.0
 
     def _finalize(state, out_keys, n_dispatches, n_dispatched, eval_s,
                   covered) -> StreamResult:
@@ -1010,178 +1057,168 @@ def _stream_impl(algorithm: Union[str, Sequence[str]] = "edgaze",
         ``[lo, hi)`` span the state is converging to).  All host work
         is O(k) / O(variants).
         """
-        host = jax.device_get(state)
-        # per-variant valid counts are range arithmetic on the variant-
-        # major flat index space — never computed on device
-        n_seen = _variant_span_counts(lo, hi, n_var, n_variants)
+        with span("sweep.finalize"):
+            with span("finalize.fetch"):
+                host = jax.device_get(state)
+            with span("finalize.regather"):
+                n_win = 0
+                while (n_win < len(host["topk_v"])
+                       and np.isfinite(host["topk_v"][n_win])):
+                    n_win += 1             # fewer than k feasible points
+                win = [divmod(int(host["topk_i"][j]), n_var)
+                       for j in range(n_win)]
+                if engine == "fused" and n_win:
+                    # tiny second pass over winners only: the megakernel
+                    # never wrote the per-point output table, so the k
+                    # winning rows re-gather their full output schema
+                    # through the banked evaluator here (padded to k so
+                    # every sweep shares one tiny executable)
+                    pts_axes = {ax: [] for ax in AXES}
+                    for vi, local in win + [win[-1]] * (k - n_win):
+                        point = vgrids[vi].point(local)
+                        for ax in AXES:
+                            pts_axes[ax].append(point[ax])
+                    vids = [vi for vi, _ in win] + [win[-1][0]] * (k - n_win)
+                    out = evaluate_bank(prep.bank, np.asarray(vids, np.int32),
+                                        make_points(plans[0], k, **pts_axes))
+                    host["topk_out"] = np.stack(
+                        [np.asarray(out[key], np.float32)[:n_win]
+                         for key in out_keys], axis=1)
+            with span("finalize.assemble"):
+                # per-variant valid counts are range arithmetic on the
+                # variant-major flat index space — never computed on
+                # device
+                n_seen = _variant_span_counts(lo, hi, n_var, n_variants)
+                summaries: Dict[str, Dict] = {}
+                n_feasible = 0
+                for vi, label in enumerate(labels):
+                    nf = int(host["n_feasible"][vi])
+                    n_feasible += nf
+                    amin = int(host["argmin"][vi])
+                    summaries[label] = dict(
+                        n=int(n_seen[vi]), n_feasible=nf,
+                        metric_min=float(host["metric_min"][vi]),
+                        metric_mean=(float(host["metric_sum"][vi]) / nf
+                                     if nf else float("nan")),
+                        argmin_index=amin % n_var if amin >= 0 else -1,
+                        argmin_point=(vgrids[vi].point(amin % n_var)
+                                      if amin >= 0 else None))
 
-        summaries: Dict[str, Dict] = {}
-        n_feasible = 0
-        for vi, label in enumerate(labels):
-            nf = int(host["n_feasible"][vi])
-            n_feasible += nf
-            amin = int(host["argmin"][vi])
-            summaries[label] = dict(
-                n=int(n_seen[vi]), n_feasible=nf,
-                metric_min=float(host["metric_min"][vi]),
-                metric_mean=(float(host["metric_sum"][vi]) / nf if nf
-                             else float("nan")),
-                argmin_index=amin % n_var if amin >= 0 else -1,
-                argmin_point=(vgrids[vi].point(amin % n_var)
-                              if amin >= 0 else None))
+                rows: List[Dict] = []
+                for j, (vi, local) in enumerate(win):
+                    row = dict(variant=vnames[vi], algorithm=valgos[vi],
+                               index=local, **vgrids[vi].point(local))
+                    row.update({key: float(host["topk_out"][j][c])
+                                for c, key in enumerate(out_keys)})
+                    rows.append(row)
 
-        n_win = 0
-        while (n_win < len(host["topk_v"])
-               and np.isfinite(host["topk_v"][n_win])):
-            n_win += 1                     # fewer than k feasible points
-        win = [divmod(int(host["topk_i"][j]), n_var)
-               for j in range(n_win)]
-        if engine == "fused" and n_win:
-            # tiny second pass over winners only: the megakernel never
-            # wrote the per-point output table, so the k winning rows
-            # re-gather their full output schema through the banked
-            # evaluator here (padded to k so every sweep shares one tiny
-            # executable)
-            pts_axes = {ax: [] for ax in AXES}
-            for vi, local in win + [win[-1]] * (k - n_win):
-                point = vgrids[vi].point(local)
-                for ax in AXES:
-                    pts_axes[ax].append(point[ax])
-            vids = [vi for vi, _ in win] + [win[-1][0]] * (k - n_win)
-            out = evaluate_bank(bank, np.asarray(vids, np.int32),
-                                make_points(plans[0], k, **pts_axes))
-            host["topk_out"] = np.stack(
-                [np.asarray(out[key], np.float32)[:n_win]
-                 for key in out_keys], axis=1)
+                return StreamResult(
+                    algorithm="+".join(algos), metric=metric, k=k,
+                    n_points=covered, n_feasible=n_feasible,
+                    n_devices=ndev, chunk_size=chunk, topk=rows,
+                    summaries=summaries, wall_s=sweep_sp.seconds,
+                    compile_s=compile_s, eval_s=eval_s,
+                    n_variants=n_variants, index_lo=lo, index_hi=hi,
+                    engine=engine, dispatches=n_dispatches,
+                    superchunk=s_len,
+                    occupancy=(covered / n_dispatched if n_dispatched
+                               else 1.0),
+                    n_var=n_var, backend=backend,
+                    kernel_mode=sweep_kernel_mode(backend))
 
-        rows: List[Dict] = []
-        for j, (vi, local) in enumerate(win):
-            row = dict(variant=vnames[vi], algorithm=valgos[vi],
-                       index=local, **vgrids[vi].point(local))
-            row.update({key: float(host["topk_out"][j][c])
-                        for c, key in enumerate(out_keys)})
-            rows.append(row)
-
-        return StreamResult(
-            algorithm="+".join(algos), metric=metric, k=k,
-            n_points=covered, n_feasible=n_feasible, n_devices=ndev,
-            chunk_size=chunk, topk=rows, summaries=summaries,
-            wall_s=time.perf_counter() - t_start,
-            compile_s=timings["compile_s"], eval_s=eval_s,
-            n_variants=n_variants, index_lo=lo, index_hi=hi,
-            engine=engine, dispatches=n_dispatches, superchunk=s_len,
-            occupancy=(covered / n_dispatched if n_dispatched else 1.0),
-            n_var=n_var, backend=backend,
-            kernel_mode=sweep_kernel_mode(backend))
     with x64_context(wide):
         # tables/bank/table2 are all-f32 (x64-independent), built once in
         # the prep — inside the context only INDEX arrays widen
         tables, bank, lmax = prep.tables, prep.bank, prep.lmax
-
-        if engine == "fused":
-            # chunk ordinals: cpv chunk slots per variant, covering the
-            # whole variant span; [c_lo, c_hi) are the ordinals that
-            # intersect [lo, hi)
-            cpv = -(-n_var // chunk)
-
-            def _ordinal(f: int) -> int:
-                vi, r = divmod(f, n_var)
-                return vi * cpv + r // chunk
-
-            c_lo = _ordinal(lo)
-            c_hi = _ordinal(hi - 1) + 1 if hi > lo else c_lo
-            n_chunks = max(c_hi - c_lo, 0)
-            s_len = (max(1, int(superchunk)) if superchunk
-                     else min(max(n_chunks, 1), _DEFAULT_SUPERCHUNK))
-            table2 = prep.table2
-            exe, out_keys = _fused_exec(
-                bank, mesh, metric, k, chunk, block_points,
-                vgrids[0].shape, n_var, lmax, idx_dtype, table2, s_len,
-                cpv, backend=backend)
+        table2 = prep.table2
+        with span("sweep.step") as step_sp:
+            if engine == "fused":
+                exe, out_keys = _fused_exec(
+                    bank, mesh, metric, k, chunk, block_points,
+                    vgrids[0].shape, n_var, lmax, idx_dtype, table2, s_len,
+                    cpv, backend=backend)
+            else:
+                exe, out_keys = _banked_exec(
+                    bank, mesh, metric, k, chunk, block_points,
+                    vgrids[0].shape, n_var, lmax, idx_dtype, tables)
             state = _init_banked_state(k, len(out_keys), n_variants,
-                                       idx_dtype, with_out=False)
-            timings["compile_s"] += time.perf_counter() - t0
+                                       idx_dtype,
+                                       with_out=engine != "fused")
+        compile_s = prep_sp.seconds + step_sp.seconds
 
-            t0 = time.perf_counter()
-            dev = lambda v: jnp.asarray(v, idx_dtype)       # noqa: E731
-            lo_dev, hi_dev, chi_dev = dev(lo), dev(hi), dev(c_hi)
-            inflight: List = []
-            for d0 in range(c_lo, c_hi, s_len):
-                state, counts = exe(dev(d0), lo_dev, hi_dev, chi_dev,
-                                    table2, bank.arrays, state)
-                dispatches += 1
-                dispatched_points += s_len * chunk
-                # pace on the counts partial so upcoming dispatches
-                # overlap device execution without running unboundedly
-                # ahead; the state itself is donated to the next
-                # superchunk and cannot be blocked on
-                inflight.append(counts)
-                if len(inflight) > pipeline_depth:
-                    jax.block_until_ready(inflight.pop(0))
-                if progress is not None or on_partial is not None:
-                    last = min(d0 + s_len, c_hi) - 1
-                    vi_l, r_l = divmod(last, cpv)
-                    end = min(vi_l * n_var + (r_l + 1) * chunk,
-                              vi_l * n_var + n_var, hi)
-                    done_pts = max(end - lo, 0)
-                    if progress is not None:
-                        progress(done_pts, hi - lo)
-                    if on_partial is not None:
-                        # bind loop state by value: the closure is only
-                        # valid until the next dispatch donates `state`
-                        on_partial(done_pts, hi - lo,
-                                   lambda st=state, nd=dispatches,
-                                   dpts=dispatched_points, cov=done_pts,
-                                   te=t0: _finalize(
-                                       st, out_keys, nd, dpts,
-                                       timings["eval_s"]
-                                       + time.perf_counter() - te, cov))
-            jax.block_until_ready(state["n_feasible"])
-            timings["eval_s"] += time.perf_counter() - t0
-        else:
-            exe, out_keys = _banked_exec(
-                bank, mesh, metric, k, chunk, block_points,
-                vgrids[0].shape, n_var, lmax, idx_dtype, tables)
-            state = _init_banked_state(k, len(out_keys), n_variants,
-                                       idx_dtype)
-            timings["compile_s"] += time.perf_counter() - t0
-
-            t0 = time.perf_counter()
-            inflight = []
-            done = 0
-            # chunks are aligned to variant boundaries so each one is
-            # variant-uniform (the evaluator broadcasts one coefficient
-            # row); `limit` masks both the variant end and the
-            # index_range end
-            for vi in range(n_variants):
-                vlo = max(lo, vi * n_var)
-                vhi = min(hi, (vi + 1) * n_var)
-                if vlo >= vhi:
-                    continue
-                limit_dev = jnp.asarray(vhi, idx_dtype)
-                for start in range(vlo, vhi, chunk):
-                    state, counts = exe(jnp.asarray(start, idx_dtype),
-                                        limit_dev, tables, bank.arrays,
-                                        state)
+        with span("sweep.dispatch") as disp_sp:
+            if engine == "fused":
+                dev = lambda v: jnp.asarray(v, idx_dtype)   # noqa: E731
+                lo_dev, hi_dev, chi_dev = dev(lo), dev(hi), dev(c_hi)
+                inflight: List = []
+                for d0 in range(c_lo, c_hi, s_len):
+                    state, counts = exe(dev(d0), lo_dev, hi_dev, chi_dev,
+                                        table2, bank.arrays, state)
                     dispatches += 1
-                    dispatched_points += chunk
+                    dispatched_points += s_len * chunk
+                    # pace on the counts partial so upcoming dispatches
+                    # overlap device execution without running
+                    # unboundedly ahead; the state itself is donated to
+                    # the next superchunk and cannot be blocked on
                     inflight.append(counts)
                     if len(inflight) > pipeline_depth:
-                        jax.block_until_ready(inflight.pop(0))
-                    done += min(start + chunk, vhi) - start
-                    if progress is not None:
-                        progress(done, hi - lo)
-                    if on_partial is not None:
-                        on_partial(done, hi - lo,
-                                   lambda st=state, nd=dispatches,
-                                   dpts=dispatched_points, cov=done,
-                                   te=t0: _finalize(
-                                       st, out_keys, nd, dpts,
-                                       timings["eval_s"]
-                                       + time.perf_counter() - te, cov))
-            jax.block_until_ready(state["n_feasible"])
-            timings["eval_s"] += time.perf_counter() - t0
+                        with span("sweep.pace"):
+                            jax.block_until_ready(inflight.pop(0))
+                    if progress is not None or on_partial is not None:
+                        last = min(d0 + s_len, c_hi) - 1
+                        vi_l, r_l = divmod(last, cpv)
+                        end = min(vi_l * n_var + (r_l + 1) * chunk,
+                                  vi_l * n_var + n_var, hi)
+                        done_pts = max(end - lo, 0)
+                        if progress is not None:
+                            progress(done_pts, hi - lo)
+                        if on_partial is not None:
+                            # bind loop state by value: the closure is
+                            # only valid until the next dispatch donates
+                            # `state`
+                            on_partial(done_pts, hi - lo,
+                                       lambda st=state, nd=dispatches,
+                                       dpts=dispatched_points,
+                                       cov=done_pts: _finalize(
+                                           st, out_keys, nd, dpts,
+                                           disp_sp.seconds, cov))
+            else:
+                inflight = []
+                done = 0
+                # chunks are aligned to variant boundaries so each one is
+                # variant-uniform (the evaluator broadcasts one
+                # coefficient row); `limit` masks both the variant end
+                # and the index_range end
+                for vi in range(n_variants):
+                    vlo = max(lo, vi * n_var)
+                    vhi = min(hi, (vi + 1) * n_var)
+                    if vlo >= vhi:
+                        continue
+                    limit_dev = jnp.asarray(vhi, idx_dtype)
+                    for start in range(vlo, vhi, chunk):
+                        state, counts = exe(jnp.asarray(start, idx_dtype),
+                                            limit_dev, tables, bank.arrays,
+                                            state)
+                        dispatches += 1
+                        dispatched_points += chunk
+                        inflight.append(counts)
+                        if len(inflight) > pipeline_depth:
+                            with span("sweep.pace"):
+                                jax.block_until_ready(inflight.pop(0))
+                        done += min(start + chunk, vhi) - start
+                        if progress is not None:
+                            progress(done, hi - lo)
+                        if on_partial is not None:
+                            on_partial(done, hi - lo,
+                                       lambda st=state, nd=dispatches,
+                                       dpts=dispatched_points,
+                                       cov=done: _finalize(
+                                           st, out_keys, nd, dpts,
+                                           disp_sp.seconds, cov))
+            with span("sweep.pace"):
+                jax.block_until_ready(state["n_feasible"])
+    count("sweep.dispatches", dispatches)
     # host-side finalization (all O(k) / O(variants)) — shared with the
     # on_partial snapshot path above
     return _finalize(state, out_keys, dispatches, dispatched_points,
-                     timings["eval_s"], hi - lo)
+                     disp_sp.seconds, hi - lo)

@@ -32,12 +32,12 @@ the pluggable registry (``repro.core.algorithms``).
 from __future__ import annotations
 
 import dataclasses
-import time
 import warnings
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..spans import span
 from .algorithms import get_algorithm
 from .axes import AXES, TECH_DECLARED, _tech_code
 from .batch import (evaluate_batch, grid_hooks_active, make_points,
@@ -285,54 +285,54 @@ def _sweep_impl(algorithm: str = "edgaze",
     evaluation separately — ``wall_s`` alone made first-call throughput
     look arbitrarily bad and BENCH numbers depend on call order.
     """
-    t0 = time.perf_counter()
-    variants, grids = _normalize_grids(algorithm, grids)
-    # one sweep-level hook decision (vs a per-chunk point readback): a
-    # grid at the hook defaults rides the hook-free executable
-    hooks = grid_hooks_active(grids)
-    if mesh is not None:
-        from .shard_sweep import evaluate_batch_sharded
+    with span("grid.sweep", algorithm=algorithm) as sp:
+        variants, grids = _normalize_grids(algorithm, grids)
+        # one sweep-level hook decision (vs a per-chunk point readback):
+        # a grid at the hook defaults rides the hook-free executable
+        hooks = grid_hooks_active(grids)
+        if mesh is not None:
+            from .shard_sweep import evaluate_batch_sharded
 
-    params: Dict[str, List] = {k: [] for k in ("variant",) + AXES}
-    outputs: Dict[str, List] = {}
-    variant_meta: Dict[str, Dict] = {}
-    timings = {"compile_s": 0.0, "eval_s": 0.0}
+        params: Dict[str, List] = {k: [] for k in ("variant",) + AXES}
+        outputs: Dict[str, List] = {}
+        variant_meta: Dict[str, Dict] = {}
+        timings = {"compile_s": 0.0, "eval_s": 0.0}
 
-    for variant in variants:
-        plan = lower_variant(algorithm, variant, soc_node=soc_node)
-        if strict and plan.stall_notes:
-            raise ValueError("pipeline stalls detected: "
-                             + "; ".join(plan.stall_notes))
-        grid = variant_grid(plan, grids)
-        for _start, flat in grid.chunks(chunk_size):
-            n = len(flat[AXES[0]])
-            points = make_points(plan, n, **flat)
-            if mesh is not None:
-                out = evaluate_batch_sharded(plan, points, mesh=mesh,
-                                             timings=timings, hooks=hooks)
-            else:
-                out = evaluate_batch(plan, points, timings=timings,
-                                     hooks=hooks)
-            if strict and not bool(out["feasible"].all()):
-                bad = int((~out["feasible"].astype(bool)).sum())
-                raise ValueError(
-                    f"{variant}: {bad}/{n} design points cannot meet the "
-                    f"frame rate (T_D >= T_FR, Sec. 4.1)")
-            params["variant"].append(np.full(n, variant, object))
-            for ax in AXES:
-                params[ax].append(flat[ax])
-            for k, v in out.items():
-                outputs.setdefault(k, []).append(v)
-        variant_meta[variant] = _variant_meta(plan)
+        for variant in variants:
+            plan = lower_variant(algorithm, variant, soc_node=soc_node)
+            if strict and plan.stall_notes:
+                raise ValueError("pipeline stalls detected: "
+                                 + "; ".join(plan.stall_notes))
+            grid = variant_grid(plan, grids)
+            for _start, flat in grid.chunks(chunk_size):
+                n = len(flat[AXES[0]])
+                points = make_points(plan, n, **flat)
+                if mesh is not None:
+                    out = evaluate_batch_sharded(plan, points, mesh=mesh,
+                                                 timings=timings,
+                                                 hooks=hooks)
+                else:
+                    out = evaluate_batch(plan, points, timings=timings,
+                                         hooks=hooks)
+                if strict and not bool(out["feasible"].all()):
+                    bad = int((~out["feasible"].astype(bool)).sum())
+                    raise ValueError(
+                        f"{variant}: {bad}/{n} design points cannot meet "
+                        f"the frame rate (T_D >= T_FR, Sec. 4.1)")
+                params["variant"].append(np.full(n, variant, object))
+                for ax in AXES:
+                    params[ax].append(flat[ax])
+                for k, v in out.items():
+                    outputs.setdefault(k, []).append(v)
+            variant_meta[variant] = _variant_meta(plan)
+        params = {k: np.concatenate(v) if k != "variant"
+                  else np.concatenate(v).astype(str)
+                  for k, v in params.items()}
+        outputs = {k: np.concatenate(v) for k, v in outputs.items()}
 
     return SweepResult(
-        algorithm=algorithm,
-        params={k: np.concatenate(v) if k != "variant"
-                else np.concatenate(v).astype(str)
-                for k, v in params.items()},
-        outputs={k: np.concatenate(v) for k, v in outputs.items()},
-        variant_meta=variant_meta,
-        wall_s=time.perf_counter() - t0,
+        algorithm=algorithm, params=params, outputs=outputs,
+        variant_meta=variant_meta, wall_s=sp.seconds,
         compile_s=timings["compile_s"], eval_s=timings["eval_s"])
 
 

@@ -28,7 +28,6 @@ compiles exactly one streaming step executable (tests/test_explore.py).
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -40,6 +39,7 @@ from ..core.shard_sweep import (StreamResult, _stream_impl,
                                 best_by_algorithm_summaries,
                                 stream_cache_info)
 from ..core.sweep import SweepResult, _sweep_impl
+from ..spans import span, traced
 from .space import DesignSpace
 
 #: engine names accepted by :func:`explore`
@@ -144,73 +144,75 @@ def _cache_snapshot() -> Dict[str, Dict]:
 def _grid_explore(space: DesignSpace, engine: str, *, k, metric,
                   chunk_size, mesh, strict) -> ExploreResult:
     """Grid engines: per-algorithm full tables -> unified result."""
-    t0 = time.perf_counter()
-    chunk = ((chunk_size or _DEFAULT_CHUNK) if engine == "chunked"
-             else None)
-    sweep_results: Dict[str, SweepResult] = {}
-    for algo in space.algorithms:
-        sweep_results[algo] = _sweep_impl(
-            algo, space.grids, soc_node=space.soc_node, strict=strict,
-            chunk_size=chunk, mesh=mesh)
+    with span("grid.explore") as sp:
+        chunk = ((chunk_size or _DEFAULT_CHUNK) if engine == "chunked"
+                 else None)
+        sweep_results: Dict[str, SweepResult] = {}
+        for algo in space.algorithms:
+            sweep_results[algo] = _sweep_impl(
+                algo, space.grids, soc_node=space.soc_node, strict=strict,
+                chunk_size=chunk, mesh=mesh)
 
-    n_var = space.n_var
-    # the concatenated per-algorithm tables ARE the variant-major flat
-    # index space: algorithms in space order, variants in slot order,
-    # n_var C-order rows per variant — same layout the codec decodes
-    metric_all = np.concatenate(
-        [np.asarray(sweep_results[a].outputs[metric], np.float64)
-         for a in space.algorithms])
-    feas_all = np.concatenate(
-        [sweep_results[a].outputs["feasible"].astype(bool)
-         for a in space.algorithms])
-    assert len(metric_all) == space.n_points, (len(metric_all),
-                                               space.n_points)
+        n_var = space.n_var
+        # the concatenated per-algorithm tables ARE the variant-major
+        # flat index space: algorithms in space order, variants in slot
+        # order, n_var C-order rows per variant — same layout the codec
+        # decodes
+        metric_all = np.concatenate(
+            [np.asarray(sweep_results[a].outputs[metric], np.float64)
+             for a in space.algorithms])
+        feas_all = np.concatenate(
+            [sweep_results[a].outputs["feasible"].astype(bool)
+             for a in space.algorithms])
+        assert len(metric_all) == space.n_points, (len(metric_all),
+                                                   space.n_points)
 
-    # ----- per-variant summaries (label convention == streaming) ----------
-    # argmin points come from the result tables, not the codec: decode()
-    # would re-touch the lowering cache and skew its hit accounting
-    summaries: Dict[str, Dict] = {}
-    slot = 0
-    for algo in space.algorithms:
-        res = sweep_results[algo]
-        for v in range(len(res) // n_var):
-            sl = slice(v * n_var, (v + 1) * n_var)
-            vals = np.asarray(res.outputs[metric], np.float64)[sl]
-            feas = res.outputs["feasible"].astype(bool)[sl]
-            nf = int(feas.sum())
-            if nf:
-                amin = int(np.argmin(np.where(feas, vals, np.inf)))
-                point = {ax: float(res.params[ax][v * n_var + amin])
-                         for ax in AXES}
-            else:
-                amin, point = -1, None
-            summaries[space.label(slot)] = dict(
-                n=n_var, n_feasible=nf,
-                metric_min=float(vals[feas].min()) if nf
-                else float("inf"),
-                metric_mean=float(vals[feas].mean()) if nf
-                else float("nan"),
-                argmin_index=amin, argmin_point=point)
-            slot += 1
+        # ----- per-variant summaries (label convention == streaming) ------
+        # argmin points come from the result tables, not the codec:
+        # decode() would re-touch the lowering cache and skew its hit
+        # accounting
+        summaries: Dict[str, Dict] = {}
+        slot = 0
+        for algo in space.algorithms:
+            res = sweep_results[algo]
+            for v in range(len(res) // n_var):
+                sl = slice(v * n_var, (v + 1) * n_var)
+                vals = np.asarray(res.outputs[metric], np.float64)[sl]
+                feas = res.outputs["feasible"].astype(bool)[sl]
+                nf = int(feas.sum())
+                if nf:
+                    amin = int(np.argmin(np.where(feas, vals, np.inf)))
+                    point = {ax: float(res.params[ax][v * n_var + amin])
+                             for ax in AXES}
+                else:
+                    amin, point = -1, None
+                summaries[space.label(slot)] = dict(
+                    n=n_var, n_feasible=nf,
+                    metric_min=float(vals[feas].min()) if nf
+                    else float("inf"),
+                    metric_mean=float(vals[feas].mean()) if nf
+                    else float("nan"),
+                    argmin_index=amin, argmin_point=point)
+                slot += 1
 
-    # ----- global top-k rows (full output schema from the tables) ---------
-    masked = np.where(feas_all, metric_all, np.inf)
-    order = np.argsort(masked, kind="stable")[:k]
-    algo_rows = np.cumsum([0] + [len(sweep_results[a])
-                                 for a in space.algorithms])
-    rows: List[Dict] = []
-    for gi in order:
-        if not np.isfinite(masked[gi]):
-            break
-        ai = int(np.searchsorted(algo_rows, gi, side="right") - 1)
-        algo = space.algorithms[ai]
-        res = sweep_results[algo]
-        r = res.row(int(gi - algo_rows[ai]))
-        row = dict(variant=str(r.pop("variant")), algorithm=algo,
-                   index=int(gi) % n_var)
-        row.update({ax: float(r[ax]) for ax in AXES})
-        row.update({key: float(r[key]) for key in OUT_KEYS})
-        rows.append(row)
+        # ----- global top-k rows (full output schema from the tables) -----
+        masked = np.where(feas_all, metric_all, np.inf)
+        order = np.argsort(masked, kind="stable")[:k]
+        algo_rows = np.cumsum([0] + [len(sweep_results[a])
+                                     for a in space.algorithms])
+        rows: List[Dict] = []
+        for gi in order:
+            if not np.isfinite(masked[gi]):
+                break
+            ai = int(np.searchsorted(algo_rows, gi, side="right") - 1)
+            algo = space.algorithms[ai]
+            res = sweep_results[algo]
+            r = res.row(int(gi - algo_rows[ai]))
+            row = dict(variant=str(r.pop("variant")), algorithm=algo,
+                       index=int(gi) % n_var)
+            row.update({ax: float(r[ax]) for ax in AXES})
+            row.update({key: float(r[key]) for key in OUT_KEYS})
+            rows.append(row)
 
     chunks_per_variant = (1 if chunk is None
                           else -(-n_var // max(int(chunk), 1)))
@@ -220,7 +222,7 @@ def _grid_explore(space: DesignSpace, engine: str, *, k, metric,
         n_variants=space.n_variants,
         n_devices=int(mesh.devices.size) if mesh is not None else 1,
         chunk_size=chunk, topk=rows, summaries=summaries,
-        wall_s=time.perf_counter() - t0,
+        wall_s=sp.seconds,
         compile_s=sum(r.compile_s for r in sweep_results.values()),
         eval_s=sum(r.eval_s for r in sweep_results.values()),
         dispatches=space.n_variants * chunks_per_variant, superchunk=1,
@@ -229,7 +231,6 @@ def _grid_explore(space: DesignSpace, engine: str, *, k, metric,
 
 
 def _stream_to_explore(space: DesignSpace, st: StreamResult, *,
-                       wall_s: Optional[float] = None,
                        campaign: Optional[Dict] = None) -> ExploreResult:
     """Wrap a (possibly merged) :class:`StreamResult` as the unified
     :class:`ExploreResult` surface."""
@@ -238,8 +239,7 @@ def _stream_to_explore(space: DesignSpace, st: StreamResult, *,
         n_points=st.n_points, n_feasible=st.n_feasible,
         n_variants=st.n_variants, n_devices=st.n_devices,
         chunk_size=st.chunk_size, topk=st.topk, summaries=st.summaries,
-        wall_s=st.wall_s if wall_s is None else wall_s,
-        compile_s=st.compile_s, eval_s=st.eval_s,
+        wall_s=st.wall_s, compile_s=st.compile_s, eval_s=st.eval_s,
         dispatches=st.dispatches, superchunk=st.superchunk,
         occupancy=st.occupancy, cache=_cache_snapshot(),
         stream_result=st, campaign=campaign, backend=st.backend)
@@ -266,6 +266,7 @@ def _validate_request(k, chunk_size) -> None:
                              f"dispatch), got {chunk_size}")
 
 
+@traced("explore")
 def explore(space: DesignSpace, *, k: int = 16, metric: str = "total_j",
             engine: str = "auto", chunk_size: Optional[int] = None,
             mesh=None, strict: bool = False, block_points: int = 4096,
@@ -380,12 +381,10 @@ def explore(space: DesignSpace, *, k: int = 16, metric: str = "total_j",
         raise ValueError("strict=True requires a grid engine "
                          "('monolithic' or 'chunked'); the streaming "
                          "engines mask infeasible points instead")
-    t0 = time.perf_counter()
     st = _stream_impl(
         list(space.algorithms), space.grids, soc_node=space.soc_node,
         chunk_size=chunk_size or _DEFAULT_CHUNK, metric=metric, k=k,
         mesh=mesh, block_points=block_points, progress=progress,
         index_range=index_range, pipeline_depth=pipeline_depth,
         engine=engine, superchunk=superchunk, backend=backend)
-    return _stream_to_explore(space, st,
-                              wall_s=time.perf_counter() - t0)
+    return _stream_to_explore(space, st)

@@ -50,6 +50,10 @@ from jax.experimental.pallas import tpu as pltpu
 from .grid_decode import decode_axis_values, grid_strides
 from .runtime import resolve_interpret
 
+#: the megakernel's name in the compiled program (the Mosaic custom
+#: call's kernel name), so a profile or HLO text finds it by name
+KERNEL_NAME = "camj_megakernel"
+
 
 def _fused_kernel(bounds_ref, table_ref, row_ref, cv_ref, cl_ref, st_ref,
                   *, compute, metric, axis_names, shape, strides, n_var,
@@ -149,5 +153,6 @@ def fused_sweep_block(table2: jax.Array, row: jax.Array, start, low, limit,
             jax.ShapeDtypeStruct((nb, 1, 2), jnp.float32),
         ],
         interpret=interpret,
+        name=KERNEL_NAME,
     )(bounds, table2, row.reshape(1, -1))
     return cv[:, 0], cl[:, 0], st[:, 0, 0], st[:, 0, 1]

@@ -21,6 +21,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.compat import auto_axis_types
+from repro.kernels.fused_sweep import KERNEL_NAME
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
                                 "benchmarks"))
@@ -146,7 +147,10 @@ def test_superchunk_step_compiles_for_v5e(prep, topo, n_chips, steer_tpu,
         scalar, scalar, scalar, scalar, spec(prep.table2),
         jax.tree.map(spec, prep.bank.arrays),
         jax.tree.map(spec, state0)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    # the profile reduction can find the megakernel by its name
+    assert KERNEL_NAME in hlo
 
 
 def test_grid_decode_compiles_for_v5e(prep, one_chip, no_persistent_cache):
