@@ -157,11 +157,11 @@ def _same_summaries(a, b, mean_rel: float) -> float:
 
 
 def _decode_check(grids, n: int = 1 << 16) -> int:
-    """The device decode (the megakernel's ``decode_axis_values``, here
-    through the standalone ``grid_decode`` kernel) against the host
-    ``ChunkedGrid``, bit for bit, on windows at the start, across a
-    variant boundary and at the end of the flat index space.  Returns
-    the number of points checked."""
+    """The staged engine's device decode (``grid_decode``, its one-hot
+    ``decode_axis_values``) against the host ``ChunkedGrid``, bit for
+    bit, on windows at the start, across a variant boundary and at the
+    end of the flat index space.  Returns the number of points
+    checked."""
     prep = _prepare_stream(list(ALGORITHMS), grids)
     n = min(n, prep.total // 2)
     shape = prep.vgrids[0].shape

@@ -63,6 +63,7 @@ surfaced in :func:`stream_cache_info`.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import threading
 import warnings
@@ -552,16 +553,16 @@ def _fused_step(bank: PlanBank, mesh, metric: str, k: int, chunk: int,
     merge path is backend-independent.
     """
     V = bank.dims.n_variants
-    total = V * n_var
     ndev = int(mesh.devices.size)
     assert chunk % ndev == 0, (chunk, ndev)
     shard = chunk // ndev
     if backend == "xla":
         # XLA fuses across block boundaries itself; bp only bounds the
         # top_k reduction width.  The jnp lane always uses exact gathers
-        # (the one-hot matmul decode is a Mosaic-only idiom).
+        # (the physics' one-hot matmul forms are a Mosaic-only idiom).
         bp = max(min(block_points, shard), 1)
         compute = build_coeff_compute(bank.dims, exact=True)
+        block_fn = fused_sweep_block_xla
     else:
         interpret = resolve_interpret(None)
         # one kernel block per shard on the interpreter (grid steps only
@@ -569,27 +570,20 @@ def _fused_step(bank: PlanBank, mesh, metric: str, k: int, chunk: int,
         # block_points
         bp = shard if interpret else max(min(block_points, shard), 1)
         compute = build_coeff_compute(bank.dims, exact=interpret)
+        block_fn = functools.partial(fused_sweep_block, interpret=interpret)
     kk = min(k, shard)
     out_keys = list(OUT_KEYS)
     if metric not in out_keys:
         raise KeyError(f"unknown stream metric {metric!r}; valid: "
                        f"{out_keys}")
 
-    def shard_body(start, low, limit, table2, row):
+    def shard_body(start, low, limit, table, row):
         six = jax.lax.axis_index("batch").astype(idx_dtype)
         s0 = start + six * shard
-        if backend == "xla":
-            cv, cl, sums, counts = fused_sweep_block_xla(
-                table2, row, s0, low, limit, compute=compute,
-                metric=metric, axis_names=tuple(AXES), shape=tuple(shape),
-                n_var=n_var, total=total, chunk=shard, lmax=lmax,
-                block_points=bp, kk=kk, idx_dtype=idx_dtype)
-        else:
-            cv, cl, sums, counts = fused_sweep_block(
-                table2, row, s0, low, limit, compute=compute,
-                metric=metric, axis_names=AXES, shape=shape, n_var=n_var,
-                total=total, chunk=shard, lmax=lmax, block_points=bp,
-                kk=kk, idx_dtype=idx_dtype, interpret=interpret)
+        cv, cl, sums, counts = block_fn(
+            table, row, s0, low, limit, compute=compute,
+            metric=metric, axis_names=tuple(AXES), shape=tuple(shape),
+            chunk=shard, block_points=bp, kk=kk, idx_dtype=idx_dtype)
         # fold the (G, kk) block candidates to this shard's top-kk
         with jax.named_scope("topk_merge"):
             neg, pos = jax.lax.top_k(-cv.reshape(-1), kk)
@@ -616,10 +610,14 @@ def _fused_step(bank: PlanBank, mesh, metric: str, k: int, chunk: int,
             r = c - vi * cpv
             start = (vi * n_var + r * chunk).astype(idx_dtype)
             limit = jnp.minimum(hi, (vi + 1) * n_var).astype(idx_dtype)
+            # the chunk's variant picks its coefficient row and its axis
+            # table (a shard past the variant's end is all masked by limit)
             v = jnp.clip(vi, 0, V - 1).astype(jnp.int32)
             row = jax.lax.dynamic_index_in_dim(
                 bank_arrays["fused"], v, 0, keepdims=True)     # (1, W)
-            parts = sharded(start, low, limit, table2, row)
+            table = jax.lax.dynamic_slice_in_dim(
+                table2, v * lmax, lmax, axis=1)         # (n_axes, Lmax)
+            parts = sharded(start, low, limit, table, row)
             with jax.named_scope("state_fold"):
                 return (_merge_candidates(parts, v, st, k, False),
                         parts["counts"])

@@ -12,8 +12,17 @@ collapses to O(k) scalars.
 This kernel fuses the whole per-chunk pipeline into ONE pass per block:
 
 1. **decode** — the block's flat stream indices expand into axis-value
-   vectors in VMEM via the shared ``grid_decode.decode_axis_values``
-   helper (div/mod against static strides + tiny axis-table lookup);
+   vectors in VMEM (:func:`decode_block`): static div/mod against the
+   grid strides gives each axis's index, and a chain of selects over
+   that axis's static length picks its value out of the chunk variant's
+   ``(n_axes, lmax)`` table, which rides in SMEM.  Chunks are
+   variant-uniform, so the caller slices that table; the kernel never
+   derives a variant.  A select copies a table entry, so decoded values
+   are bit-identical to the host gather.  The staged engine's
+   ``grid_decode`` keeps its own one-hot lookup (its chunks may span
+   variants); the fused == XLA twin == staged parity tests in
+   ``tests/test_fused_sweep.py`` and the decode tests there against the
+   host grid keep the two from drifting;
 2. **evaluate** — the banked Eq. 1-17 physics runs on the decoded block
    through the coefficient-form compute function
    (``repro.core.batch.build_coeff_compute``), the chunk's fused ``(W,)``
@@ -33,10 +42,13 @@ Masking follows the streaming driver's contract: a point is valid iff
 otherwise double-count the next shard's points).
 
 Mosaic's rules shape the layout: ``start`` / ``low`` / ``limit`` arrive
-as one SMEM vector, each block writes whole ``(1, n)`` rows of
+as one SMEM vector and the chunk's axis table as another (its scalars
+broadcast into the selects), each block writes whole ``(1, n)`` rows of
 ``(G, 1, n)`` outputs (the block's trailing dims then equal the array's),
 and the kernel holds no 64-bit value, so the compiled kernel takes int32
 indices only.  ``tests/test_tpu_compile.py`` compiles it for a TPU v5e.
+Compiled and interpreted kernels run the same decode, so the CPU tests
+exercise the code the chip runs.
 """
 from __future__ import annotations
 
@@ -47,7 +59,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .grid_decode import decode_axis_values, grid_strides
+from .grid_decode import grid_strides
 from .runtime import resolve_interpret
 
 #: the megakernel's name in the compiled program (the Mosaic custom
@@ -55,20 +67,47 @@ from .runtime import resolve_interpret
 KERNEL_NAME = "camj_megakernel"
 
 
-def _fused_kernel(bounds_ref, table_ref, row_ref, cv_ref, cl_ref, st_ref,
-                  *, compute, metric, axis_names, shape, strides, n_var,
-                  total, chunk, block, kk, idx_dtype, n_variants, lmax,
-                  gather):
+def decode_block(bounds_ref, tab_ref, *, shape, strides, lmax, chunk,
+                 block, idx_dtype):
+    """The validity mask and decoded axis values of this grid step's block.
+
+    ``bounds_ref`` holds ``start``, ``low`` and ``limit``; ``tab_ref``
+    the chunk variant's axis table flattened to ``(n_axes * lmax,)``
+    (axis ``a`` holds its values at ``a * lmax`` onwards; padding is
+    never read).  Returns the ``(block,)`` mask and a list of ``(block,)``
+    f32 vectors in :class:`~repro.core.sweep.ChunkedGrid` axis order.
+    The axis index ``(off // stride) % size`` repeats with the variant's
+    span, so it needs no offset from the variant's start and stays in
+    range past its end (masked points) without a clamp.  Each value is
+    a chain of selects over the axis's static length: an exact copy of a
+    table entry.
+    """
     i = pl.program_id(0)
     lane = jax.lax.broadcasted_iota(idx_dtype, (1, block), 1)
     pos = i * block + lane                      # position within the chunk
     off = bounds_ref[0] + pos
     valid = ((off >= bounds_ref[1]) & (off < bounds_ref[2])
              & (pos < chunk))[0]
-    offc = jnp.minimum(off, total - 1)          # clamp tail for the decode
-    vals, _vid = decode_axis_values(
-        offc, table_ref[...], shape=shape, strides=strides, n_var=n_var,
-        n_variants=n_variants, lmax=lmax, gather=gather)
+    # lax, not jnp: off >= 0, so truncating div/rem equal the floored
+    # ones without their sign fix-ups, and the ~100 selects trace as bare
+    # primitives (jnp's wrappers made them a visible share of set-up)
+    vals = []
+    for a, (n, stride) in enumerate(zip(shape, strides)):
+        val = jax.lax.broadcast(tab_ref[a * lmax], off.shape)
+        if n > 1:
+            idx = jax.lax.rem(jax.lax.div(off, idx_dtype(stride)),
+                              idx_dtype(n))
+            for j in range(1, n):
+                val = jax.lax.select(
+                    jax.lax.eq(idx, idx_dtype(j)),
+                    jax.lax.broadcast(tab_ref[a * lmax + j], off.shape), val)
+        vals.append(val[0])
+    return valid, vals
+
+
+def _fused_kernel(bounds_ref, tab_ref, row_ref, cv_ref, cl_ref, st_ref,
+                  *, compute, metric, axis_names, kk, block, **decode):
+    valid, vals = decode_block(bounds_ref, tab_ref, block=block, **decode)
     out = compute(row_ref[0, :], dict(zip(axis_names, vals)))
     ok = (out["feasible"] & valid)[None, :]
     mv = out[metric].astype(jnp.float32)[None, :]
@@ -99,27 +138,27 @@ def _fused_kernel(bounds_ref, table_ref, row_ref, cv_ref, cl_ref, st_ref,
         jax.lax.broadcasted_iota(jnp.int32, (1, 2), 1) == 0, s, n)
 
 
-def fused_sweep_block(table2: jax.Array, row: jax.Array, start, low, limit,
+def fused_sweep_block(table: jax.Array, row: jax.Array, start, low, limit,
                       *, compute, metric: str, axis_names, shape,
-                      n_var: int, total: int, chunk: int, lmax: int,
-                      block_points: int = 4096, kk: int = 16,
-                      idx_dtype=jnp.int32, interpret: bool = None):
+                      chunk: int, block_points: int = 4096,
+                      kk: int = 16, idx_dtype=jnp.int32,
+                      interpret: bool = None):
     """Decode + evaluate + reduce flat indices ``[start, start + chunk)``.
 
-    ``table2`` is the pre-transposed ``(n_axes, n_variants * lmax)`` f32
-    axis-value bank, ``row`` the chunk's ``(1, W)`` fused coefficient row
-    (chunks are variant-uniform) and ``compute`` the coefficient-form
-    evaluator from :func:`repro.core.batch.build_coeff_compute` (its
-    ``exact`` flag must match this call's resolved ``interpret`` mode).
-    Returns ``(cand_v, cand_l, sums, counts)``: per-block ascending
-    candidate metric values ``(G, kk)`` (+inf-padded), their block-LOCAL
-    int32 indices ``(G, kk)`` (global flat index = ``start + g *
-    block_points + cand_l``), and the masked per-block metric sums /
-    valid counts ``(G,)``.
+    ``table`` is the chunk variant's ``(n_axes, lmax)`` f32 axis-value
+    table (axis ``a`` holds its first ``shape[a]`` entries; chunks are
+    variant-uniform), ``row`` the variant's ``(1, W)`` fused coefficient
+    row and ``compute`` the coefficient-form evaluator from
+    :func:`repro.core.batch.build_coeff_compute` (its ``exact`` flag must
+    match this call's resolved ``interpret`` mode).  Returns ``(cand_v,
+    cand_l, sums, counts)``: per-block ascending candidate metric values
+    ``(G, kk)`` (+inf-padded), their block-LOCAL int32 indices ``(G,
+    kk)`` (global flat index = ``start + g * block_points + cand_l``),
+    and the masked per-block metric sums / valid counts ``(G,)``.
     """
-    n_axes, vl = table2.shape
-    assert n_axes == len(shape) == len(axis_names), (table2.shape, shape)
-    assert vl % lmax == 0, (table2.shape, lmax)
+    n_axes, lmax = table.shape
+    assert n_axes == len(shape) == len(axis_names), (table.shape, shape)
+    assert max(shape) <= lmax, (table.shape, shape)
     bp = max(min(block_points, chunk), 1)
     nb = -(-chunk // bp)
     interpret = resolve_interpret(interpret)
@@ -132,14 +171,13 @@ def fused_sweep_block(table2: jax.Array, row: jax.Array, start, low, limit,
     cv, cl, st = pl.pallas_call(
         functools.partial(
             _fused_kernel, compute=compute, metric=metric,
-            axis_names=tuple(axis_names), shape=tuple(shape),
-            strides=grid_strides(shape), n_var=n_var, total=total,
-            chunk=chunk, block=bp, kk=kk, idx_dtype=idx_dtype,
-            n_variants=vl // lmax, lmax=lmax, gather=interpret),
+            axis_names=tuple(axis_names), kk=kk, shape=tuple(shape),
+            strides=grid_strides(shape), lmax=lmax, chunk=chunk, block=bp,
+            idx_dtype=idx_dtype),
         grid=(nb,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((n_axes, vl), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, row.shape[-1]), lambda i: (0, 0)),
         ],
         out_specs=[
@@ -154,5 +192,5 @@ def fused_sweep_block(table2: jax.Array, row: jax.Array, start, low, limit,
         ],
         interpret=interpret,
         name=KERNEL_NAME,
-    )(bounds, table2, row.reshape(1, -1))
+    )(bounds, table.reshape(-1), row.reshape(1, -1))
     return cv[:, 0], cl[:, 0], st[:, 0, 0], st[:, 0, 1]

@@ -8,8 +8,10 @@ ordinary element-wise math + a bounded reduction, exactly the program
 shape XLA already compiles well on CPU and GPU.  This module is that
 body re-expressed in pure ``jnp``:
 
-1. **decode** — the same ``grid_decode.decode_axis_values`` stride math
-   (``gather=True``: plain XLA gathers, no one-hot MXU idiom needed);
+1. **decode** — the kernel's stride math on the same flat indices, then
+   a plain XLA gather from the chunk variant's ``(n_axes, lmax)`` table
+   (the kernel's select chain is the Mosaic form of that gather, and the
+   parity tests hold the two together);
 2. **evaluate** — the same coefficient-form Eq. 1-17 compute function
    from ``repro.core.batch.build_coeff_compute(dims, exact=True)``, the
    chunk's fused ``(W,)`` coefficient row broadcasting across the block;
@@ -29,8 +31,8 @@ the identical merge path, and the rel-1e-6 parity chain (XLA == Pallas
 Validity masking is the shared streaming contract: a point counts iff
 ``low <= flat < limit`` AND it lies inside this call's ``chunk`` span
 (blocks pad up to ``block_points``; spillover positions would otherwise
-double-count the next shard's points).  Tail indices clamp to
-``total - 1`` before decoding, exactly like the kernel.
+double-count the next shard's points).  Past the variant's end the axis
+indices wrap around, exactly like the kernel's.
 
 The function is jitted (shape-static args) for the same reason
 ``grid_decode`` is: it also runs nested inside the already-jitted
@@ -46,16 +48,15 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .grid_decode import decode_axis_values, grid_strides
+from .grid_decode import grid_strides
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "compute", "metric", "axis_names", "shape", "n_var", "total", "chunk",
-    "lmax", "block_points", "kk", "idx_dtype"))
-def fused_sweep_block_xla(table2: jax.Array, row: jax.Array, start, low,
+    "compute", "metric", "axis_names", "shape", "chunk", "block_points",
+    "kk", "idx_dtype"))
+def fused_sweep_block_xla(table: jax.Array, row: jax.Array, start, low,
                           limit, *, compute, metric: str, axis_names,
-                          shape, n_var: int, total: int, chunk: int,
-                          lmax: int, block_points: int = 4096,
+                          shape, chunk: int, block_points: int = 4096,
                           kk: int = 16, idx_dtype=jnp.int32):
     """Decode + evaluate + reduce flat indices ``[start, start + chunk)``.
 
@@ -65,9 +66,9 @@ def fused_sweep_block_xla(table2: jax.Array, row: jax.Array, start, low,
     come from ``build_coeff_compute(dims, exact=True)`` (plain gathers;
     the one-hot ``exact=False`` form is a Mosaic-only idiom).
     """
-    n_axes, vl = table2.shape
-    assert n_axes == len(shape) == len(axis_names), (table2.shape, shape)
-    assert vl % lmax == 0, (table2.shape, lmax)
+    n_axes, lmax = table.shape
+    assert n_axes == len(shape) == len(axis_names), (table.shape, shape)
+    assert max(shape) <= lmax, (table.shape, shape)
     bp = max(min(block_points, chunk), 1)
     nb = -(-chunk // bp)
 
@@ -76,10 +77,9 @@ def fused_sweep_block_xla(table2: jax.Array, row: jax.Array, start, low,
     valid = ((off >= jnp.asarray(low, idx_dtype))
              & (off < jnp.asarray(limit, idx_dtype))
              & (pos < chunk))[0]
-    offc = jnp.minimum(off, total - 1)          # clamp tail; mask decides
-    vals, _vid = decode_axis_values(
-        offc, table2, shape=tuple(shape), strides=grid_strides(shape),
-        n_var=n_var, n_variants=vl // lmax, lmax=lmax, gather=True)
+    vals = [jnp.take(table[a], (off[0] // stride) % n)
+            for a, (n, stride) in enumerate(zip(shape,
+                                                grid_strides(shape)))]
     out = compute(row.reshape(-1), dict(zip(axis_names, vals)))
     ok = out["feasible"] & valid
     mv = out[metric].astype(jnp.float32)

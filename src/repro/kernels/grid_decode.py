@@ -45,9 +45,15 @@ def decode_axis_values(off, table, *, shape, strides, n_var, n_variants,
     n_variants * lmax)`` axis-value bank loaded from a kernel ref.
     Returns ``(vals, vid32)``: a list of ``(block,)`` f32 axis-value
     vectors in :class:`~repro.core.sweep.ChunkedGrid` axis order and the
-    ``(1, block)`` int32 variant ids.  Shared by the standalone
-    ``grid_decode`` kernel and the fused sweep megakernel
-    (``repro.kernels.fused_sweep``) so the two can never drift.
+    ``(1, block)`` int32 variant ids.  The staged engine's decode: its
+    chunks may span variants, and it returns per-point variant ids.  The
+    fused megakernel's chunks are variant-uniform, so it decodes with
+    selects over its chunk's own table
+    (``repro.kernels.fused_sweep.decode_block``) instead; the
+    fused == staged parity tests in ``tests/test_fused_sweep.py`` and
+    both decodes' bit-for-bit tests against the host ``ChunkedGrid``
+    (``tests/test_grid_decode.py``, ``tests/test_fused_sweep.py``) keep
+    the two from drifting.
     """
     vid = off // n_var
     local = off - vid * n_var

@@ -52,46 +52,6 @@ def _engines_case(grids, *, algorithm="edgaze", chunk_size=16, k=5,
 # ---------------------------------------------------------------------------
 # megakernel == staged pipeline (fixed + hypothesis-driven shapes)
 # ---------------------------------------------------------------------------
-def test_fused_matches_staged_fixed_cases():
-    """Deterministic coverage: multi-variant, tail chunks, tiny chunks."""
-    grids = {"variant": ["2d_in", "3d_in"],
-             "cis_node": [130.0, 65.0, 28.0],
-             "frame_rate": [15.0, 30.0],
-             "sys_rows": [8.0, 16.0, 32.0],
-             "active_fraction_scale": [0.25, 1.0]}
-    fused, staged = _engines_case(grids, chunk_size=13, k=7)
-    # the fused driver folds many chunks into one scan dispatch
-    assert fused.dispatches < staged.dispatches
-    # non-divisible chunking never drops nor double-counts a point
-    assert fused.n_points == 2 * 3 * 2 * 3 * 2
-
-
-def test_fused_matches_staged_multi_algorithm():
-    grids = {"variant": ["2d_in", "3d_in"],
-             "cis_node": [130.0, 65.0],
-             "frame_rate": [15.0, 60.0],
-             "sys_rows": [8.0, 32.0],
-             "mem_tech": ["sram_hp", "stt"]}
-    _engines_case(grids, algorithm=["edgaze", "rhythmic"], chunk_size=8,
-                  k=6)
-
-
-def test_fused_matches_staged_index_range_tails():
-    """index_range cuts landing inside chunks and inside variants — the
-    fused path masks a chunk's low side (ordinals are span-aligned, the
-    staged driver starts chunks exactly at the cut)."""
-    grids = {"variant": ["2d_in", "3d_in"],
-             "cis_node": [130.0, 65.0, 28.0],
-             "frame_rate": [15.0, 30.0],
-             "active_fraction_scale": [0.25, 1.0]}
-    total = 2 * 3 * 2 * 2
-    for lo, hi in ((0, total), (5, total - 3), (total // 2 - 1,
-                                                total // 2 + 3)):
-        fused, _staged = _engines_case(grids, chunk_size=8, k=4,
-                                       index_range=(lo, hi))
-        assert fused.n_points == hi - lo
-
-
 def test_fused_matches_staged_property():
     """Hypothesis sweep over grid shapes, chunk sizes, k and range cuts
     (skips without hypothesis, mirroring the grid_decode tests)."""
@@ -277,41 +237,57 @@ def _backend_case(grids, *, algorithm="edgaze", chunk_size=16, k=5,
     assert staged.backend == "pallas"
     _assert_stream_equal(xla, pal)
     _assert_stream_equal(xla, staged)
-    return xla, pal
+    return xla, pal, staged
 
 
-def test_backend_parity_fixed_cases():
-    grids = {"variant": ["2d_in", "3d_in"],
-             "cis_node": [130.0, 65.0, 28.0],
-             "frame_rate": [15.0, 30.0],
-             "sys_rows": [8.0, 16.0, 32.0],
-             "active_fraction_scale": [0.25, 1.0]}
-    xla, pal = _backend_case(grids, chunk_size=13, k=7)
-    assert xla.n_points == pal.n_points == 2 * 3 * 2 * 3 * 2
+_PARITY_CASES = {
+    # multi-variant, tail chunks, a chunk that divides no variant span
+    "fixed": dict(grids={"variant": ["2d_in", "3d_in"],
+                         "cis_node": [130.0, 65.0, 28.0],
+                         "frame_rate": [15.0, 30.0],
+                         "sys_rows": [8.0, 16.0, 32.0],
+                         "active_fraction_scale": [0.25, 1.0]},
+                  chunk_size=13, k=7),
+    "multi_algorithm": dict(grids={"variant": ["2d_in", "3d_in"],
+                                   "cis_node": [130.0, 65.0],
+                                   "frame_rate": [15.0, 60.0],
+                                   "sys_rows": [8.0, 32.0],
+                                   "mem_tech": ["sram_hp", "stt"]},
+                            algorithm=["edgaze", "rhythmic"],
+                            chunk_size=8, k=6),
+}
+#: index_range cuts landing inside chunks and inside variants: the fused
+#: path masks a chunk's low side (ordinals are span-aligned, the staged
+#: driver starts chunks exactly at the cut)
+_CUT_GRIDS = {"variant": ["2d_in", "3d_in"],
+              "cis_node": [130.0, 65.0, 28.0],
+              "frame_rate": [15.0, 30.0],
+              "active_fraction_scale": [0.25, 1.0]}
+_CUT_TOTAL = 2 * 3 * 2 * 2
+_PARITY_CASES.update(
+    (f"index_range_{lo}_{hi}",
+     dict(grids=_CUT_GRIDS, chunk_size=8, k=4, index_range=(lo, hi)))
+    for lo, hi in ((0, _CUT_TOTAL), (5, _CUT_TOTAL - 3),
+                   (_CUT_TOTAL // 2 - 1, _CUT_TOTAL // 2 + 3)))
+
+
+@pytest.mark.parametrize("case", sorted(_PARITY_CASES))
+def test_fused_parity(case):
+    """Both fused backends (the XLA twin and the Pallas megakernel) ==
+    the staged oracle on fixed cases; the fused driver folds many chunks
+    into one scan dispatch, and neither drops nor double-counts a point
+    under non-divisible chunking or a cut range."""
+    kw = _PARITY_CASES[case]
+    xla, pal, staged = _backend_case(**kw)
+    lo, hi = kw.get("index_range") or (0, None)
+    n = staged.n_points if hi is None else hi - lo
+    assert xla.n_points == pal.n_points == n
+    if case == "fixed":
+        assert n == 2 * 3 * 2 * 3 * 2
     # both lanes ride the same scan driver: dispatch counts agree
     assert xla.dispatches == pal.dispatches
-
-
-def test_backend_parity_multi_algorithm():
-    grids = {"variant": ["2d_in", "3d_in"],
-             "cis_node": [130.0, 65.0],
-             "frame_rate": [15.0, 60.0],
-             "mem_tech": ["sram_hp", "stt"]}
-    _backend_case(grids, algorithm=["edgaze", "rhythmic"], chunk_size=8,
-                  k=6)
-
-
-def test_backend_parity_index_range_tails():
-    grids = {"variant": ["2d_in", "3d_in"],
-             "cis_node": [130.0, 65.0, 28.0],
-             "frame_rate": [15.0, 30.0],
-             "active_fraction_scale": [0.25, 1.0]}
-    total = 2 * 3 * 2 * 2
-    for lo, hi in ((0, total), (5, total - 3),
-                   (total // 2 - 1, total // 2 + 3)):
-        xla, _pal = _backend_case(grids, chunk_size=8, k=4,
-                                  index_range=(lo, hi))
-        assert xla.n_points == hi - lo
+    if kw.get("index_range") is None:
+        assert xla.dispatches < staged.dispatches
 
 
 def test_backend_parity_property():
@@ -470,3 +446,151 @@ def test_coeff_compute_one_hot_form_matches_banked_eval():
     """The compiled-kernel form (one-hot matmul gathers and scatters in
     place of ``take`` / ``.at[].add``) meets the same parity."""
     _coeff_compute_case(exact=False)
+
+
+# ---------------------------------------------------------------------------
+# the megakernel's decode == the host grid, bit for bit
+# ---------------------------------------------------------------------------
+#: 30 points a variant (3 x 5 x 2), Ed-Gaze + Rhythmic: 8 variants
+_DECODE_GRIDS = {"cis_node": [130.0, 65.0, 28.0],
+                 "frame_rate": [15.0, 30.0, 60.0, 90.0, 120.0],
+                 "sys_rows": [8.0, 32.0]}
+_DECODE_CASES = {
+    # axes shorter than the table's lmax: each select chain stops at its
+    # axis's length and never reads the padding
+    "lmax_padding": dict(grids=_DECODE_GRIDS, chunk=30, ndev=1, block=16),
+    # swept length-1 axes beside the default vdd_scale / adc_bits
+    "length_1_axes": dict(grids={"cis_node": [65.0], "mem_tech": ["stt"],
+                                 "frame_rate": [15.0, 30.0, 60.0]},
+                          chunk=3, ndev=1, block=2),
+    # the last chunk's blocks run past the end of the flat space
+    "last_chunk_tail": dict(grids=_DECODE_GRIDS, chunk=16, ndev=1, block=6),
+    # a chunk that does not divide the variant's span: each variant's
+    # last chunk runs past its end
+    "chunk_past_variant": dict(grids=_DECODE_GRIDS, chunk=8, ndev=1,
+                               block=8),
+    # four shards a chunk (shard offsets s0), shards wholly past their
+    # variant's end, and a cut index range
+    "four_shards": dict(grids=_DECODE_GRIDS, chunk=12, ndev=4, block=2,
+                        index_range=(7, 8 * 30 - 11)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_DECODE_CASES))
+def test_megakernel_decode_matches_host_grid(case):
+    """The megakernel's decode (``decode_block``: the chunk variant's
+    table in SMEM, selects over each axis's values) gives the host
+    ``ChunkedGrid``'s values bit for bit at every valid position of
+    every chunk and shard the superchunk step walks, for every variant
+    of Ed-Gaze and Rhythmic, and marks each point of the range valid
+    exactly once."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from repro.core.shard_sweep import _prepare_stream
+    from repro.core.sweep import AXES
+    from repro.kernels.fused_sweep import decode_block
+    from repro.kernels.grid_decode import grid_strides
+    kw = _DECODE_CASES[case]
+    chunk, ndev, block = kw["chunk"], kw["ndev"], kw["block"]
+    prep = _prepare_stream(["edgaze", "rhythmic"], kw["grids"])
+    assert prep.n_variants == 8
+    assert list(prep.vgrids[0].names) == list(AXES)
+    shape, n_var, lmax = tuple(prep.vgrids[0].shape), prep.n_var, prep.lmax
+    total, n_axes, shard = prep.total, len(shape), chunk // ndev
+    nb = -(-shard // block)
+
+    def kernel(bounds_ref, tab_ref, vals_ref, valid_ref):
+        valid, vals = decode_block(
+            bounds_ref, tab_ref, shape=shape, strides=grid_strides(shape),
+            lmax=lmax, chunk=shard, block=block, idx_dtype=jnp.int32)
+        for a in range(n_axes):
+            vals_ref[a, :] = vals[a]
+        valid_ref[0, :] = valid.astype(jnp.int32)
+
+    @jax.jit
+    def decode(bounds, table):
+        return pl.pallas_call(
+            kernel, grid=(nb,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] * 2,
+            out_specs=[pl.BlockSpec((n_axes, block), lambda i: (0, i)),
+                       pl.BlockSpec((1, block), lambda i: (0, i))],
+            out_shape=[
+                jax.ShapeDtypeStruct((n_axes, nb * block), jnp.float32),
+                jax.ShapeDtypeStruct((1, nb * block), jnp.int32)],
+            interpret=True)(bounds, table.reshape(-1))
+
+    lo, hi = kw.get("index_range", (0, total))
+    cpv = -(-n_var // chunk)
+    seen = np.zeros(total, np.int64)
+    # every chunk ordinal and shard, with the bounds the superchunk step
+    # derives for them
+    for c in range(prep.n_variants * cpv):
+        vi, r = divmod(c, cpv)
+        base = vi * n_var
+        start, limit = base + r * chunk, min(hi, base + n_var)
+        table = prep.table2[:, vi * lmax:(vi + 1) * lmax]
+        for six in range(ndev):
+            s0 = start + six * shard
+            vals, valid = decode(
+                jnp.asarray([s0, lo, limit], jnp.int32), table)
+            vals = np.asarray(vals)[:, :shard]
+            valid = np.asarray(valid)[0, :shard].astype(bool)
+            off = s0 + np.arange(shard)
+            want = (off >= lo) & (off < limit)
+            np.testing.assert_array_equal(valid, want, err_msg=(c, six))
+            if not want.any():
+                continue
+            seen[off[want]] += 1
+            host = prep.vgrids[vi].chunk(int(off[want][0]) - base,
+                                         int(off[want][-1]) + 1 - base)
+            for a, name in enumerate(AXES):
+                np.testing.assert_array_equal(
+                    vals[a, want], host[name].astype(np.float32),
+                    err_msg=f"{name} in chunk {c}, shard {six}")
+    assert (seen[lo:hi] == 1).all()
+    assert not seen[:lo].any() and not seen[hi:].any()
+
+
+FOUR_SHARD_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import json, jax
+from repro.core.shard_sweep import sweep_stream
+from repro.launch.mesh import make_batch_mesh
+assert len(jax.devices()) == 4
+grids = {"cis_node": [130.0, 65.0, 28.0],
+         "frame_rate": [15.0, 30.0, 60.0, 90.0, 120.0],
+         "sys_rows": [8.0, 32.0]}
+kw = dict(chunk_size=12, k=8, index_range=(7, 8 * 30 - 11))
+out = []
+for backend, n in (("pallas", 4), ("xla", 1)):
+    res = sweep_stream(["edgaze", "rhythmic"], grids, backend=backend,
+                       mesh=make_batch_mesh(n), **kw)
+    assert (res.n_devices, res.chunk_size) == (n, 12), (n, res.chunk_size)
+    out.append(res.to_payload())
+print(json.dumps(out))
+"""
+
+
+def test_megakernel_four_shards_match_one_device():
+    """The Pallas megakernel on four devices, where a chunk's last shards
+    lie wholly past their variant's end (12-point chunks, 30-point
+    variants), gives the XLA twin's one-device result."""
+    import json
+    import os
+    import subprocess
+    import sys
+    from repro.core.shard_sweep import StreamResult
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, PYTHONPATH=src + os.pathsep
+               + os.environ.get("PYTHONPATH", ""))
+    proc = subprocess.run([sys.executable, "-c", FOUR_SHARD_SCRIPT],
+                          env=env, capture_output=True, text=True,
+                          timeout=560)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    pal, xla = (StreamResult.from_payload(p) for p in
+                json.loads(proc.stdout.strip().splitlines()[-1]))
+    assert pal.n_points == 8 * 30 - 18
+    _assert_stream_equal(pal, xla)

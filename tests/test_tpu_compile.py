@@ -2,7 +2,10 @@
 
 The sweep's Mosaic kernels and the superchunk step compile here for a
 described ``v5e:2x2`` topology at the mega grid's real widths (10 axes,
-8 variants, ~1.26e7 points, ``block_points=4096``).  A compile that the
+8 variants, ~1.26e7 points, ``block_points=4096``); the megakernel and
+the steps also at the camj-study space's (five 16-value axes, so the
+decode's select chains and the SMEM axis table are as long as the chip
+benchmark's).  A compile that the
 TPU compiler refuses fails here at no chip time; a compile that passes
 is not a chip run and says nothing about results or speed.
 
@@ -31,6 +34,24 @@ BLOCK = 4096
 CHUNK = 1 << 18
 K = 16
 
+#: the widths of the chip benchmark's camj-study space
+#: (``benchmarks/chip/configs/camj-study.json``): every value of the
+#: three categorical axes and 16 values on each other swept axis
+STUDY_GRIDS = {
+    "cis_node": [130.0, 110.0, 90.0, 80.0, 65.0, 55.0, 45.0, 40.0, 32.0,
+                 28.0, 22.0, 16.0, 14.0],
+    "soc_node": [14.0, 22.0, 28.0],
+    "mem_tech": ["sram", "sram_hp", "stt"],
+    "sys_rows": list(np.linspace(4.0, 128.0, 16)),
+    "sys_cols": list(np.linspace(4.0, 128.0, 16)),
+    "frame_rate": list(np.linspace(15.0, 240.0, 16)),
+    "active_fraction_scale": list(np.linspace(0.1, 1.0, 16)),
+    "pixel_pitch_um": list(np.linspace(2.0, 6.0, 16)),
+}
+#: grids and metric of each width the rehearsals compile at
+WIDTHS = {"mega": (MEGA_GRIDS, "total_j"),
+          "camj-study": (STUDY_GRIDS, "density_mw_mm2")}
+
 
 @pytest.fixture(scope="module")
 def topo():
@@ -49,9 +70,15 @@ def one_chip(topo):
 
 
 @pytest.fixture(scope="module")
-def prep():
+def preps():
     from repro.core.shard_sweep import _prepare_stream
-    return _prepare_stream(["edgaze", "rhythmic"], MEGA_GRIDS)
+    return {name: _prepare_stream(["edgaze", "rhythmic"], grids)
+            for name, (grids, _metric) in WIDTHS.items()}
+
+
+@pytest.fixture
+def prep(preps):
+    return preps["mega"]
 
 
 @pytest.fixture
@@ -77,35 +104,36 @@ def steer_tpu(monkeypatch):
     monkeypatch.setattr(runtime, "_BACKEND_IS_TPU", True)
 
 
-def _kernel(prep, idx_dtype):
+def _kernel(prep, idx_dtype, metric="total_j"):
     from repro.core.batch import build_coeff_compute
     from repro.core.sweep import AXES
     from repro.kernels.fused_sweep import fused_sweep_block
     compute = build_coeff_compute(prep.bank.dims, exact=False)
 
-    def f(table2, row, start, low, limit):
+    def f(table, row, start, low, limit):
         return fused_sweep_block(
-            table2, row, start, low, limit, compute=compute,
-            metric="total_j", axis_names=tuple(AXES),
-            shape=tuple(prep.vgrids[0].shape), n_var=prep.n_var,
-            total=prep.total, chunk=CHUNK, lmax=prep.lmax,
-            block_points=BLOCK, kk=K, idx_dtype=idx_dtype,
-            interpret=False)
+            table, row, start, low, limit, compute=compute,
+            metric=metric, axis_names=tuple(AXES),
+            shape=tuple(prep.vgrids[0].shape), chunk=CHUNK,
+            block_points=BLOCK, kk=K, idx_dtype=idx_dtype, interpret=False)
     return f
 
 
 def _kernel_args(prep, sharding, idx_dtype):
     width = prep.bank.arrays["fused"].shape[1]
     scalar = jax.ShapeDtypeStruct((), idx_dtype, sharding=sharding)
-    return (jax.ShapeDtypeStruct(prep.table2.shape, jnp.float32,
-                                 sharding=sharding),
+    return (jax.ShapeDtypeStruct((prep.table2.shape[0], prep.lmax),
+                                 jnp.float32, sharding=sharding),
             jax.ShapeDtypeStruct((1, width), jnp.float32,
                                  sharding=sharding),
             scalar, scalar, scalar)
 
 
-def test_megakernel_compiles_for_v5e(prep, one_chip, no_persistent_cache):
-    compiled = jax.jit(_kernel(prep, jnp.int32)).lower(
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_megakernel_compiles_for_v5e(preps, width, one_chip,
+                                     no_persistent_cache):
+    prep = preps[width]
+    compiled = jax.jit(_kernel(prep, jnp.int32, WIDTHS[width][1])).lower(
         *_kernel_args(prep, one_chip, jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -121,20 +149,22 @@ def test_megakernel_refuses_int64_indices(prep, one_chip,
             lowered.lower(*_kernel_args(prep, one_chip, jnp.int64))
 
 
+@pytest.mark.parametrize("width", sorted(WIDTHS))
 @pytest.mark.parametrize("n_chips", [1, 4])
-def test_superchunk_step_compiles_for_v5e(prep, topo, n_chips, steer_tpu,
-                                          no_persistent_cache):
-    """The whole superchunk scan step of the mega sweep, on a mesh of the
+def test_superchunk_step_compiles_for_v5e(preps, width, topo, n_chips,
+                                          steer_tpu, no_persistent_cache):
+    """The whole superchunk scan step of the sweep, on a mesh of the
     described chips, as ``explore()`` builds it on a TPU."""
     from repro.core.shard_sweep import (_DEFAULT_SUPERCHUNK, _fused_step,
                                         _init_banked_state)
+    prep = preps[width]
     mesh = Mesh(np.array(topo.devices[:n_chips]), ("batch",),
                 axis_types=auto_axis_types(1))
     cpv = -(-prep.n_var // CHUNK)
     superchunk, out_keys = _fused_step(
-        prep.bank, mesh, "total_j", K, CHUNK, BLOCK, prep.vgrids[0].shape,
-        prep.n_var, prep.lmax, jnp.int32, _DEFAULT_SUPERCHUNK, cpv,
-        backend="pallas")
+        prep.bank, mesh, WIDTHS[width][1], K, CHUNK, BLOCK,
+        prep.vgrids[0].shape, prep.n_var, prep.lmax, jnp.int32,
+        _DEFAULT_SUPERCHUNK, cpv, backend="pallas")
     rep = NamedSharding(mesh, P())
 
     def spec(x):
@@ -154,8 +184,8 @@ def test_superchunk_step_compiles_for_v5e(prep, topo, n_chips, steer_tpu,
 
 
 def test_grid_decode_compiles_for_v5e(prep, one_chip, no_persistent_cache):
-    """The standalone decode kernel, which shares the megakernel's
-    ``decode_axis_values`` and which the chip smoke checks bit for bit
+    """The staged engine's standalone decode kernel (its one-hot
+    ``decode_axis_values``), which the chip smoke checks bit for bit
     against the host grid."""
     from repro.kernels.grid_decode import grid_decode
     compiled = jax.jit(lambda t, s: grid_decode(
