@@ -45,7 +45,7 @@ from typing import Callable, Dict, List, Optional, Set, Tuple
 
 from ..ckpt import atomic_write_json
 from ..core.shard_sweep import (_DEFAULT_SUPERCHUNK, StreamResult,
-                                _prepare_stream, stream_index_dtype)
+                                _prepare_stream, check_variant_span)
 from ..kernels.runtime import explicit_backend, on_tpu, resolve_backend
 from ..spans import span, traced
 from .executor import (CheckpointWriter, ProcessShardExecutor,
@@ -205,8 +205,9 @@ def _run(space, checkpoint_dir, run_sp, resumed, *, k, metric, engine,
             else:
                 resolved_backend = resolve_backend(backend)
             chunk = int(chunk_size or _DEFAULT_CHUNK)
-            # refuse a sweep the device cannot index before planning shards
-            stream_index_dtype(space.n_points, chunk, resolved_backend)
+            # refuse a space the device cannot index before planning
+            # shards (the shards' sweeps would each refuse it)
+            check_variant_span(space.n_var)
             sweep = {"k": int(k), "metric": metric, "engine": engine,
                      "chunk_size": chunk,
                      # FIXED scan length: the default would shrink with the

@@ -39,10 +39,13 @@ The PR-3 staged path is kept verbatim as the parity oracle
 ``engine="fused"`` == ``engine="staged"`` == the monolithic ``sweep()``
 oracle at rel 1e-6.
 
-Flat stream indices are variant-major (``variant = g // n_var``); they
-ride int32 and widen to int64 (scoped ``repro.compat.x64_context``) for
-grids >= 2**31 points.  ``index_range=`` streams a sub-range of the flat
-index space — the multi-host partitioning hook and the int64 test seam.
+Flat stream indices are variant-major (``variant = g // n_var``).  A
+global index exists only on the host, as a Python int: the driver cuts
+``[lo, hi)`` into per-variant segments (``split_index_range``), and the
+device sees each point as its variant slot plus an int32 offset inside
+the variant, so a space may pass 2**31 points as long as each variant
+stays under it.  ``index_range=`` streams a sub-range of the flat index
+space (the multi-host partitioning and campaign sharding hook).
 
     from repro.explore import DesignSpace, explore
     res = explore(DesignSpace(["edgaze", "rhythmic"], grids),
@@ -75,7 +78,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map, x64_context
+from ..compat import shard_map
 from ..kernels.fused_sweep import fused_sweep_block
 from ..kernels.fused_sweep_xla import fused_sweep_block_xla
 from ..kernels.grid_decode import grid_decode
@@ -101,26 +104,43 @@ _POINT_SPECS = DesignPoints(*([_BATCH_SPEC] * len(DesignPoints._fields)))
 _DEFAULT_SUPERCHUNK = 16
 
 
-def stream_index_dtype(total: int, chunk: int, backend: str):
-    """The flat-index dtype of a ``total``-point sweep in ``chunk``-point
-    chunks: int32, or int64 once int32 cannot hold ``start + chunk - 1``
-    BEFORE tail clamping/masking (at ``total`` in ``(2**31 - chunk,
-    2**31)`` the tail additions would wrap negative and sneak past the
-    validity mask otherwise).
+#: variant-local offsets ride int32 on the device
+_INDEX_LIMIT = 2 ** 31
 
-    The compiled Mosaic kernel holds no 64-bit values, so a wide sweep on
-    the compiled Pallas lane raises here, before anything is traced.
+
+def check_variant_span(n_var: int) -> None:
+    """Refuse, before anything is traced, a space whose variant-local
+    offsets do not fit int32.
+
+    The device holds each point as its variant slot and an int32 offset
+    inside the variant, so one variant must span fewer than 2**31
+    points; the whole space may be any multiple of that.  A chunk that
+    runs past the variant's end may wrap its offsets past 2**31 to
+    negative values: the masks (``off >= low``, ``flat >= 0``) drop
+    them with the other points past the end.
     """
-    if total + chunk < 2 ** 31:
-        return jnp.int32
-    if backend == "pallas" and not resolve_interpret(None):
+    if n_var >= _INDEX_LIMIT:
         raise NotImplementedError(
-            f"a {total}-point sweep needs int64 flat indices, and the "
-            f"compiled Pallas megakernel cannot hold 64-bit values on a "
-            f"TPU (Mosaic: '64-bit types are not supported'); grids of "
-            f">= 2**31 points wait on the int32 (variant, chunk, offset) "
-            f"index rework, ROADMAP item B1")
-    return jnp.int64
+            f"one variant of this space spans {n_var} points; the sweep "
+            f"holds a point as its variant and an int32 offset inside "
+            f"it, so a variant must span fewer than 2**31 points: sweep "
+            f"fewer values on an axis, or split the variant's grid into "
+            f"spaces of their own")
+
+
+def split_index_range(lo: int, hi: int, n_var: int
+                      ) -> List[Tuple[int, int, int]]:
+    """Cut the flat range ``[lo, hi)`` into per-variant segments.
+
+    Returns ``(slot, local_lo, local_hi)`` for each variant the range
+    touches, in flat order; host Python ints throughout, so ``lo`` and
+    ``hi`` may pass 2**31 while every local bound stays under ``n_var``.
+    """
+    out = []
+    for vi in range(lo // n_var, -(-hi // n_var)) if hi > lo else ():
+        base = vi * n_var
+        out.append((vi, max(lo, base) - base, min(hi, base + n_var) - base))
+    return out
 
 
 # the on-device decoder emits axis rows in ChunkedGrid order == AXES order;
@@ -339,15 +359,19 @@ def _validate_index_range(index_range, total: int) -> Tuple[int, int]:
     return lo, hi
 
 
-def _init_banked_state(k: int, n_out: int, n_variants: int, idx_dtype,
+def _init_banked_state(k: int, n_out: int, n_variants: int,
                        with_out: bool = True) -> Dict[str, jnp.ndarray]:
+    """The device reduction state: the running top-k as (value, variant
+    slot, int32 offset inside the variant) and per variant its feasible
+    count, metric sum, minimum and the offset of that minimum."""
     state = dict(
         topk_v=jnp.full((k,), jnp.inf, jnp.float32),
-        topk_i=jnp.full((k,), -1, idx_dtype),
-        n_feasible=jnp.zeros((n_variants,), idx_dtype),
+        topk_s=jnp.full((k,), -1, jnp.int32),
+        topk_i=jnp.full((k,), -1, jnp.int32),
+        n_feasible=jnp.zeros((n_variants,), jnp.int32),
         metric_sum=jnp.zeros((n_variants,), jnp.float32),
         metric_min=jnp.full((n_variants,), jnp.inf, jnp.float32),
-        argmin=jnp.full((n_variants,), -1, idx_dtype),
+        argmin=jnp.full((n_variants,), -1, jnp.int32),
     )
     if with_out:
         # the staged oracle path maintains winners' full output rows on
@@ -356,29 +380,17 @@ def _init_banked_state(k: int, n_out: int, n_variants: int, idx_dtype,
     return state
 
 
-def _variant_span_counts(lo: int, hi: int, n_var: int, n_variants: int
-                         ) -> np.ndarray:
-    """How many of the flat indices ``[lo, hi)`` land in each variant.
-
-    The flat stream is variant-major, so per-variant valid counts are pure
-    range arithmetic — no reason to burn device time scatter-counting them
-    per chunk.
-    """
-    vi = np.arange(n_variants, dtype=np.int64)
-    base = vi * n_var
-    return np.maximum(
-        np.minimum(hi, base + n_var) - np.maximum(lo, base), 0)
-
-
 def _merge_candidates(c: Dict[str, jnp.ndarray], v,
                       state: Dict[str, jnp.ndarray], k: int,
                       with_out: bool) -> Dict[str, jnp.ndarray]:
     """Fold one chunk's O(k) partials into the running banked state.
 
-    ``v`` is the chunk's (traced) variant slot.  All update ops are
-    neutral for an all-masked chunk (counts 0, mins +inf, candidates
-    +inf), which is what makes dead scan slots in the superchunk path
-    semantically free.
+    ``v`` is the chunk's (traced) variant slot and the candidates'
+    indices are int32 offsets inside it.  All update ops are neutral for
+    an all-masked chunk (counts 0, mins +inf, candidates +inf), which is
+    what makes dead scan slots in the superchunk path semantically free.
+    Chunks arrive in flat order, so a tie keeps the state's entry: the
+    lower flat index, as ``lax.top_k`` keeps the lower position.
     """
     s = jnp.argmin(c["mins"])                 # first-min shard wins
     c_min = c["mins"][s]
@@ -388,9 +400,11 @@ def _merge_candidates(c: Dict[str, jnp.ndarray], v,
     old_min = state["metric_min"][v]
     out = dict(
         topk_v=-neg2,
+        topk_s=jnp.concatenate([state["topk_s"], jnp.full(
+            c["cand_i"].shape, v, jnp.int32)])[sel],
         topk_i=jnp.concatenate([state["topk_i"], c["cand_i"]])[sel],
         n_feasible=state["n_feasible"].at[v].add(
-            jnp.sum(c["counts"]).astype(state["n_feasible"].dtype)),
+            jnp.sum(c["counts"]).astype(jnp.int32)),
         metric_sum=state["metric_sum"].at[v].add(jnp.sum(c["sums"])),
         metric_min=state["metric_min"].at[v].min(c_min),
         argmin=state["argmin"].at[v].set(
@@ -403,24 +417,20 @@ def _merge_candidates(c: Dict[str, jnp.ndarray], v,
 
 
 def _banked_step(bank: PlanBank, mesh, metric: str, k: int, chunk: int,
-                 block_points: int, shape: Tuple[int, ...], n_var: int,
-                 idx_dtype):
+                 block_points: int, shape: Tuple[int, ...], n_var: int):
     """Build the (untraced) STAGED banked chunk step + its output keys.
 
     This is the PR-3 parity oracle: per chunk, the shard body runs the
     three staged device passes — ``grid_decode`` kernel, banked
     ``evaluate_bank`` evaluator, ``block_stats`` kernel + full-chunk
     ``top_k`` — and the merge maintains winners' output rows on device.
-    The driver aligns chunks to variant boundaries (variants own
-    contiguous runs of the variant-major flat index space), so the whole
-    chunk shares one variant and its coefficient row is a broadcast
-    dynamic slice of the bank — the variant index ``start // n_var``
-    stays a traced value, so the executable serves every variant.
-    ``limit`` masks both the variant's end and the sweep's
-    ``index_range`` end.
+    The driver cuts the range into per-variant segments, so the whole
+    chunk shares one variant ``v`` (a traced slot, so the executable
+    serves every variant): its coefficient row is a broadcast dynamic
+    slice of the bank, its axis table a slice of the table bank, and
+    ``start`` / ``limit`` are int32 offsets inside it.  ``limit`` masks
+    both the variant's end and the sweep's ``index_range`` end.
     """
-    V = bank.dims.n_variants
-    total = V * n_var
     ndev = int(mesh.devices.size)
     assert chunk % ndev == 0, (chunk, ndev)
     shard = chunk // ndev
@@ -432,17 +442,16 @@ def _banked_step(bank: PlanBank, mesh, metric: str, k: int, chunk: int,
         raise KeyError(f"unknown stream metric {metric!r}; valid: "
                        f"{out_keys}")
 
-    def shard_body(start, limit, tables, bank_arrays):
-        six = jax.lax.axis_index("batch").astype(idx_dtype)
-        s0 = start + six * shard
+    def shard_body(v, start, limit, tables, bank_arrays):
+        s0 = start + jax.lax.axis_index("batch").astype(jnp.int32) * shard
         # one decode block per shard: the kernel is gather-bound, so
         # grid iterations only add interpreter dispatch overhead
-        vals, _vid = grid_decode(tables, s0, shape=shape, n_var=n_var,
-                                 total=total, chunk=shard,
-                                 block_points=shard, idx_dtype=idx_dtype)
-        flat = s0 + jnp.arange(shard, dtype=idx_dtype)
-        valid = flat < limit
-        v = (start // n_var).astype(jnp.int32)   # chunk-uniform variant
+        table = jax.lax.dynamic_index_in_dim(tables, v, 0, keepdims=True)
+        vals, _vid = grid_decode(table, s0, shape=shape, n_var=n_var,
+                                 total=n_var, chunk=shard,
+                                 block_points=shard)
+        flat = s0 + jnp.arange(shard, dtype=jnp.int32)
+        valid = (flat >= 0) & (flat < limit)    # wrapped past 2**31: < 0
         points = points_from_axis_rows(vals)
         out = fn_uniform(bank_arrays, v, points)
         ok = out["feasible"] & valid
@@ -453,8 +462,7 @@ def _banked_step(bank: PlanBank, mesh, metric: str, k: int, chunk: int,
         mins, amins, sums, counts = block_stats(metric_v, ok,
                                                 block_points=bp)
         g = jnp.argmin(mins)
-        amin_i = s0 + (g.astype(jnp.int32) * bp
-                       + amins[g]).astype(idx_dtype)
+        amin_i = s0 + g.astype(jnp.int32) * bp + amins[g]
 
         # per-shard global top-k candidates (ascending; invalids +inf)
         neg, pos = jax.lax.top_k(jnp.where(ok, -metric_v, -jnp.inf), kk)
@@ -469,15 +477,14 @@ def _banked_step(bank: PlanBank, mesh, metric: str, k: int, chunk: int,
 
     partial_keys = ("cand_v", "cand_i", "cand_out", "mins",
                     "amin_i", "sums", "counts")
-    in_specs = (P(), P(), P(),
+    in_specs = (P(), P(), P(), P(),
                 jax.tree.map(lambda _: P(), bank.arrays))
     sharded = shard_map(shard_body, mesh=mesh, in_specs=in_specs,
                         out_specs={key: _BATCH_SPEC
                                    for key in partial_keys})
 
-    def chunk_step(start, limit, tables, bank_arrays, state):
-        c = sharded(start, limit, tables, bank_arrays)
-        v = (start // n_var).astype(jnp.int32)
+    def chunk_step(v, start, limit, tables, bank_arrays, state):
+        c = sharded(v, start, limit, tables, bank_arrays)
         return _merge_candidates(c, v, state, k, True), c["counts"]
 
     return chunk_step, out_keys
@@ -485,11 +492,10 @@ def _banked_step(bank: PlanBank, mesh, metric: str, k: int, chunk: int,
 
 def _banked_exec(bank: PlanBank, mesh, metric: str, k: int, chunk: int,
                  block_points: int, shape: Tuple[int, ...], n_var: int,
-                 lmax: int, idx_dtype, tables):
+                 lmax: int, tables):
     """The cached STAGED fused chunk AOT executable for this sweep SHAPE."""
     key = ("banked", _mesh_key(mesh), chunk, metric, k, block_points,
-           tuple(bank.dims), tuple(shape), n_var, lmax,
-           jnp.dtype(idx_dtype).name)
+           tuple(bank.dims), tuple(shape), n_var, lmax)
     with _STREAM_LOCK:
         hit = _cache_get(key)
         if hit is not None:
@@ -497,12 +503,12 @@ def _banked_exec(bank: PlanBank, mesh, metric: str, k: int, chunk: int,
         with span("step.lower"):
             chunk_step, out_keys = _banked_step(bank, mesh, metric, k,
                                                 chunk, block_points, shape,
-                                                n_var, idx_dtype)
-            zero = jnp.asarray(0, idx_dtype)
+                                                n_var)
+            zero = jnp.asarray(0, jnp.int32)
             state0 = _init_banked_state(k, len(out_keys),
-                                        bank.dims.n_variants, idx_dtype)
-            lowered = jax.jit(chunk_step, donate_argnums=(4,)).lower(
-                zero, zero, tables, bank.arrays, state0)
+                                        bank.dims.n_variants)
+            lowered = jax.jit(chunk_step, donate_argnums=(5,)).lower(
+                zero, zero, zero, tables, bank.arrays, state0)
         with span("step.compile"):
             exe = lowered.compile(compiler_options=_compiler_opts())
         count("stream.step_compiles")
@@ -510,7 +516,8 @@ def _banked_exec(bank: PlanBank, mesh, metric: str, k: int, chunk: int,
         # point invalid, so counts are 0, every candidate metric is +inf
         # and the state is semantically untouched
         with span("step.warm"):
-            state0, counts = exe(zero, zero, tables, bank.arrays, state0)
+            state0, counts = exe(zero, zero, zero, tables, bank.arrays,
+                                 state0)
             jax.block_until_ready(counts)
         entry = (exe, out_keys)
         _cache_put(key, entry)
@@ -529,22 +536,26 @@ def _compiler_opts():
 # Fused engine: superchunk scan over megakernel chunk steps
 # ---------------------------------------------------------------------------
 def _fused_step(bank: PlanBank, mesh, metric: str, k: int, chunk: int,
-                block_points: int, shape: Tuple[int, ...], n_var: int,
-                lmax: int, idx_dtype, s_len: int, cpv: int,
-                backend: str = "pallas"):
+                block_points: int, shape: Tuple[int, ...], lmax: int,
+                s_len: int, cpv: int, backend: str = "pallas"):
     """Build the (untraced) superchunk scan step + its output key list.
 
-    One call evaluates ``s_len`` consecutive chunk ordinals: scan step
-    ``c`` derives its chunk's ``start`` / ``limit`` / variant slot from
-    pure index arithmetic on the variant-major flat space (``cpv`` chunk
-    ordinals per variant), runs the chunk through the fused megakernel
-    shard body, and folds the O(k) partials into the scan-carried banked
-    state.  Ordinals at or past ``c_hi`` are skipped by a scalar
-    ``lax.cond`` (the carry passes through untouched — bit-identical to
-    merging an all-masked chunk), so a mostly-dead superchunk costs only
-    its live slots and the trailing superchunk needs no special-casing.
-    Only the metric rides the kernel; winners' full output rows are
-    re-gathered by the driver at finalization.
+    One call evaluates ``s_len`` consecutive chunk ordinals: ordinal
+    ``c`` is chunk ``c % cpv`` of variant slot ``c // cpv`` (``cpv``
+    chunks cover a variant's span), which starts at int32 offset ``(c %
+    cpv) * chunk`` inside the variant.  The host cut the sweep's range
+    into per-variant segments: ``lows`` / ``limits`` hold each
+    variant's segment as int32 offsets (``[0, 0)`` where the range
+    misses it).  Scan step ``c`` runs its chunk through the fused
+    megakernel shard body with its variant's coefficient row, axis table
+    and segment bounds, and folds the O(k) partials into the
+    scan-carried banked state, so a superchunk crosses variant
+    boundaries without a dead slot.  Ordinals at or past ``c_hi`` are
+    skipped by a scalar ``lax.cond`` (the carry passes through untouched
+    — bit-identical to merging an all-masked chunk), so a mostly-dead
+    superchunk costs only its live slots and the trailing superchunk
+    needs no special-casing.  Only the metric rides the kernel; winners'
+    full output rows are re-gathered by the driver at finalization.
 
     ``backend`` (already resolved: "pallas" or "xla") picks the fused
     megakernel implementation — ``pallas_call`` (Mosaic on TPU, Pallas
@@ -552,7 +563,6 @@ def _fused_step(bank: PlanBank, mesh, metric: str, k: int, chunk: int,
     natively; both share the exact block reduction contract, so the
     merge path is backend-independent.
     """
-    V = bank.dims.n_variants
     ndev = int(mesh.devices.size)
     assert chunk % ndev == 0, (chunk, ndev)
     shard = chunk // ndev
@@ -578,20 +588,17 @@ def _fused_step(bank: PlanBank, mesh, metric: str, k: int, chunk: int,
                        f"{out_keys}")
 
     def shard_body(start, low, limit, table, row):
-        six = jax.lax.axis_index("batch").astype(idx_dtype)
-        s0 = start + six * shard
+        s0 = start + jax.lax.axis_index("batch").astype(jnp.int32) * shard
         cv, cl, sums, counts = block_fn(
             table, row, s0, low, limit, compute=compute,
             metric=metric, axis_names=tuple(AXES), shape=tuple(shape),
-            chunk=shard, block_points=bp, kk=kk, idx_dtype=idx_dtype)
+            chunk=shard, block_points=bp, kk=kk)
         # fold the (G, kk) block candidates to this shard's top-kk
         with jax.named_scope("topk_merge"):
             neg, pos = jax.lax.top_k(-cv.reshape(-1), kk)
-            blk = (pos // kk).astype(idx_dtype)
-            cand_i = s0 + blk * bp + cl.reshape(-1)[pos].astype(idx_dtype)
+            cand_i = s0 + (pos // kk) * bp + cl.reshape(-1)[pos]
             g = jnp.argmin(cv[:, 0])
-            amin_i = s0 + (g.astype(jnp.int32) * bp
-                           + cl[g, 0]).astype(idx_dtype)
+            amin_i = s0 + g.astype(jnp.int32) * bp + cl[g, 0]
             return dict(
                 cand_v=-neg, cand_i=cand_i,
                 mins=cv[g, 0][None], amin_i=amin_i[None],
@@ -604,20 +611,17 @@ def _fused_step(bank: PlanBank, mesh, metric: str, k: int, chunk: int,
                         out_specs={key: _BATCH_SPEC
                                    for key in partial_keys})
 
-    def superchunk(c0, low, hi, c_hi, table2, bank_arrays, state):
+    def superchunk(c0, lows, limits, c_hi, table2, bank_arrays, state):
         def live(c, st):
-            vi = c // cpv
-            r = c - vi * cpv
-            start = (vi * n_var + r * chunk).astype(idx_dtype)
-            limit = jnp.minimum(hi, (vi + 1) * n_var).astype(idx_dtype)
-            # the chunk's variant picks its coefficient row and its axis
-            # table (a shard past the variant's end is all masked by limit)
-            v = jnp.clip(vi, 0, V - 1).astype(jnp.int32)
+            v = c // cpv
+            # the chunk's variant picks its coefficient row, its axis
+            # table and its segment of the range
             row = jax.lax.dynamic_index_in_dim(
                 bank_arrays["fused"], v, 0, keepdims=True)     # (1, W)
             table = jax.lax.dynamic_slice_in_dim(
                 table2, v * lmax, lmax, axis=1)         # (n_axes, Lmax)
-            parts = sharded(start, low, limit, table, row)
+            parts = sharded((c - v * cpv) * chunk, lows[v], limits[v],
+                            table, row)
             with jax.named_scope("state_fold"):
                 return (_merge_candidates(parts, v, st, k, False),
                         parts["counts"])
@@ -634,7 +638,7 @@ def _fused_step(bank: PlanBank, mesh, metric: str, k: int, chunk: int,
         def body(st, c):
             return jax.lax.cond(c < c_hi, live, dead, c, st)
 
-        cs = c0 + jnp.arange(s_len, dtype=idx_dtype)
+        cs = c0 + jnp.arange(s_len, dtype=jnp.int32)
         return jax.lax.scan(body, state, cs)
 
     return superchunk, out_keys
@@ -653,9 +657,8 @@ def _fused_table2(tables):
 
 
 def _fused_exec(bank: PlanBank, mesh, metric: str, k: int, chunk: int,
-                block_points: int, shape: Tuple[int, ...], n_var: int,
-                lmax: int, idx_dtype, table2, s_len: int, cpv: int,
-                backend: str = "pallas"):
+                block_points: int, shape: Tuple[int, ...], lmax: int,
+                table2, s_len: int, cpv: int, backend: str = "pallas"):
     """The cached superchunk AOT executable for this sweep SHAPE.
 
     ``backend`` joins the cache key: the Pallas and XLA lanes are
@@ -663,30 +666,29 @@ def _fused_exec(bank: PlanBank, mesh, metric: str, k: int, chunk: int,
     invariant is asserted in tests/test_fused_sweep.py).
     """
     key = ("fused", backend, _mesh_key(mesh), chunk, metric, k,
-           block_points, tuple(bank.dims), tuple(shape), n_var, lmax,
-           s_len, cpv, jnp.dtype(idx_dtype).name)
+           block_points, tuple(bank.dims), tuple(shape), lmax, s_len, cpv)
     with _STREAM_LOCK:
         hit = _cache_get(key)
         if hit is not None:
             return hit
         with span("step.lower"):
             superchunk, out_keys = _fused_step(
-                bank, mesh, metric, k, chunk, block_points, shape, n_var,
-                lmax, idx_dtype, s_len, cpv, backend=backend)
-            zero = jnp.asarray(0, idx_dtype)
+                bank, mesh, metric, k, chunk, block_points, shape, lmax,
+                s_len, cpv, backend=backend)
+            zero = jnp.asarray(0, jnp.int32)
+            bounds = jnp.zeros((bank.dims.n_variants,), jnp.int32)
             state0 = _init_banked_state(k, len(out_keys),
-                                        bank.dims.n_variants, idx_dtype,
+                                        bank.dims.n_variants,
                                         with_out=False)
             lowered = jax.jit(superchunk, donate_argnums=(6,)).lower(
-                zero, zero, zero, zero, table2, bank.arrays, state0)
+                zero, bounds, bounds, zero, table2, bank.arrays, state0)
         with span("step.compile"):
             exe = lowered.compile(compiler_options=_compiler_opts())
         count("stream.step_compiles")
         # warm the dispatch path on an all-dead superchunk: c_hi=0 turns
-        # every scan slot into a limit=0 no-op, leaving the state
-        # untouched
+        # every scan slot into a no-op, leaving the state untouched
         with span("step.warm"):
-            state0, counts = exe(zero, zero, zero, zero, table2,
+            state0, counts = exe(zero, bounds, bounds, zero, table2,
                                  bank.arrays, state0)
             jax.block_until_ready(counts)
         entry = (exe, out_keys)
@@ -699,11 +701,11 @@ class _StreamPrep:
     """Lowered, device-resident sweep inputs shared across dispatches.
 
     Everything here is a pure function of ``(algorithms, grids,
-    soc_node)`` and — being all-f32 / host metadata — independent of the
-    scoped x64 context, so one prep serves every ``index_range`` shard
-    of a campaign: the campaign runner builds it ONCE and threads it
-    through ``_stream_impl(_prepared=...)``, hoisting the per-shard
-    variant re-lowering, bank rebuild and table transpose out of the
+    soc_node)`` — all-f32 arrays and host metadata, no index — so one
+    prep serves every ``index_range`` shard of a campaign: the campaign
+    runner builds it ONCE and threads it through
+    ``_stream_impl(_prepared=...)``, hoisting the per-shard variant
+    re-lowering, bank rebuild and table transpose out of the
     shard loop (they dominated campaign fixed overhead).  Read-only
     after construction (thread-safe to share).
     """
@@ -943,9 +945,10 @@ def _stream_impl(algorithm: Union[str, Sequence[str]] = "edgaze",
     exactly that shape, so the whole sweep compiles ONE step executable
     total (asserted via :func:`stream_cache_info` in tests); re-runs
     with the same shapes hit the LRU executable cache even across
-    re-gridding.  Grids of >= 2**31 points stream with int64 indices
-    automatically.  ``index_range=(lo, hi)`` streams only that slice of
-    the flat index space (multi-host partitioning hook);
+    re-gridding.  The whole space may pass 2**31 points; one variant
+    may not (``check_variant_span``).  ``index_range=(lo, hi)`` streams
+    only that slice of the flat index space (multi-host partitioning
+    hook), cut on the host into per-variant segments;
     ``progress(done, span)`` fires after every dispatch.
 
     ``on_partial(done, span, snapshot)`` is the partial-result hook (the
@@ -1021,24 +1024,36 @@ def _stream_run(sweep_sp, algorithm, grids, *, soc_node, chunk_size,
         chunk = -(-max(int(chunk_size), 1) // ndev) * ndev
         chunk = min(chunk, -(-n_var // ndev) * ndev)
         lo, hi = _validate_index_range(index_range, total)
-        idx_dtype = stream_index_dtype(total, chunk, backend)
-        wide = idx_dtype == jnp.int64
-        # fused chunk ordinals: cpv chunk slots per variant, covering the
-        # whole variant span; [c_lo, c_hi) are the ordinals that
-        # intersect [lo, hi)
-        cpv = -(-n_var // chunk)
-
-        def _ordinal(f: int) -> int:
-            vi, r = divmod(f, n_var)
-            return vi * cpv + r // chunk
-
-        c_lo = _ordinal(lo)
-        c_hi = _ordinal(hi - 1) + 1 if hi > lo else c_lo
-        n_chunks = max(c_hi - c_lo, 0)
-        s_len = 1
-        if engine == "fused":
-            s_len = (max(1, int(superchunk)) if superchunk
-                     else min(max(n_chunks, 1), _DEFAULT_SUPERCHUNK))
+        check_variant_span(n_var)
+        with span("sweep.split"):
+            # [(slot, local lo, local hi)]: every bound the device sees
+            # is an int32 offset inside one variant
+            segments = split_index_range(lo, hi, n_var)
+            if engine == "fused":
+                # chunk ordinals: cpv chunks cover a variant's span, and
+                # ordinal c is chunk c % cpv of variant c // cpv;
+                # [c_lo, c_hi) are the ordinals that meet the range
+                cpv = -(-n_var // chunk)
+                c_lo = c_hi = 0
+                if segments:
+                    (v0, vlo, _), (v1, _, vhi) = segments[0], segments[-1]
+                    c_lo = v0 * cpv + vlo // chunk
+                    c_hi = v1 * cpv + -(-vhi // chunk)
+                n_chunks = c_hi - c_lo
+                s_len = (max(1, int(superchunk)) if superchunk
+                         else min(max(n_chunks, 1), _DEFAULT_SUPERCHUNK))
+                if n_variants * cpv + s_len > _INDEX_LIMIT:
+                    raise ValueError(
+                        f"{n_variants * cpv} chunk ordinals of {chunk} "
+                        f"points do not fit int32; raise chunk_size")
+                slots = s_len * -(-n_chunks // s_len)
+            else:
+                s_len = 1
+                n_chunks = slots = sum(-(-(vhi - vlo) // chunk)
+                                       for _vi, vlo, vhi in segments)
+            count("sweep.segments", len(segments))
+            count("sweep.slots", slots)
+            count("sweep.dead_slots", slots - n_chunks)
 
     dispatches = 0
     dispatched_points = 0
@@ -1063,7 +1078,8 @@ def _stream_run(sweep_sp, algorithm, grids, *, soc_node, chunk_size,
                 while (n_win < len(host["topk_v"])
                        and np.isfinite(host["topk_v"][n_win])):
                     n_win += 1             # fewer than k feasible points
-                win = [divmod(int(host["topk_i"][j]), n_var)
+                # winners as (variant slot, offset inside it)
+                win = [(int(host["topk_s"][j]), int(host["topk_i"][j]))
                        for j in range(n_win)]
                 if engine == "fused" and n_win:
                     # tiny second pass over winners only: the megakernel
@@ -1083,10 +1099,11 @@ def _stream_run(sweep_sp, algorithm, grids, *, soc_node, chunk_size,
                         [np.asarray(out[key], np.float32)[:n_win]
                          for key in out_keys], axis=1)
             with span("finalize.assemble"):
-                # per-variant valid counts are range arithmetic on the
-                # variant-major flat index space — never computed on
-                # device
-                n_seen = _variant_span_counts(lo, hi, n_var, n_variants)
+                # per-variant valid counts are the segments' lengths —
+                # never computed on device
+                n_seen = [0] * n_variants
+                for vi, vlo, vhi in segments:
+                    n_seen[vi] = vhi - vlo
                 summaries: Dict[str, Dict] = {}
                 n_feasible = 0
                 for vi, label in enumerate(labels):
@@ -1094,12 +1111,12 @@ def _stream_run(sweep_sp, algorithm, grids, *, soc_node, chunk_size,
                     n_feasible += nf
                     amin = int(host["argmin"][vi])
                     summaries[label] = dict(
-                        n=int(n_seen[vi]), n_feasible=nf,
+                        n=n_seen[vi], n_feasible=nf,
                         metric_min=float(host["metric_min"][vi]),
                         metric_mean=(float(host["metric_sum"][vi]) / nf
                                      if nf else float("nan")),
-                        argmin_index=amin % n_var if amin >= 0 else -1,
-                        argmin_point=(vgrids[vi].point(amin % n_var)
+                        argmin_index=amin,
+                        argmin_point=(vgrids[vi].point(amin)
                                       if amin >= 0 else None))
 
                 rows: List[Dict] = []
@@ -1124,97 +1141,88 @@ def _stream_run(sweep_sp, algorithm, grids, *, soc_node, chunk_size,
                     n_var=n_var, backend=backend,
                     kernel_mode=sweep_kernel_mode(backend))
 
-    with x64_context(wide):
-        # tables/bank/table2 are all-f32 (x64-independent), built once in
-        # the prep — inside the context only INDEX arrays widen
-        tables, bank, lmax = prep.tables, prep.bank, prep.lmax
-        table2 = prep.table2
-        with span("sweep.step") as step_sp:
-            if engine == "fused":
-                exe, out_keys = _fused_exec(
-                    bank, mesh, metric, k, chunk, block_points,
-                    vgrids[0].shape, n_var, lmax, idx_dtype, table2, s_len,
-                    cpv, backend=backend)
-            else:
-                exe, out_keys = _banked_exec(
-                    bank, mesh, metric, k, chunk, block_points,
-                    vgrids[0].shape, n_var, lmax, idx_dtype, tables)
-            state = _init_banked_state(k, len(out_keys), n_variants,
-                                       idx_dtype,
-                                       with_out=engine != "fused")
-        compile_s = prep_sp.seconds + step_sp.seconds
+    tables, bank, lmax = prep.tables, prep.bank, prep.lmax
+    table2 = prep.table2
+    with span("sweep.step") as step_sp:
+        if engine == "fused":
+            exe, out_keys = _fused_exec(
+                bank, mesh, metric, k, chunk, block_points,
+                vgrids[0].shape, lmax, table2, s_len, cpv, backend=backend)
+        else:
+            exe, out_keys = _banked_exec(
+                bank, mesh, metric, k, chunk, block_points,
+                vgrids[0].shape, n_var, lmax, tables)
+        state = _init_banked_state(k, len(out_keys), n_variants,
+                                   with_out=engine != "fused")
+    compile_s = prep_sp.seconds + step_sp.seconds
 
-        with span("sweep.dispatch") as disp_sp:
-            if engine == "fused":
-                dev = lambda v: jnp.asarray(v, idx_dtype)   # noqa: E731
-                lo_dev, hi_dev, chi_dev = dev(lo), dev(hi), dev(c_hi)
-                inflight: List = []
-                for d0 in range(c_lo, c_hi, s_len):
-                    state, counts = exe(dev(d0), lo_dev, hi_dev, chi_dev,
-                                        table2, bank.arrays, state)
+    with span("sweep.dispatch") as disp_sp:
+        dev = lambda x: jnp.asarray(x, jnp.int32)   # noqa: E731
+        inflight: List = []
+        if engine == "fused":
+            # each variant's segment as int32 offsets, [0, 0) where the
+            # range misses the variant
+            bounds = np.zeros((2, n_variants), np.int32)
+            for vi, vlo, vhi in segments:
+                bounds[:, vi] = vlo, vhi
+            chi_dev, lows, limits = dev(c_hi), dev(bounds[0]), dev(bounds[1])
+            for d0 in range(c_lo, c_hi, s_len):
+                state, counts = exe(dev(d0), lows, limits, chi_dev, table2,
+                                    bank.arrays, state)
+                dispatches += 1
+                dispatched_points += s_len * chunk
+                # pace on the counts partial so upcoming dispatches
+                # overlap device execution without running unboundedly
+                # ahead; the state itself is donated to the next
+                # superchunk and cannot be blocked on
+                inflight.append(counts)
+                if len(inflight) > pipeline_depth:
+                    with span("sweep.pace"):
+                        jax.block_until_ready(inflight.pop(0))
+                if progress is not None or on_partial is not None:
+                    vi_l, r_l = divmod(min(d0 + s_len, c_hi) - 1, cpv)
+                    end = min(vi_l * n_var + min((r_l + 1) * chunk, n_var),
+                              hi)
+                    done_pts = max(end - lo, 0)
+                    if progress is not None:
+                        progress(done_pts, hi - lo)
+                    if on_partial is not None:
+                        # bind loop state by value: the closure is only
+                        # valid until the next dispatch donates `state`
+                        on_partial(done_pts, hi - lo,
+                                   lambda st=state, nd=dispatches,
+                                   dpts=dispatched_points,
+                                   cov=done_pts: _finalize(
+                                       st, out_keys, nd, dpts,
+                                       disp_sp.seconds, cov))
+        else:
+            done = 0
+            # one variant per segment, so each chunk is variant-uniform
+            # (the evaluator broadcasts one coefficient row); `limit`
+            # masks both the variant end and the index_range end
+            for vi, vlo, vhi in segments:
+                v_dev, limit_dev = dev(vi), dev(vhi)
+                for start in range(vlo, vhi, chunk):
+                    state, counts = exe(v_dev, dev(start), limit_dev,
+                                        tables, bank.arrays, state)
                     dispatches += 1
-                    dispatched_points += s_len * chunk
-                    # pace on the counts partial so upcoming dispatches
-                    # overlap device execution without running
-                    # unboundedly ahead; the state itself is donated to
-                    # the next superchunk and cannot be blocked on
+                    dispatched_points += chunk
                     inflight.append(counts)
                     if len(inflight) > pipeline_depth:
                         with span("sweep.pace"):
                             jax.block_until_ready(inflight.pop(0))
-                    if progress is not None or on_partial is not None:
-                        last = min(d0 + s_len, c_hi) - 1
-                        vi_l, r_l = divmod(last, cpv)
-                        end = min(vi_l * n_var + (r_l + 1) * chunk,
-                                  vi_l * n_var + n_var, hi)
-                        done_pts = max(end - lo, 0)
-                        if progress is not None:
-                            progress(done_pts, hi - lo)
-                        if on_partial is not None:
-                            # bind loop state by value: the closure is
-                            # only valid until the next dispatch donates
-                            # `state`
-                            on_partial(done_pts, hi - lo,
-                                       lambda st=state, nd=dispatches,
-                                       dpts=dispatched_points,
-                                       cov=done_pts: _finalize(
-                                           st, out_keys, nd, dpts,
-                                           disp_sp.seconds, cov))
-            else:
-                inflight = []
-                done = 0
-                # chunks are aligned to variant boundaries so each one is
-                # variant-uniform (the evaluator broadcasts one
-                # coefficient row); `limit` masks both the variant end
-                # and the index_range end
-                for vi in range(n_variants):
-                    vlo = max(lo, vi * n_var)
-                    vhi = min(hi, (vi + 1) * n_var)
-                    if vlo >= vhi:
-                        continue
-                    limit_dev = jnp.asarray(vhi, idx_dtype)
-                    for start in range(vlo, vhi, chunk):
-                        state, counts = exe(jnp.asarray(start, idx_dtype),
-                                            limit_dev, tables, bank.arrays,
-                                            state)
-                        dispatches += 1
-                        dispatched_points += chunk
-                        inflight.append(counts)
-                        if len(inflight) > pipeline_depth:
-                            with span("sweep.pace"):
-                                jax.block_until_ready(inflight.pop(0))
-                        done += min(start + chunk, vhi) - start
-                        if progress is not None:
-                            progress(done, hi - lo)
-                        if on_partial is not None:
-                            on_partial(done, hi - lo,
-                                       lambda st=state, nd=dispatches,
-                                       dpts=dispatched_points,
-                                       cov=done: _finalize(
-                                           st, out_keys, nd, dpts,
-                                           disp_sp.seconds, cov))
-            with span("sweep.pace"):
-                jax.block_until_ready(state["n_feasible"])
+                    done += min(start + chunk, vhi) - start
+                    if progress is not None:
+                        progress(done, hi - lo)
+                    if on_partial is not None:
+                        on_partial(done, hi - lo,
+                                   lambda st=state, nd=dispatches,
+                                   dpts=dispatched_points,
+                                   cov=done: _finalize(
+                                       st, out_keys, nd, dpts,
+                                       disp_sp.seconds, cov))
+        with span("sweep.pace"):
+            jax.block_until_ready(state["n_feasible"])
     count("sweep.dispatches", dispatches)
     # host-side finalization (all O(k) / O(variants)) — shared with the
     # on_partial snapshot path above

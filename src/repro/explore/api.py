@@ -287,7 +287,9 @@ def explore(space: DesignSpace, *, k: int = 16, metric: str = "total_j",
     (grid engines) raises on pipeline stalls / infeasible points like the
     scalar oracle.  ``index_range`` / ``progress`` / ``superchunk`` /
     ``pipeline_depth`` / ``block_points`` tune the streaming engines
-    (``index_range`` is the multi-host partitioning hook).
+    (``index_range`` is the multi-host partitioning hook; its bounds are
+    host integers and may pass 2**31, as may the space, so long as each
+    variant spans fewer than 2**31 points).
 
     ``backend`` selects the fused megakernel implementation: ``"pallas"``
     (``pallas_call`` — Mosaic-compiled on TPU, interpreted elsewhere),

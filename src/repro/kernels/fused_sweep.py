@@ -11,13 +11,13 @@ collapses to O(k) scalars.
 
 This kernel fuses the whole per-chunk pipeline into ONE pass per block:
 
-1. **decode** — the block's flat stream indices expand into axis-value
-   vectors in VMEM (:func:`decode_block`): static div/mod against the
-   grid strides gives each axis's index, and a chain of selects over
-   that axis's static length picks its value out of the chunk variant's
-   ``(n_axes, lmax)`` table, which rides in SMEM.  Chunks are
-   variant-uniform, so the caller slices that table; the kernel never
-   derives a variant.  A select copies a table entry, so decoded values
+1. **decode** — the block's offsets inside its variant expand into
+   axis-value vectors in VMEM (:func:`decode_block`): static div/mod
+   against the grid strides gives each axis's index, and a chain of
+   selects over that axis's static length picks its value out of the
+   chunk variant's ``(n_axes, lmax)`` table, which rides in SMEM.
+   Chunks are variant-uniform, so the caller slices that table; the
+   kernel never derives a variant.  A select copies a table entry, so decoded values
    are bit-identical to the host gather.  The staged engine's
    ``grid_decode`` keeps its own one-hot lookup (its chunks may span
    variants); the fused == XLA twin == staged parity tests in
@@ -37,16 +37,19 @@ table never touch HBM.  Winning rows re-gather their full output schema
 in a tiny O(k) second pass at sweep finalization.
 
 Masking follows the streaming driver's contract: a point is valid iff
-``low <= flat < limit`` AND it lies inside this call's ``chunk`` span
+``low <= off < limit`` AND it lies inside this call's ``chunk`` span
 (blocks are padded up to ``block_points``; the spillover positions would
-otherwise double-count the next shard's points).
+otherwise double-count the next shard's points).  ``start``, ``low`` and
+``limit`` are int32 offsets inside the chunk's variant: the driver cuts a
+sweep into per-variant segments on the host, so a space may pass 2**31
+points while every value the kernel holds stays int32.
 
 Mosaic's rules shape the layout: ``start`` / ``low`` / ``limit`` arrive
 as one SMEM vector and the chunk's axis table as another (its scalars
 broadcast into the selects), each block writes whole ``(1, n)`` rows of
 ``(G, 1, n)`` outputs (the block's trailing dims then equal the array's),
-and the kernel holds no 64-bit value, so the compiled kernel takes int32
-indices only.  ``tests/test_tpu_compile.py`` compiles it for a TPU v5e.
+and the kernel holds no 64-bit value.  ``tests/test_tpu_compile.py``
+compiles it for a TPU v5e.
 Compiled and interpreted kernels run the same decode, so the CPU tests
 exercise the code the chip runs.
 """
@@ -68,22 +71,21 @@ KERNEL_NAME = "camj_megakernel"
 
 
 def decode_block(bounds_ref, tab_ref, *, shape, strides, lmax, chunk,
-                 block, idx_dtype):
+                 block):
     """The validity mask and decoded axis values of this grid step's block.
 
-    ``bounds_ref`` holds ``start``, ``low`` and ``limit``; ``tab_ref``
-    the chunk variant's axis table flattened to ``(n_axes * lmax,)``
-    (axis ``a`` holds its values at ``a * lmax`` onwards; padding is
-    never read).  Returns the ``(block,)`` mask and a list of ``(block,)``
-    f32 vectors in :class:`~repro.core.sweep.ChunkedGrid` axis order.
-    The axis index ``(off // stride) % size`` repeats with the variant's
-    span, so it needs no offset from the variant's start and stays in
-    range past its end (masked points) without a clamp.  Each value is
-    a chain of selects over the axis's static length: an exact copy of a
-    table entry.
+    ``bounds_ref`` holds ``start``, ``low`` and ``limit`` (int32 offsets
+    inside the chunk's variant); ``tab_ref`` the chunk variant's axis
+    table flattened to ``(n_axes * lmax,)`` (axis ``a`` holds its values
+    at ``a * lmax`` onwards; padding is never read).  Returns the
+    ``(block,)`` mask and a list of ``(block,)`` f32 vectors in
+    :class:`~repro.core.sweep.ChunkedGrid` axis order.  The axis index
+    ``(off // stride) % size`` stays in range past the variant's end
+    (masked points) without a clamp.  Each value is a chain of selects
+    over the axis's static length: an exact copy of a table entry.
     """
     i = pl.program_id(0)
-    lane = jax.lax.broadcasted_iota(idx_dtype, (1, block), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
     pos = i * block + lane                      # position within the chunk
     off = bounds_ref[0] + pos
     valid = ((off >= bounds_ref[1]) & (off < bounds_ref[2])
@@ -95,11 +97,11 @@ def decode_block(bounds_ref, tab_ref, *, shape, strides, lmax, chunk,
     for a, (n, stride) in enumerate(zip(shape, strides)):
         val = jax.lax.broadcast(tab_ref[a * lmax], off.shape)
         if n > 1:
-            idx = jax.lax.rem(jax.lax.div(off, idx_dtype(stride)),
-                              idx_dtype(n))
+            idx = jax.lax.rem(jax.lax.div(off, jnp.int32(stride)),
+                              jnp.int32(n))
             for j in range(1, n):
                 val = jax.lax.select(
-                    jax.lax.eq(idx, idx_dtype(j)),
+                    jax.lax.eq(idx, jnp.int32(j)),
                     jax.lax.broadcast(tab_ref[a * lmax + j], off.shape), val)
         vals.append(val[0])
     return valid, vals
@@ -141,9 +143,8 @@ def _fused_kernel(bounds_ref, tab_ref, row_ref, cv_ref, cl_ref, st_ref,
 def fused_sweep_block(table: jax.Array, row: jax.Array, start, low, limit,
                       *, compute, metric: str, axis_names, shape,
                       chunk: int, block_points: int = 4096,
-                      kk: int = 16, idx_dtype=jnp.int32,
-                      interpret: bool = None):
-    """Decode + evaluate + reduce flat indices ``[start, start + chunk)``.
+                      kk: int = 16, interpret: bool = None):
+    """Decode + evaluate + reduce offsets ``[start, start + chunk)``.
 
     ``table`` is the chunk variant's ``(n_axes, lmax)`` f32 axis-value
     table (axis ``a`` holds its first ``shape[a]`` entries; chunks are
@@ -153,7 +154,7 @@ def fused_sweep_block(table: jax.Array, row: jax.Array, start, low, limit,
     match this call's resolved ``interpret`` mode).  Returns ``(cand_v,
     cand_l, sums, counts)``: per-block ascending candidate metric values
     ``(G, kk)`` (+inf-padded), their block-LOCAL int32 indices ``(G,
-    kk)`` (global flat index = ``start + g * block_points + cand_l``),
+    kk)`` (offset = ``start + g * block_points + cand_l``),
     and the masked per-block metric sums / valid counts ``(G,)``.
     """
     n_axes, lmax = table.shape
@@ -163,7 +164,7 @@ def fused_sweep_block(table: jax.Array, row: jax.Array, start, low, limit,
     nb = -(-chunk // bp)
     interpret = resolve_interpret(interpret)
 
-    bounds = jnp.stack([jnp.asarray(v, idx_dtype)
+    bounds = jnp.stack([jnp.asarray(v, jnp.int32)
                         for v in (start, low, limit)])
     # per-block outputs are (G, 1, n) arrays whose leading block dim is
     # squeezed: each kernel block writes one whole (1, n) row, and the
@@ -172,8 +173,7 @@ def fused_sweep_block(table: jax.Array, row: jax.Array, start, low, limit,
         functools.partial(
             _fused_kernel, compute=compute, metric=metric,
             axis_names=tuple(axis_names), kk=kk, shape=tuple(shape),
-            strides=grid_strides(shape), lmax=lmax, chunk=chunk, block=bp,
-            idx_dtype=idx_dtype),
+            strides=grid_strides(shape), lmax=lmax, chunk=chunk, block=bp),
         grid=(nb,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

@@ -8,7 +8,7 @@ ordinary element-wise math + a bounded reduction, exactly the program
 shape XLA already compiles well on CPU and GPU.  This module is that
 body re-expressed in pure ``jnp``:
 
-1. **decode** — the kernel's stride math on the same flat indices, then
+1. **decode** — the kernel's stride math on the same offsets, then
    a plain XLA gather from the chunk variant's ``(n_axes, lmax)`` table
    (the kernel's select chain is the Mosaic form of that gather, and the
    parity tests hold the two together);
@@ -22,17 +22,20 @@ body re-expressed in pure ``jnp``:
 
 The return contract is bit-for-bit the Pallas kernel's: ``(cand_v,
 cand_l, sums, counts)`` with ``(G, kk)`` ascending +inf-padded candidate
-values, ``(G, kk)`` block-LOCAL int32 indices (global flat index =
-``start + g * block_points + cand_l``), and ``(G,)`` stats — so
+values, ``(G, kk)`` block-LOCAL int32 indices (the point's offset
+inside its variant is ``start + g * block_points + cand_l``), and
+``(G,)`` stats — so
 ``core.shard_sweep._fused_step`` folds either backend's output through
 the identical merge path, and the rel-1e-6 parity chain (XLA == Pallas
 == staged == monolithic) is asserted in tests/test_fused_sweep.py.
 
 Validity masking is the shared streaming contract: a point counts iff
-``low <= flat < limit`` AND it lies inside this call's ``chunk`` span
+``low <= off < limit`` AND it lies inside this call's ``chunk`` span
 (blocks pad up to ``block_points``; spillover positions would otherwise
-double-count the next shard's points).  Past the variant's end the axis
-indices wrap around, exactly like the kernel's.
+double-count the next shard's points).  ``start``, ``low`` and ``limit``
+are int32 offsets inside the chunk's variant, as the kernel's are.  Past
+the variant's end the axis indices wrap around, exactly like the
+kernel's.
 
 The function is jitted (shape-static args) for the same reason
 ``grid_decode`` is: it also runs nested inside the already-jitted
@@ -53,12 +56,12 @@ from .grid_decode import grid_strides
 
 @functools.partial(jax.jit, static_argnames=(
     "compute", "metric", "axis_names", "shape", "chunk", "block_points",
-    "kk", "idx_dtype"))
+    "kk"))
 def fused_sweep_block_xla(table: jax.Array, row: jax.Array, start, low,
                           limit, *, compute, metric: str, axis_names,
                           shape, chunk: int, block_points: int = 4096,
-                          kk: int = 16, idx_dtype=jnp.int32):
-    """Decode + evaluate + reduce flat indices ``[start, start + chunk)``.
+                          kk: int = 16):
+    """Decode + evaluate + reduce offsets ``[start, start + chunk)``.
 
     Same signature and return contract as
     :func:`repro.kernels.fused_sweep.fused_sweep_block`, minus the
@@ -72,10 +75,10 @@ def fused_sweep_block_xla(table: jax.Array, row: jax.Array, start, low,
     bp = max(min(block_points, chunk), 1)
     nb = -(-chunk // bp)
 
-    pos = jnp.arange(nb * bp, dtype=idx_dtype).reshape(1, -1)
-    off = jnp.asarray(start, idx_dtype) + pos
-    valid = ((off >= jnp.asarray(low, idx_dtype))
-             & (off < jnp.asarray(limit, idx_dtype))
+    pos = jnp.arange(nb * bp, dtype=jnp.int32).reshape(1, -1)
+    off = jnp.asarray(start, jnp.int32) + pos
+    valid = ((off >= jnp.asarray(low, jnp.int32))
+             & (off < jnp.asarray(limit, jnp.int32))
              & (pos < chunk))[0]
     vals = [jnp.take(table[a], (off[0] // stride) % n)
             for a, (n, stride) in enumerate(zip(shape,

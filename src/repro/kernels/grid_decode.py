@@ -20,10 +20,11 @@ variant, exactly :class:`repro.core.sweep.ChunkedGrid` semantics):
   ``category_reduce`` (one-hot rows sum exactly one f32 table entry, so
   decoded values are bit-identical to the host gather).
 
-Indices ride ``int32`` by default and ``int64`` for >=2**31-point grids
-(the caller scopes ``repro.compat.x64_context`` around trace + dispatch).
-Out-of-range tail indices are clamped to ``total - 1``; callers mask them
-via their own ``flat < hi`` validity predicate.
+Indices are int32.  The staged streaming engine passes one variant's
+table and offsets inside that variant, so they stay under 2**31 however
+large the whole space is.  Out-of-range tail indices are clamped to
+``total - 1``; callers mask them via their own ``flat < hi`` validity
+predicate.
 """
 from __future__ import annotations
 
@@ -81,11 +82,10 @@ def decode_axis_values(off, table, *, shape, strides, n_var, n_variants,
 
 
 def _decode_kernel(start_ref, table_ref, vals_ref, vid_ref, *, shape,
-                   strides, n_var, total, block, idx_dtype, n_variants,
-                   lmax, gather):
+                   strides, n_var, total, block, n_variants, lmax, gather):
     i = pl.program_id(0)
     off = (start_ref[0, 0] + i * block
-           + jax.lax.broadcasted_iota(idx_dtype, (1, block), 1))
+           + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1))
     off = jnp.minimum(off, total - 1)          # clamp tail; caller masks
     vals, vid32 = decode_axis_values(
         off, table_ref[...], shape=shape, strides=strides, n_var=n_var,
@@ -104,11 +104,10 @@ def grid_strides(shape) -> tuple:
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "shape", "n_var", "total", "chunk", "block_points", "interpret",
-    "idx_dtype"))
+    "shape", "n_var", "total", "chunk", "block_points", "interpret"))
 def grid_decode(tables: jax.Array, start, *, shape, n_var: int, total: int,
                 chunk: int, block_points: int = 4096,
-                interpret: bool = None, idx_dtype=jnp.int32):
+                interpret: bool = None):
     """Decode flat stream indices ``[start, start + chunk)`` on device.
 
     ``tables`` is the ``(V, n_axes, Lmax)`` f32 axis-value bank (axis
@@ -127,12 +126,12 @@ def grid_decode(tables: jax.Array, start, *, shape, n_var: int, total: int,
     interpret = resolve_interpret(interpret)
     table2 = jnp.transpose(tables, (1, 0, 2)).reshape(
         n_axes, n_variants * lmax).astype(jnp.float32)
-    start2 = jnp.asarray(start, idx_dtype).reshape(1, 1)
+    start2 = jnp.asarray(start, jnp.int32).reshape(1, 1)
     vals, vid = pl.pallas_call(
         functools.partial(
             _decode_kernel, shape=tuple(shape), strides=grid_strides(shape),
-            n_var=n_var, total=total, block=bp, idx_dtype=idx_dtype,
-            n_variants=n_variants, lmax=lmax, gather=interpret),
+            n_var=n_var, total=total, block=bp, n_variants=n_variants,
+            lmax=lmax, gather=interpret),
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((1, 1), lambda i: (0, 0)),

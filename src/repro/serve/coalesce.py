@@ -53,7 +53,6 @@ class PreparedRequest:
     chunk: int                   #: device-divisible, span-clamped
     s_len: int                   #: scan length (chunks per dispatch)
     cpv: int                     #: chunk ordinals per variant
-    wide: bool                   #: int64 index lane
     prep: _StreamPrep            #: hoisted lowering/bank/tables
 
     @property
@@ -84,7 +83,7 @@ def prepare_request(space, *, k: int, metric: str, backend: str,
     return PreparedRequest(
         space=space, k=int(k), metric=metric, backend=backend,
         block_points=int(block_points), chunk=chunk, s_len=s_len,
-        cpv=cpv, wide=prep.total + chunk >= 2 ** 31, prep=prep)
+        cpv=cpv, prep=prep)
 
 
 def compat_key(pr: PreparedRequest, mesh) -> tuple:
@@ -94,7 +93,7 @@ def compat_key(pr: PreparedRequest, mesh) -> tuple:
     return ("serve", pr.backend, _mesh_key(mesh), pr.chunk, pr.metric,
             pr.k, pr.block_points, tuple(pr.prep.bank.dims),
             tuple(pr.prep.vgrids[0].shape), pr.prep.n_var,
-            pr.prep.lmax, pr.s_len, pr.cpv, pr.wide)
+            pr.prep.lmax, pr.s_len, pr.cpv)
 
 
 def _ordinal_span(o0: int, o1: int, *, cpv: int, n_var: int,

@@ -18,8 +18,8 @@ and attributes, so a profile taken with ``jax.profiler.trace`` holds
 the program's spans on its host plane, on the profiler's clock: a
 record lines up with its annotation up to one constant offset.
 
-``count(name, n)`` adds to a counter of the innermost open span's root
-and to the process totals (:func:`counters`).  JAX's compile events are
+``count(name, n)`` adds to a counter of the innermost open span, of its
+root and of the process totals (:func:`counters`).  JAX's compile events are
 counted the same way, and also on the innermost open span, which is how
 a recompile is named: ``compile.trace_s`` (jaxpr tracing),
 ``compile.lower_s`` (lowering to MLIR, Pallas to Mosaic included),
@@ -50,8 +50,10 @@ import jax
 
 #: roots kept, oldest dropped first
 MAX_ROOTS = 256
-#: span records kept per root (a 1e9-point sweep records about 250)
-MAX_SPANS_PER_ROOT = 1024
+#: span records kept per root (a 1e9-point sweep records about 250, a
+#: campaign call about 30 a 2**26-point shard: 3.3e9 points, 50 shards,
+#: about 1,500)
+MAX_SPANS_PER_ROOT = 4096
 
 #: JAX compile events and the counters they add to
 _COMPILE_EVENTS = {
@@ -94,7 +96,7 @@ class _Root:
         self.threads: List[int] = []
         #: a span's attributes, or None for none
         self.attrs: List[Optional[Dict]] = []
-        #: span index -> the compile counters charged to that span
+        #: span index -> the counters charged to that span
         self.span_counters: Dict[int, Dict[str, float]] = {}
         self.counters: Dict[str, float] = {}
         self.dropped = 0
@@ -242,14 +244,18 @@ def traced(name: str) -> Callable:
 
 
 def count(name: str, n: float = 1) -> None:
-    """Add ``n`` to the counter ``name`` of the innermost open span's
-    root, and to the process totals."""
+    """Add ``n`` to the counter ``name`` of the innermost open span, of
+    its root, and of the process totals."""
     sp = current()
     with _lock:
         _totals[name] = _totals.get(name, 0) + n
         if sp is not None:
-            c = sp._rec.counters
-            c[name] = c.get(name, 0) + n
+            targets = [sp._rec.counters]
+            if sp._index >= 0:
+                targets.append(sp._rec.span_counters.setdefault(
+                    sp._index, {}))
+            for c in targets:
+                c[name] = c.get(name, 0) + n
 
 
 def counters() -> Dict[str, float]:

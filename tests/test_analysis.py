@@ -106,9 +106,9 @@ def test_mutated_adc_col_fails_coverage(tmp_path):
 def test_mutated_scan_body_item_is_flagged(tmp_path):
     sweep = tmp_path / "shard_sweep.py"
     src = open(f"{SRC}/core/shard_sweep.py").read()
-    needle = "vi = c // cpv"
+    needle = "v = c // cpv"
     assert needle in src
-    sweep.write_text(src.replace(needle, "vi = c.item() // cpv"))
+    sweep.write_text(src.replace(needle, "v = c.item() // cpv"))
 
     findings = analyze_paths([str(sweep)], rules=["hot-host-sync"])
     assert [f.rule for f in findings] == ["hot-host-sync"]
@@ -149,11 +149,13 @@ def test_mutated_xla_lane_item_is_flagged(tmp_path):
 def test_relayout_inside_scan_driver_is_flagged(tmp_path):
     sweep = tmp_path / "shard_sweep.py"
     src = open(f"{SRC}/core/shard_sweep.py").read()
-    needle = "def superchunk(c0, low, hi, c_hi, table2, bank_arrays, state):"
+    needle = ("def superchunk(c0, lows, limits, c_hi, table2, bank_arrays, "
+              "state):")
     assert needle in src
     sweep.write_text(src.replace(
         needle,
-        "def superchunk(c0, low, hi, c_hi, tables, bank_arrays, state):\n"
+        "def superchunk(c0, lows, limits, c_hi, tables, bank_arrays, "
+        "state):\n"
         "        table2 = jnp.transpose(tables, (1, 0, 2)).reshape(\n"
         "            tables.shape[1], -1).astype(jnp.float32)"))
     findings = analyze_paths([str(sweep)],

@@ -323,10 +323,11 @@ def test_backend_parity_property():
 
 
 @pytest.mark.slow
-def test_backend_xla_int64_widened_window():
-    """The XLA lane honors the `total + chunk >= 2**31` int64 widening:
-    a tail slice inside the int32 danger window must match the Pallas
-    lane bit-for-bit instead of wrapping flat indices negative."""
+def test_backend_xla_variant_just_under_int32():
+    """The XLA lane on one variant of 2**31 - 2 points, the largest the
+    int32 offsets hold: a tail slice must match the Pallas lane
+    bit-for-bit, its chunk's offsets past 2**31 (wrapped negative: 24
+    does not divide 2**31) masked."""
     from repro.core.shard_sweep import sweep_stream
     grids = {"variant": ["3d_in"],
              "cis_node": list(np.linspace(28.0, 130.0, 1057)),
@@ -334,10 +335,10 @@ def test_backend_xla_int64_widened_window():
              "frame_rate": list(np.linspace(15.0, 120.0, 341)),
              "active_fraction_scale": list(np.linspace(0.1, 1.0, 331))}
     total = 1057 * 18 * 341 * 331
-    assert total == 2 ** 31 - 2            # in the int32 danger window
-    xla = sweep_stream("edgaze", grids, chunk_size=16, k=3,
+    assert total == 2 ** 31 - 2            # one variant, just under
+    xla = sweep_stream("edgaze", grids, chunk_size=24, k=3,
                        index_range=(total - 6, total), backend="xla")
-    pal = sweep_stream("edgaze", grids, chunk_size=16, k=3,
+    pal = sweep_stream("edgaze", grids, chunk_size=24, k=3,
                        index_range=(total - 6, total), backend="pallas")
     assert xla.n_points == pal.n_points == 6
     assert total - 6 <= xla.topk[0]["index"] < total
@@ -488,7 +489,7 @@ def test_megakernel_decode_matches_host_grid(case):
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    from repro.core.shard_sweep import _prepare_stream
+    from repro.core.shard_sweep import _prepare_stream, split_index_range
     from repro.core.sweep import AXES
     from repro.kernels.fused_sweep import decode_block
     from repro.kernels.grid_decode import grid_strides
@@ -504,7 +505,7 @@ def test_megakernel_decode_matches_host_grid(case):
     def kernel(bounds_ref, tab_ref, vals_ref, valid_ref):
         valid, vals = decode_block(
             bounds_ref, tab_ref, shape=shape, strides=grid_strides(shape),
-            lmax=lmax, chunk=shard, block=block, idx_dtype=jnp.int32)
+            lmax=lmax, chunk=shard, block=block)
         for a in range(n_axes):
             vals_ref[a, :] = vals[a]
         valid_ref[0, :] = valid.astype(jnp.int32)
@@ -524,31 +525,32 @@ def test_megakernel_decode_matches_host_grid(case):
     lo, hi = kw.get("index_range", (0, total))
     cpv = -(-n_var // chunk)
     seen = np.zeros(total, np.int64)
-    # every chunk ordinal and shard, with the bounds the superchunk step
-    # derives for them
-    for c in range(prep.n_variants * cpv):
-        vi, r = divmod(c, cpv)
+    # every chunk ordinal and shard of every variant segment, with the
+    # variant-local bounds the superchunk step derives for them
+    for vi, vlo, vhi in split_index_range(lo, hi, n_var):
         base = vi * n_var
-        start, limit = base + r * chunk, min(hi, base + n_var)
         table = prep.table2[:, vi * lmax:(vi + 1) * lmax]
-        for six in range(ndev):
-            s0 = start + six * shard
-            vals, valid = decode(
-                jnp.asarray([s0, lo, limit], jnp.int32), table)
-            vals = np.asarray(vals)[:, :shard]
-            valid = np.asarray(valid)[0, :shard].astype(bool)
-            off = s0 + np.arange(shard)
-            want = (off >= lo) & (off < limit)
-            np.testing.assert_array_equal(valid, want, err_msg=(c, six))
-            if not want.any():
-                continue
-            seen[off[want]] += 1
-            host = prep.vgrids[vi].chunk(int(off[want][0]) - base,
-                                         int(off[want][-1]) + 1 - base)
-            for a, name in enumerate(AXES):
-                np.testing.assert_array_equal(
-                    vals[a, want], host[name].astype(np.float32),
-                    err_msg=f"{name} in chunk {c}, shard {six}")
+        for c in range(cpv):
+            for six in range(ndev):
+                s0 = c * chunk + six * shard
+                vals, valid = decode(
+                    jnp.asarray([s0, vlo, vhi], jnp.int32), table)
+                vals = np.asarray(vals)[:, :shard]
+                valid = np.asarray(valid)[0, :shard].astype(bool)
+                off = s0 + np.arange(shard)
+                want = (off >= vlo) & (off < vhi)
+                np.testing.assert_array_equal(valid, want,
+                                              err_msg=(vi, c, six))
+                if not want.any():
+                    continue
+                seen[base + off[want]] += 1
+                host = prep.vgrids[vi].chunk(int(off[want][0]),
+                                             int(off[want][-1]) + 1)
+                for a, name in enumerate(AXES):
+                    np.testing.assert_array_equal(
+                        vals[a, want], host[name].astype(np.float32),
+                        err_msg=f"{name} in variant {vi} chunk {c}, "
+                                f"shard {six}")
     assert (seen[lo:hi] == 1).all()
     assert not seen[:lo].any() and not seen[hi:].any()
 

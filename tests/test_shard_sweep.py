@@ -262,35 +262,44 @@ def test_stream_index_range_partitions_compose():
                                [r["total_j"] for r in full.topk], rtol=0)
 
 
-@pytest.mark.slow
-@pytest.mark.parametrize("engine", ["fused", "staged"])
-def test_stream_int64_indices_beyond_int32_ceiling(engine):
-    """>=2**31-point grids stream with int64 flat indices instead of
-    raising (ISSUE 3 regression); verified on a tail slice whose global
-    indices exceed int32, against the per-plan batched oracle — for both
-    the megakernel scan engine and the staged oracle (ISSUE 4)."""
+def _batch_oracle(variant, grids, local):
+    """The per-plan batched evaluator at one variant-local index."""
     from repro.core.batch import evaluate_batch, make_points
-    from repro.core.shard_sweep import sweep_stream
     from repro.core.sweep import _normalize_grids, lower_variant, \
         variant_grid
-    grids = {"variant": ["3d_in"],
+    plan = lower_variant("edgaze", variant)
+    _variants, ngrids = _normalize_grids("edgaze", dict(grids))
+    point = variant_grid(plan, ngrids).point(local)
+    return evaluate_batch(plan, make_points(
+        plan, 1, **{ax: [val] for ax, val in point.items()}))
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("engine", ["fused", "staged"])
+def test_stream_past_int32_ceiling(engine):
+    """A space of more than 2**32 points, each variant under 2**31,
+    streams on int32 (variant, offset) indices (ISSUE 3 regression): a
+    window whose global indices pass 2**32 is cut into its variant's
+    segment, and its winner matches the per-plan batched oracle — on
+    the megakernel scan engine and the staged oracle alike."""
+    from repro.core.shard_sweep import sweep_stream
+    variants = ["2d_in", "2d_off", "3d_in", "3d_in_stt", "2d_in_mixed"]
+    grids = {"variant": variants,
              "cis_node": list(np.linspace(28.0, 130.0, 1500)),
              "frame_rate": list(np.linspace(15.0, 120.0, 1500)),
-             "active_fraction_scale": list(np.linspace(0.1, 1.0, 1000))}
-    total = 1500 * 1500 * 1000
-    assert total >= 2 ** 31
+             "active_fraction_scale": list(np.linspace(0.1, 1.0, 400))}
+    n_var = 1500 * 1500 * 400
+    total = len(variants) * n_var
+    assert n_var < 2 ** 31 and total >= 2 ** 32
     st = sweep_stream("edgaze", grids, chunk_size=64, k=4,
                       index_range=(total - 150, total), engine=engine)
     assert st.n_points == 150
-    assert st.summaries["3d_in"]["n"] == 150
+    assert st.summaries["2d_in_mixed"]["n"] == 150
+    assert sum(s["n"] for s in st.summaries.values()) == 150
     row = st.topk[0]
-    flat = row["index"]                    # single variant: local == flat
-    assert flat >= 2 ** 31
-    plan = lower_variant("edgaze", "3d_in")
-    _variants, ngrids = _normalize_grids("edgaze", dict(grids))
-    point = variant_grid(plan, ngrids).point(flat)
-    ref = evaluate_batch(plan, make_points(
-        plan, 1, **{ax: [val] for ax, val in point.items()}))
+    assert row["variant"] == "2d_in_mixed"
+    assert (len(variants) - 1) * n_var + row["index"] >= 2 ** 32
+    ref = _batch_oracle("2d_in_mixed", grids, row["index"])
     np.testing.assert_allclose(ref["total_j"][0], row["total_j"],
                                rtol=1e-6)
 
@@ -298,9 +307,10 @@ def test_stream_int64_indices_beyond_int32_ceiling(engine):
 @pytest.mark.parametrize("campaign", [False, True])
 def test_wide_grid_on_compiled_pallas_raises(monkeypatch, tmp_path,
                                              campaign):
-    """On a TPU the compiled megakernel cannot hold int64 indices: a
-    >=2**31-point explore() (or campaign) refuses before compiling and
-    names ROADMAP B1, instead of falling back to another lane."""
+    """One variant of 2**31 points or more cannot be held as an int32
+    offset: explore() (or a campaign) refuses it before compiling, on
+    the compiled Pallas lane as on every other, instead of falling back
+    to another lane."""
     from repro.core.shard_sweep import stream_cache_info
     from repro.explore import DesignSpace, explore
     from repro.kernels import runtime
@@ -313,41 +323,37 @@ def test_wide_grid_on_compiled_pallas_raises(monkeypatch, tmp_path,
              "active_fraction_scale": list(np.linspace(0.1, 1.0, 1000))}
     before = stream_cache_info()["step_compiles"]
     kw = dict(checkpoint_dir=str(tmp_path / "c")) if campaign else {}
-    with pytest.raises(NotImplementedError, match="B1"):
+    with pytest.raises(NotImplementedError,
+                       match=r"spans 2250000000 points.*fewer than 2\*\*31"):
         explore(DesignSpace("edgaze", grids), engine="fused", k=4, **kw)
     assert stream_cache_info()["step_compiles"] == before
 
 
 @pytest.mark.slow
 @pytest.mark.parametrize("engine", ["fused", "staged"])
-def test_stream_int32_boundary_window_widens(engine):
-    """total just BELOW 2**31 but with the last chunk overshooting it
-    must widen to int64 too: int32 flat math wraps negative inside the
-    tail chunk and the wrapped points sneak past the validity mask
-    (regression for the `total + chunk >= 2**31` widen condition)."""
-    from repro.core.batch import evaluate_batch, make_points
+def test_stream_variant_just_under_int32(engine):
+    """One variant of 2**31 - 2 points, the largest kind the int32
+    offsets hold: a window at its end streams without widening, and the
+    chunk that runs past 2**31 wraps its offsets negative, which the
+    masks drop (a 24-point chunk does not divide 2**31, so on both
+    engines the window's chunk passes it), instead of letting them sneak
+    past as valid points."""
     from repro.core.shard_sweep import sweep_stream
-    from repro.core.sweep import _normalize_grids, lower_variant, \
-        variant_grid
     grids = {"variant": ["3d_in"],
              "cis_node": list(np.linspace(28.0, 130.0, 1057)),
              "sys_rows": list(np.linspace(4.0, 128.0, 18)),
              "frame_rate": list(np.linspace(15.0, 120.0, 341)),
              "active_fraction_scale": list(np.linspace(0.1, 1.0, 331))}
     total = 1057 * 18 * 341 * 331
-    assert total == 2 ** 31 - 2            # in the int32 danger window
-    st = sweep_stream("edgaze", grids, chunk_size=16, k=3,
+    assert total == 2 ** 31 - 2            # one variant, just under
+    st = sweep_stream("edgaze", grids, chunk_size=24, k=3,
                       index_range=(total - 6, total), engine=engine)
     assert st.n_points == 6
     assert st.summaries["3d_in"]["n"] == 6
     assert st.n_feasible <= 6              # wrapped garbage would exceed
     row = st.topk[0]
     assert total - 6 <= row["index"] < total
-    plan = lower_variant("edgaze", "3d_in")
-    _variants, ngrids = _normalize_grids("edgaze", dict(grids))
-    point = variant_grid(plan, ngrids).point(row["index"])
-    ref = evaluate_batch(plan, make_points(
-        plan, 1, **{ax: [val] for ax, val in point.items()}))
+    ref = _batch_oracle("3d_in", grids, row["index"])
     np.testing.assert_allclose(ref["total_j"][0], row["total_j"],
                                rtol=1e-6)
 

@@ -37,6 +37,7 @@ CHUNK, K, DEPTH = 3, 4, 2
 FUSED_TREE = {
     "sweep": "explore",
     "sweep.prep": "sweep", "sweep.step": "sweep",
+    "sweep.split": "sweep.prep",
     "sweep.dispatch": "sweep", "sweep.finalize": "sweep",
     "step.lower": "sweep.step", "step.compile": "sweep.step",
     "step.warm": "sweep.step", "sweep.pace": "sweep.dispatch",
@@ -165,6 +166,35 @@ def _killed_and_resumed(space, mesh, directory):
     return killed, _last("resume"), res
 
 
+@pytest.mark.parametrize("engine", ["fused", "staged"])
+def test_split_span_counts_segments_and_slots(space, mesh, engine):
+    """``sweep.split`` times the host's cut of the range into per-variant
+    segments, under ``sweep.prep``, and carries its counters: segments,
+    scan slots dispatched and the dead ones among them."""
+    # 9 points a variant in 3 chunks; [4, 14) meets variant 0 at chunk
+    # ordinals 1-2 and variant 1 at 3-4
+    kw = dict(superchunk=3) if engine == "fused" else {}
+    res = explore(space, k=K, chunk_size=CHUNK, mesh=mesh, engine=engine,
+                  index_range=(4, 14), **kw)
+    root = _last("explore")
+    split = _one(root, "sweep.split")
+    assert _one(root, "sweep.prep")["id"] == split["parent"]
+    if engine == "fused":
+        # 4 live chunks in 2 dispatches of 3 slots
+        want = {"sweep.segments": 2, "sweep.slots": 6,
+                "sweep.dead_slots": 2}
+        assert res.dispatches * res.superchunk == 6
+    else:
+        # the staged engine dispatches each variant's chunks from its
+        # segment's start: 2 + 2, none dead
+        want = {"sweep.segments": 2, "sweep.slots": 4,
+                "sweep.dead_slots": 0}
+        assert res.dispatches == 4
+    assert split["counters"] == want
+    for key, n in want.items():
+        assert root["counters"][key] == n
+
+
 def test_campaign_ckpt_writes_under_shard_roots(space, mesh, tmp_path):
     killed, resumed, res = _killed_and_resumed(space, mesh,
                                                str(tmp_path / "c"))
@@ -180,9 +210,14 @@ def test_campaign_ckpt_writes_under_shard_roots(space, mesh, tmp_path):
             assert (shard["attrs"]["lo"], shard["attrs"]["hi"]) == \
                 (w["attrs"]["lo"], w["attrs"]["hi"])
         for s in _named(root, "campaign.shard"):
-            assert len([c for c in root["spans"]
-                        if c["parent"] == s["id"]
-                        and c["name"] == "sweep"]) == 1
+            sweeps = [c for c in root["spans"]
+                      if c["parent"] == s["id"] and c["name"] == "sweep"]
+            assert len(sweeps) == 1
+            # every shard's range is cut into its variants' segments
+            split, = [c for c in _named(root, "sweep.split")
+                      if by_id[c["parent"]]["parent"] == sweeps[0]["id"]]
+            assert split["counters"]["sweep.segments"] >= 1
+            assert split["counters"]["sweep.slots"] >= 1
     run = _one(resumed, "campaign.run")
     assert run["attrs"] == {"resumed": True}
     for name in ("campaign.plan", "campaign.load", "campaign.prep",
@@ -323,7 +358,8 @@ def test_stream_cache_info_keeps_its_keys():
 SPAN_READERS = ("fetch_ms.sweep", "regather_ms.sweep",
                 "dispatch_host_us.sweep", "step_lower_s",
                 "step_backend_s", "setup_program_s",
-                "shard_finalize_ms.campaign", "campaign_fixed_ms.campaign")
+                "shard_finalize_ms.campaign", "campaign_fixed_ms.campaign",
+                "dead_slot_pct.campaign")
 
 
 @pytest.fixture(scope="module")
@@ -354,8 +390,9 @@ class _Roots:
         self.roots.append(r)
         return r
 
-    def add(self, root, name, start, end, parent=None):
+    def add(self, root, name, start, end, parent=None, counters=None):
         s = self._new(name, start, end, (parent or root)["id"])
+        s["counters"] = dict(counters or {})
         root["spans"].append(s)
         return s
 
@@ -382,11 +419,14 @@ def _sweep_recorder():
 
 def _campaign_recorder():
     """A set-up call and one kill-and-resume cycle: 2 shards before the
-    kill, 3 after."""
+    kill, 3 after, each of 32 scan slots with 0, 12, 4, 0 and 8 dead."""
     rec = _Roots()
     setup = rec.root("explore", 0, 8000)
     rec.add(setup, "step.lower", 10, 4010)
     rec.add(setup, "step.compile", 4010, 5510)
+    rec.add(setup, "sweep.split", 5510, 5511,
+            counters={"sweep.slots": 16, "sweep.dead_slots": 15})
+    dead = iter((0, 12, 4, 0, 8))
     for name, start, end, shards in (("explore", 10000, 10100, 2),
                                      ("resume", 10200, 10400, 3)):
         r = rec.root(name, start, end)
@@ -394,6 +434,10 @@ def _campaign_recorder():
             s0 = start + 10 + 30 * j
             sh = rec.add(r, "campaign.shard", s0, s0 + 30)
             sw = rec.add(r, "sweep", s0, s0 + 30, sh)
+            prep = rec.add(r, "sweep.prep", s0, s0 + 2, sw)
+            rec.add(r, "sweep.split", s0 + 1, s0 + 2, prep,
+                    counters={"sweep.segments": 1, "sweep.slots": 32,
+                              "sweep.dead_slots": next(dead)})
             rec.add(r, "sweep.finalize", s0 + 20, s0 + 30, sw)
     return rec.roots, {"cycles": [{}]}
 
@@ -410,6 +454,9 @@ SYNTHETIC = {
     "shard_finalize_ms.campaign": (_campaign_recorder, 10.0),
     # (100 - 60) + (200 - 90) ms outside the shards, one cycle
     "campaign_fixed_ms.campaign": (_campaign_recorder, 150.0),
+    # 24 of the window shards' 160 slots dead (the set-up's split is not
+    # a shard's)
+    "dead_slot_pct.campaign": (_campaign_recorder, 15.0),
 }
 
 

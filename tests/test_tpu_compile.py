@@ -14,6 +14,7 @@ one process may load the TPU library, and each test worker imports every
 test file.
 """
 import os
+import re
 import sys
 
 import jax
@@ -48,6 +49,13 @@ STUDY_GRIDS = {
     "active_fraction_scale": list(np.linspace(0.1, 1.0, 16)),
     "pixel_pitch_um": list(np.linspace(2.0, 6.0, 16)),
 }
+#: the widths of the camj-wide space (``configs/camj-wide.json``):
+#: camj-study's with 24 values on sys_rows, sys_cols and frame_rate,
+#: 414,056,448 points a variant and 3,312,451,584 in all, past 2**31
+WIDE_GRIDS = dict(STUDY_GRIDS,
+                  sys_rows=list(np.linspace(4.0, 128.0, 24)),
+                  sys_cols=list(np.linspace(4.0, 128.0, 24)),
+                  frame_rate=list(np.linspace(15.0, 240.0, 24)))
 #: grids and metric of each width the rehearsals compile at
 WIDTHS = {"mega": (MEGA_GRIDS, "total_j"),
           "camj-study": (STUDY_GRIDS, "density_mw_mm2")}
@@ -104,7 +112,7 @@ def steer_tpu(monkeypatch):
     monkeypatch.setattr(runtime, "_BACKEND_IS_TPU", True)
 
 
-def _kernel(prep, idx_dtype, metric="total_j"):
+def _kernel(prep, metric="total_j"):
     from repro.core.batch import build_coeff_compute
     from repro.core.sweep import AXES
     from repro.kernels.fused_sweep import fused_sweep_block
@@ -115,13 +123,13 @@ def _kernel(prep, idx_dtype, metric="total_j"):
             table, row, start, low, limit, compute=compute,
             metric=metric, axis_names=tuple(AXES),
             shape=tuple(prep.vgrids[0].shape), chunk=CHUNK,
-            block_points=BLOCK, kk=K, idx_dtype=idx_dtype, interpret=False)
+            block_points=BLOCK, kk=K, interpret=False)
     return f
 
 
-def _kernel_args(prep, sharding, idx_dtype):
+def _kernel_args(prep, sharding):
     width = prep.bank.arrays["fused"].shape[1]
-    scalar = jax.ShapeDtypeStruct((), idx_dtype, sharding=sharding)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=sharding)
     return (jax.ShapeDtypeStruct((prep.table2.shape[0], prep.lmax),
                                  jnp.float32, sharding=sharding),
             jax.ShapeDtypeStruct((1, width), jnp.float32,
@@ -133,20 +141,66 @@ def _kernel_args(prep, sharding, idx_dtype):
 def test_megakernel_compiles_for_v5e(preps, width, one_chip,
                                      no_persistent_cache):
     prep = preps[width]
-    compiled = jax.jit(_kernel(prep, jnp.int32, WIDTHS[width][1])).lower(
-        *_kernel_args(prep, one_chip, jnp.int32)).compile()
+    compiled = jax.jit(_kernel(prep, WIDTHS[width][1])).lower(
+        *_kernel_args(prep, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_megakernel_refuses_int64_indices(prep, one_chip,
-                                          no_persistent_cache):
-    """Mosaic holds no 64-bit values: the >=2**31-point index path cannot
-    compile for the chip (ROADMAP B1), which is why the sweep refuses
-    such grids on a TPU before tracing."""
-    with jax.enable_x64(True):
-        lowered = jax.jit(_kernel(prep, jnp.int64))
-        with pytest.raises(NotImplementedError, match="64-bit"):
-            lowered.lower(*_kernel_args(prep, one_chip, jnp.int64))
+def _lower_step(prep, mesh, metric):
+    """The superchunk scan step of the sweep as ``explore()`` builds it
+    on a TPU, lowered for ``mesh``."""
+    from repro.core.shard_sweep import (_DEFAULT_SUPERCHUNK, _fused_step,
+                                        _init_banked_state)
+    superchunk, out_keys = _fused_step(
+        prep.bank, mesh, metric, K, CHUNK, BLOCK, prep.vgrids[0].shape,
+        prep.lmax, _DEFAULT_SUPERCHUNK, -(-prep.n_var // CHUNK),
+        backend="pallas")
+    rep = NamedSharding(mesh, P())
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(jnp.shape(x), jnp.asarray(x).dtype,
+                                    sharding=rep)
+    state0 = _init_banked_state(K, len(out_keys), prep.n_variants,
+                                with_out=False)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)
+    bounds = jax.ShapeDtypeStruct((prep.n_variants,), jnp.int32,
+                                  sharding=rep)
+    return jax.jit(superchunk, donate_argnums=(6,)).lower(
+        scalar, bounds, bounds, scalar, spec(prep.table2),
+        jax.tree.map(spec, prep.bank.arrays), jax.tree.map(spec, state0))
+
+
+def test_wide_space_step_holds_no_64bit_value(topo, steer_tpu,
+                                              no_persistent_cache):
+    """A camj-wide space (3.3e9 points, each variant under 2**31) runs
+    on the same int32 superchunk step as any other: it compiles for the
+    chip with no 64-bit integer anywhere in the program, while a space
+    with ONE variant of 2**31 points or more is refused before tracing
+    (ROADMAP B1's limit)."""
+    from repro.core.shard_sweep import _prepare_stream, stream_cache_info
+    from repro.explore import DesignSpace, explore
+    prep = _prepare_stream(["edgaze", "rhythmic"], WIDE_GRIDS)
+    assert prep.n_var == 414_056_448 and prep.n_variants == 8
+    assert prep.total == 3_312_451_584 > 2 ** 31
+    mesh = Mesh(np.array(topo.devices[:1]), ("batch",),
+                axis_types=auto_axis_types(1))
+    lowered = _lower_step(prep, mesh, "density_mw_mm2")
+    # no 64-bit integer value: none in the lowered module's tensors, none
+    # in the compiled program's shapes
+    assert not re.search(r"tensor<(?:[0-9?]+x)*u?i64>", lowered.as_text())
+    hlo = lowered.compile().as_text()
+    assert "tpu_custom_call" in hlo and KERNEL_NAME in hlo
+    assert not re.search(r"\b[su]64\[", hlo)
+
+    before = stream_cache_info()["step_compiles"]
+    one_variant = dict(WIDE_GRIDS, variant=["3d_in"],
+                       active_fraction_scale=list(np.linspace(0.1, 1.0,
+                                                              83)))
+    space = DesignSpace("edgaze", one_variant)
+    assert space.n_var >= 2 ** 31
+    with pytest.raises(NotImplementedError, match=r"fewer than 2\*\*31"):
+        explore(space, engine="fused", k=K)
+    assert stream_cache_info()["step_compiles"] == before
 
 
 @pytest.mark.parametrize("width", sorted(WIDTHS))
@@ -155,29 +209,10 @@ def test_superchunk_step_compiles_for_v5e(preps, width, topo, n_chips,
                                           steer_tpu, no_persistent_cache):
     """The whole superchunk scan step of the sweep, on a mesh of the
     described chips, as ``explore()`` builds it on a TPU."""
-    from repro.core.shard_sweep import (_DEFAULT_SUPERCHUNK, _fused_step,
-                                        _init_banked_state)
-    prep = preps[width]
     mesh = Mesh(np.array(topo.devices[:n_chips]), ("batch",),
                 axis_types=auto_axis_types(1))
-    cpv = -(-prep.n_var // CHUNK)
-    superchunk, out_keys = _fused_step(
-        prep.bank, mesh, WIDTHS[width][1], K, CHUNK, BLOCK,
-        prep.vgrids[0].shape, prep.n_var, prep.lmax, jnp.int32,
-        _DEFAULT_SUPERCHUNK, cpv, backend="pallas")
-    rep = NamedSharding(mesh, P())
-
-    def spec(x):
-        return jax.ShapeDtypeStruct(jnp.shape(x), jnp.asarray(x).dtype,
-                                    sharding=rep)
-    state0 = _init_banked_state(K, len(out_keys), prep.n_variants,
-                                jnp.int32, with_out=False)
-    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)
-    compiled = jax.jit(superchunk, donate_argnums=(6,)).lower(
-        scalar, scalar, scalar, scalar, spec(prep.table2),
-        jax.tree.map(spec, prep.bank.arrays),
-        jax.tree.map(spec, state0)).compile()
-    hlo = compiled.as_text()
+    hlo = _lower_step(preps[width], mesh, WIDTHS[width][1]).compile(
+    ).as_text()
     assert "tpu_custom_call" in hlo
     # the profile reduction can find the megakernel by its name
     assert KERNEL_NAME in hlo
