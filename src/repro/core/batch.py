@@ -13,10 +13,11 @@ least specialized:
   traced ``PlanBank`` row (``plan_bank.bank_layout``), same per-point
   arithmetic ``vmap``-ed; one executable serves every variant;
 * ``build_coeff_compute`` — the coefficient-form BLOCK compute: the same
-  banked physics vectorized ``(slots, B)`` with kernel-legal primitives
-  only, callable from inside a Pallas kernel body — this is what the
-  fused mega-sweep megakernel (``repro.kernels.fused_sweep``) evaluates
-  so per-point intermediates never reach HBM.
+  banked physics on points of any shape, one value per slot and scalar
+  coefficients, with kernel-legal primitives only, callable from inside
+  a Pallas kernel body — this is what the fused mega-sweep megakernel
+  (``repro.kernels.fused_sweep``) evaluates so per-point intermediates
+  never reach HBM.
 
 Numerics note: evaluation runs in f32 on device (the scalar oracle is
 f64 Python); per-plan parity holds to ~1e-5 relative vs the oracle, and
@@ -25,6 +26,7 @@ in tests.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, NamedTuple, Optional, Sequence
 
@@ -44,9 +46,8 @@ from .constants import (MIPI_CSI2_ENERGY_PER_BYTE, DYNAMIC_ENERGY_SCALE,
 from .fom import fom_table_points
 from .plan import CATEGORIES, EnergyPlan, _EXTRA_CACHES
 
-#: matmuls that stand in for gathers and segment sums must not round
-#: their f32 operands: at default precision a TPU multiplies in one bf16
-#: pass, while HIGHEST reproduces every f32 one-hot product exactly
+#: the banked evaluator's category matmul must not round its f32
+#: operands: at default precision a TPU multiplies in one bf16 pass
 _EXACT = jax.lax.Precision.HIGHEST
 
 
@@ -603,7 +604,7 @@ def _piecewise_interp(x, xs, ys):
     for i in range(len(xs) - 1):
         t = (x - xs[i]) / (xs[i + 1] - xs[i])
         seg = ys[i] + t * (ys[i + 1] - ys[i])
-        y = jnp.where((x >= xs[i]) & (x < xs[i + 1]), seg, y)
+        y = jax.lax.select((x >= xs[i]) & (x < xs[i + 1]), seg, y)
     return jnp.where(x >= xs[-1], ys[-1], y)
 
 
@@ -621,51 +622,71 @@ def _make_fom_interp():
     return lambda rate: 10.0 ** _piecewise_interp(jnp.log10(rate), xs, ys)
 
 
-def _take_rows(x, idx, n, exact: bool):
-    """Gather rows ``x[idx]`` of the (n, B) slab; one-hot matmul when the
-    compiled Mosaic path cannot lower a dynamic gather."""
-    if exact:
-        return jnp.take(x, idx, axis=0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], n), 1)
-    onehot = (idx[:, None] == lane).astype(jnp.float32)
-    return jnp.dot(onehot, x, precision=_EXACT)
+def _pick_slot(slots, idx):
+    """``slots[idx]`` for a traced scalar slot index (an exact small
+    float): a select chain over the static slots, which copies the chosen
+    value exactly."""
+    out = slots[0]
+    for a in range(1, len(slots)):
+        out = jnp.where(idx == a, slots[a], out)
+    return out
 
 
-def _scatter_add_rows(x, idx, n, exact: bool):
-    """Scatter-add the (m, B) rows of ``x`` into an (n, B) zero slab at
-    ``idx`` (duplicates sum); transposed one-hot matmul when compiled."""
-    if exact:
-        return jnp.zeros((n, x.shape[1]), jnp.float32).at[idx].add(x)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], n), 1)
-    onehot = (idx[:, None] == lane).astype(jnp.float32)
-    return jnp.dot(onehot.T, x, precision=_EXACT)
+def _scatter_add_slots(slots, idx, terms):
+    """``slots[a] + (the sum of the terms whose slot index is a)`` for
+    every slot ``a``, the terms summed in their order: the arithmetic of
+    a scatter-add into zeros, as masked sums over the static slots."""
+    out = []
+    for a, base in enumerate(slots):
+        acc = None
+        for i, term in zip(idx, terms):
+            t = jnp.where(i == a, term, 0.0)
+            acc = t if acc is None else acc + t
+        out.append(base + acc)
+    return out
 
 
-def build_coeff_compute(dims, *, exact: bool = True):
-    """The banked Eqs. 1-17 physics as ONE block-vectorized function
+def _over_slots(fn, slots):
+    """``[fn(s) for s in slots]`` for an elementwise ``fn``, traced once:
+    ``fn`` runs on the slots stacked along a new leading axis, so each
+    slot stays a whole tile and each point's arithmetic is unchanged."""
+    if not slots:
+        return []
+    out = fn(jnp.stack(slots))
+    return [out[i] for i in range(len(slots))]
+
+
+def build_coeff_compute(dims):
+    """The banked Eqs. 1-17 physics as ONE point-vectorized function
     callable from inside a Pallas kernel body.
 
-    Returns ``compute(row, pt) -> {name: (B,) array}`` where ``row`` is a
-    variant's fused ``(W,)`` coefficient row (``plan_bank.bank_layout``)
-    and ``pt`` maps every :data:`repro.core.sweep.AXES` name to a ``(B,)``
-    value vector (``mem_tech`` as its numeric code).  Unlike the vmap-ed
-    :func:`build_banked_eval` path, intermediates are laid out
-    ``(slots, B)`` with explicit broadcasting and no per-point batching
-    transform, so the whole computation stays legal inside a kernel: the
+    Returns ``compute(row, pt, keys=OUT_KEYS) -> {key: array of the
+    points' shape}`` for the output ``keys`` asked for; a category row
+    that none of them needs is not traced.  ``row`` is a variant's fused ``(W,)`` coefficient row
+    (``plan_bank.bank_layout``), anything that a static int indexes to
+    an f32 scalar: an array, or the kernel's SMEM ref.  ``pt`` maps every
+    :data:`repro.core.sweep.AXES` name to an f32 array of one shape, any
+    shape (``mem_tech`` as its numeric code): ``(B,)`` in the XLA twin,
+    a dense ``(8, B/8)`` tile in the megakernel.  Every slot (analog,
+    digital, memory, unit, category row) is its own value of that shape
+    in a static Python list and every coefficient a scalar, so
+    cross-slot reductions are elementwise folds and a traced slot index
+    picks among the slots by selects.  Unlike the vmap-ed
+    :func:`build_banked_eval` path there is no batching transform and no
+    gather, so the whole computation stays legal inside a kernel: the
     fused mega-sweep kernel (``repro.kernels.fused_sweep``) evaluates a
     block of decoded points without the ``(n_axes, B)`` point matrix or
     the ``B x n_out`` output table ever reaching HBM.
 
-    ``exact=True`` (the Pallas-interpreter / plain-jnp path) uses the
-    very same gather / scatter-add / ``jnp.interp`` ops as the staged
-    evaluator, so outputs match it to f32 elementwise roundoff;
-    ``exact=False`` swaps those for one-hot matmuls and a static
-    piecewise unroll that the compiled Mosaic path can lower.
-    The output schema is exactly :data:`OUT_KEYS`.
+    Each point runs the banked evaluator's arithmetic in its order, so
+    outputs match it to f32 elementwise roundoff (the node interpolation
+    is a static piecewise unroll, see :func:`_piecewise_interp`).  The
+    output schema is exactly :data:`OUT_KEYS`.
     """
     from .plan_bank import bank_layout
     V, A, L, F, D, M = dims
     n_c = len(CATEGORIES)
+    n_w = n_c + 2
     layout = bank_layout(dims)
 
     dyn_scale = _make_scale_interp(DYNAMIC_ENERGY_SCALE)
@@ -673,174 +694,171 @@ def build_coeff_compute(dims, *, exact: bool = True):
     hp_scale = _make_scale_interp(SRAM_HP_LEAKAGE_PER_BIT)
     walden = _make_fom_interp()
 
-    def compute(row, pt):
-        g = row_getter(row, layout)
+    def compute(row, pt, keys=OUT_KEYS):
+        def g(name, i=0):
+            # entry i of a coefficient slot (row-major), as a scalar
+            return row[layout[name][0] + i]
 
-        def flat(name):
-            # a matrix coefficient as its flat row-major slice: a kernel
-            # cannot reshape a 1-D vector into a 2-D one
-            off, shape = layout[name]
-            return row[off:off + int(np.prod(shape))]
-
-        b = pt["frame_rate"].shape[0]
-        cis = pt["cis_node"][None, :]
-        soc = pt["soc_node"][None, :]
+        shape = pt["frame_rate"].shape
+        cis, soc = pt["cis_node"], pt["soc_node"]
 
         def node_for(role, declared):
-            r = role[:, None]
-            return jnp.where(r == 0, cis,
-                             jnp.where(r == 1, soc, declared[:, None]))
+            return jnp.where(role == 0, cis,
+                             jnp.where(role == 1, soc, declared))
 
         frame_time = 1.0 / pt["frame_rate"]
-        # axis-registry coefficient hooks, (1, B)-oriented for the block
-        # layout; same arithmetic order as the vmap evaluators
-        dyn_v = _VDD_HOOKS["dynamic"](pt["vdd_scale"])[None, :]
-        stat_v = _VDD_HOOKS["static"](pt["vdd_scale"])[None, :]
+        # axis-registry coefficient hooks; same arithmetic order as the
+        # vmap evaluators
+        dyn_v = _VDD_HOOKS["dynamic"](pt["vdd_scale"])
+        stat_v = _VDD_HOOKS["static"](pt["vdd_scale"])
 
         # ----- Sec. 4.1 digital timing over padded slots ------------------
         if D:
-            thr = ((pt["sys_rows"] * pt["sys_cols"])[None, :]
-                   * g("d_util")[:, None])
-            cycles = jnp.where(
-                g("d_is_sys")[:, None] > 0.5,
-                jnp.ceil(g("d_macs")[:, None] / thr)
-                + (pt["sys_rows"] + pt["sys_cols"])[None, :],
-                g("d_cycles")[:, None])
-            durs = cycles / g("d_clock")[:, None]            # (D, B)
-            edge_w = flat("d_edge_w")
-            # the mask stays f32 until each scalar is read out: a kernel
-            # can only extract 32-bit scalars from a vector
-            edge_m = flat("d_edge_mask")
+            sq = pt["sys_rows"] * pt["sys_cols"]
+            fill = pt["sys_rows"] + pt["sys_cols"]
+            durs = []
+            for i in range(D):
+                cycles = jnp.where(
+                    g("d_is_sys", i) > 0.5,
+                    jnp.ceil(g("d_macs", i) / (sq * g("d_util", i)))
+                    + fill, g("d_cycles", i))
+                durs.append(cycles / g("d_clock", i))
             starts = []
             for i in range(D):      # static unroll; DAG edges go backward
-                s_i = jnp.zeros((b,), jnp.float32)
+                s_i = jnp.zeros(shape, jnp.float32)
                 for j in range(i):
                     s_i = jnp.maximum(s_i, jnp.where(
-                        edge_m[i * D + j] > 0.5,
-                        starts[j] + edge_w[i * D + j] * durs[j],
+                        g("d_edge_mask", i * D + j) > 0.5,
+                        starts[j] + g("d_edge_w", i * D + j) * durs[j],
                         0.0))
                 starts.append(s_i)
-            starts = jnp.stack(starts)                       # (D, B)
-            ends = starts + durs
-            dv = g("d_valid")[:, None] > 0.5
-            t_d = (jnp.max(jnp.where(dv, ends, -jnp.inf), axis=0)
-                   - jnp.min(jnp.where(dv, starts, jnp.inf), axis=0))
-            t_d = jnp.where(jnp.any(dv), t_d, 0.0)
+            last = jnp.full(shape, -jnp.inf, jnp.float32)
+            first = jnp.full(shape, jnp.inf, jnp.float32)
+            for i in range(D):
+                dv = g("d_valid", i) > 0.5
+                last = jnp.maximum(last, jnp.where(
+                    dv, starts[i] + durs[i], -jnp.inf))
+                first = jnp.minimum(first, jnp.where(dv, starts[i],
+                                                     jnp.inf))
+                any_dv = dv if i == 0 else any_dv | dv
+            t_d = jnp.where(any_dv, last - first, 0.0)
         else:
-            t_d = jnp.zeros((b,), jnp.float32)
+            t_d = jnp.zeros(shape, jnp.float32)
         t_a = (frame_time - t_d) / g("n_phases")
         feasible = t_a > 0.0
 
-        rows = []
+        units = []
 
         # ----- analog rows (Eqs. 2-13) ------------------------------------
         if A:
-            pad = t_a[None, :] * g("a_pad_coeff")[:, None]   # (A, B)
-            e_access = jnp.broadcast_to(g("a_const")[:, None],
-                                        (A, b)) * dyn_v
+            pad = [t_a * g("a_pad_coeff", a) for a in range(A)]
+            e_access = [g("a_const", a) * dyn_v for a in range(A)]
             if L:
-                la = g("lin_arr").astype(jnp.int32)
-                t_cell = jnp.maximum(
-                    _take_rows(pad, la, A, exact) * g("lin_inv")[:, None],
-                    1e-12)
-                e_access = e_access + _scatter_add_rows(
-                    g("lin_coeff")[:, None] * t_cell * stat_v, la, A,
-                    exact)
+                la = [g("lin_arr", i) for i in range(L)]
+                e_access = _scatter_add_slots(e_access, la, [
+                    g("lin_coeff", i) * jnp.maximum(
+                        _pick_slot(pad, la[i]) * g("lin_inv", i), 1e-12)
+                    * stat_v for i in range(L)])
             if F:
-                fa = g("fom_arr").astype(jnp.int32)
-                t_cell = jnp.maximum(
-                    _take_rows(pad, fa, A, exact) * g("fom_inv")[:, None],
-                    1e-12)
-                fom = walden(1.0 / t_cell)
-                fom = fom * _ADC_HOOK(pt["adc_bits"][None, :],
-                                      g(_ADC_REF_COL)[:, None])
-                e_access = e_access + _scatter_add_rows(
-                    g("fom_scale")[:, None] * fom * dyn_v, fa, A, exact)
-            rows.append(e_access * g("a_ops")[:, None])
+                fa = [g("fom_arr", i) for i in range(F)]
+                rate = [1.0 / jnp.maximum(
+                    _pick_slot(pad, fa[i]) * g("fom_inv", i), 1e-12)
+                    for i in range(F)]
+                terms = []
+                for i, fom in enumerate(_over_slots(walden, rate)):
+                    fom = fom * _ADC_HOOK(pt["adc_bits"],
+                                          g(_ADC_REF_COL, i))
+                    terms.append(g("fom_scale", i) * fom * dyn_v)
+                e_access = _scatter_add_slots(e_access, fa, terms)
+            units += [e_access[a] * g("a_ops", a) for a in range(A)]
 
         # ----- digital compute rows (Eqs. 14-15) --------------------------
-        if D:
-            node_u = node_for(g("d_role"), g("d_node"))
-            s_u = dyn_scale(node_u)
-            rows.append(g("d_dyn")[:, None] * s_u * dyn_v
-                        + g("d_static")[:, None] * durs * stat_v)
+        # every slot's node scaling in one pass over the stacked nodes
+        node_m = [node_for(g("m_role", m), g("m_node", m)) for m in range(M)]
+        scale = _over_slots(dyn_scale, [
+            node_for(g("d_role", i), g("d_node", i)) for i in range(D)]
+            + node_m)
+        for i in range(D):
+            units.append(g("d_dyn", i) * scale[i] * dyn_v
+                         + g("d_static", i) * durs[i] * stat_v)
 
         # ----- memory rows (Eq. 16) ---------------------------------------
-        if M:
-            node_m = node_for(g("m_role"), g("m_node"))
-            s_m = dyn_scale(node_m)
-            mt = pt["mem_tech"].astype(jnp.float32)[None, :]
-            tech = jnp.where(mt >= 0, jnp.broadcast_to(mt, (M, b)),
-                             g("m_tech")[:, None])
+        mt = pt["mem_tech"].astype(jnp.float32)
+        reads_div = jnp.maximum(pt["sys_rows"], 1.0)
+        hp_m = _over_slots(hp_scale, node_m)
+        leak_m = _over_slots(leak_scale, node_m)
+        for m in range(M):
+            s_m = scale[D + m]
+            tech = jnp.where(mt >= 0, mt, g("m_tech", m))
             is_stt = tech == 2
-            bits = g("m_bits_pa")[:, None]
+            bits = g("m_bits_pa", m)
             sram_access = (SRAM_ACCESS_ENERGY_PER_BIT_65 * bits
-                           * g("m_size_f")[:, None]) * s_m
+                           * g("m_size_f", m)) * s_m
             read_e = jnp.where(is_stt,
                                STT_READ_ENERGY_PER_BIT_65 * bits * s_m,
                                sram_access)
             write_e = jnp.where(is_stt,
                                 STT_WRITE_ENERGY_PER_BIT_65 * bits * s_m,
                                 sram_access)
-            read_e = jnp.where(jnp.isnan(g("m_read_x")[:, None]),
-                               read_e, g("m_read_x")[:, None])
-            write_e = jnp.where(jnp.isnan(g("m_write_x")[:, None]),
-                                write_e, g("m_write_x")[:, None])
+            read_x, write_x = g("m_read_x", m), g("m_write_x", m)
+            read_e = jnp.where(jnp.isnan(read_x), read_e, read_x)
+            write_e = jnp.where(jnp.isnan(write_x), write_e, write_x)
             leak_bit = jnp.where(
                 is_stt, jnp.float32(STT_LEAKAGE_PER_BIT),
-                jnp.where(tech == 1, hp_scale(node_m),
-                          leak_scale(node_m)))
-            leak = leak_bit * g("m_bits_total")[:, None]
-            leak = jnp.where(jnp.isnan(g("m_leak_x")[:, None]),
-                             leak, g("m_leak_x")[:, None])
-            reads = (g("m_reads_fixed")[:, None]
-                     + g("m_reads_dnn2")[:, None]
-                     / jnp.maximum(pt["sys_rows"], 1.0)[None, :])
-            alpha = (g("m_alpha")[:, None]
-                     * pt["active_fraction_scale"][None, :])
-            rows.append((read_e * reads
-                         + write_e * g("m_writes")[:, None]) * dyn_v
-                        + leak * frame_time[None, :] * alpha * stat_v)
+                jnp.where(tech == 1, hp_m[m], leak_m[m]))
+            leak = leak_bit * g("m_bits_total", m)
+            leak_x = g("m_leak_x", m)
+            leak = jnp.where(jnp.isnan(leak_x), leak, leak_x)
+            reads = g("m_reads_fixed", m) + g("m_reads_dnn2", m) / reads_div
+            alpha = g("m_alpha", m) * pt["active_fraction_scale"]
+            units.append((read_e * reads + write_e * g("m_writes", m))
+                         * dyn_v + leak * frame_time * alpha * stat_v)
 
         # ----- communication rows (Eq. 17) --------------------------------
-        rows.append(jnp.stack([
-            jnp.broadcast_to(g("utsv_bytes") * UTSV_ENERGY_PER_BYTE, (b,)),
-            jnp.broadcast_to(g("mipi_bytes") * MIPI_CSI2_ENERGY_PER_BYTE,
-                             (b,))]))
-        unit_e = jnp.concatenate(rows, axis=0)               # (U, B)
-        # category reduction (C+2, B) as one rank-1 update per unit, on
-        # the VPU in exact f32 (a TPU matmul at default precision would
-        # round the energies to bf16)
-        n_w = n_c + 2
-        w = flat("weights")
-        red = w[:n_w][:, None] * unit_e[0][None, :]
-        for u in range(1, unit_e.shape[0]):
-            red = red + w[u * n_w:(u + 1) * n_w][:, None] * unit_e[u][None, :]
+        units.append(jnp.broadcast_to(
+            g("utsv_bytes") * UTSV_ENERGY_PER_BYTE, shape))
+        units.append(jnp.broadcast_to(
+            g("mipi_bytes") * MIPI_CSI2_ENERGY_PER_BYTE, shape))
+        # category reduction: each of the C+2 rows accumulates one rank-1
+        # update per unit, on the VPU in exact f32 (a TPU matmul at
+        # default precision would round the energies to bf16); a row is
+        # traced only when an output asks for it
+        red = {}
+
+        def category(c):
+            if c not in red:
+                acc = g("weights", c) * units[0]
+                for u in range(1, len(units)):
+                    acc = acc + g("weights", u * n_w + c) * units[u]
+                red[c] = acc
+            return red[c]
 
         # ----- Sec. 6.2 power density -------------------------------------
         analog_area = g("n_pixels") * (pt["pixel_pitch_um"] * 1e-3) ** 2
-        if M:
-            node_area = node_for(g("m_area_role"), g("m_node"))
+        digital_area = jnp.zeros(shape, jnp.float32)
+        for m in range(M):
+            node_area = node_for(g("m_area_role", m), g("m_node", m))
             cell_area = 150.0 * (node_area * 1e-6) ** 2
-            digital_area = jnp.sum(g("m_bits_total")[:, None] * cell_area,
-                                   axis=0)
-        else:
-            digital_area = jnp.zeros((b,), jnp.float32)
+            term = g("m_bits_total", m) * cell_area
+            digital_area = term if m == 0 else digital_area + term
         area = jnp.where(g("stacked") > 0,
                          jnp.maximum(analog_area, digital_area),
                          analog_area + digital_area)
 
-        out = {f"cat_{c}_j": red[i] for i, c in enumerate(CATEGORIES)}
-        out["total_j"] = red[n_c]
-        out["on_sensor_j"] = red[n_c + 1]
-        out["t_d_s"] = t_d
-        out["t_a_s"] = t_a
-        out["feasible"] = feasible
-        out["area_mm2"] = area
-        out["power_mw"] = out["on_sensor_j"] * pt["frame_rate"] * 1e3
-        out["density_mw_mm2"] = out["power_mw"] / jnp.maximum(area, 1e-9)
-        assert set(out) == set(OUT_KEYS), (sorted(out), OUT_KEYS)
-        return out
+        def power():
+            return category(n_c + 1) * pt["frame_rate"] * 1e3
+
+        make = {f"cat_{c}_j": functools.partial(category, i)
+                for i, c in enumerate(CATEGORIES)}
+        make.update(
+            total_j=functools.partial(category, n_c),
+            on_sensor_j=functools.partial(category, n_c + 1),
+            t_d_s=lambda: t_d, t_a_s=lambda: t_a,
+            feasible=lambda: feasible, area_mm2=lambda: area,
+            power_mw=power,
+            density_mw_mm2=lambda: power() / jnp.maximum(area, 1e-9))
+        assert set(make) == set(OUT_KEYS), (sorted(make), OUT_KEYS)
+        return {key: make[key]() for key in keys}
 
     return compute
 

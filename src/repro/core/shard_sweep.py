@@ -79,7 +79,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..compat import shard_map
-from ..kernels.fused_sweep import fused_sweep_block
+from ..kernels.fused_sweep import fused_sweep_block, kernel_block
 from ..kernels.fused_sweep_xla import fused_sweep_block_xla
 from ..kernels.grid_decode import grid_decode
 from ..kernels.runtime import (resolve_backend, resolve_interpret,
@@ -566,20 +566,18 @@ def _fused_step(bank: PlanBank, mesh, metric: str, k: int, chunk: int,
     ndev = int(mesh.devices.size)
     assert chunk % ndev == 0, (chunk, ndev)
     shard = chunk // ndev
+    compute = build_coeff_compute(bank.dims)
     if backend == "xla":
         # XLA fuses across block boundaries itself; bp only bounds the
-        # top_k reduction width.  The jnp lane always uses exact gathers
-        # (the physics' one-hot matmul forms are a Mosaic-only idiom).
+        # top_k reduction width
         bp = max(min(block_points, shard), 1)
-        compute = build_coeff_compute(bank.dims, exact=True)
         block_fn = fused_sweep_block_xla
     else:
         interpret = resolve_interpret(None)
         # one kernel block per shard on the interpreter (grid steps only
         # add emulation overhead there); compiled Mosaic tiles by
-        # block_points
-        bp = shard if interpret else max(min(block_points, shard), 1)
-        compute = build_coeff_compute(bank.dims, exact=interpret)
+        # block_points; either rounds up to whole tile rows
+        bp = kernel_block(shard if interpret else block_points, shard)
         block_fn = functools.partial(fused_sweep_block, interpret=interpret)
     kk = min(k, shard)
     out_keys = list(OUT_KEYS)

@@ -9,10 +9,13 @@ point, so the sweep is bandwidth-bound: the staged path writes and
 re-reads ~100 B of HBM per design point that the reduction immediately
 collapses to O(k) scalars.
 
-This kernel fuses the whole per-chunk pipeline into ONE pass per block:
+This kernel fuses the whole per-chunk pipeline into ONE pass per block.
+A block of points is one dense ``(8, block / 8)`` tile (:data:`ROWS`
+rows, :func:`block_positions`), and every per-point value in the kernel
+has that shape, so each fills whole ``(8, 128)`` vregs:
 
 1. **decode** — the block's offsets inside its variant expand into
-   axis-value vectors in VMEM (:func:`decode_block`): static div/mod
+   axis-value tiles in VMEM (:func:`decode_block`): static div/mod
    against the grid strides gives each axis's index, and a chain of
    selects over that axis's static length picks its value out of the
    chunk variant's ``(n_axes, lmax)`` table, which rides in SMEM.
@@ -25,8 +28,10 @@ This kernel fuses the whole per-chunk pipeline into ONE pass per block:
    host grid keep the two from drifting;
 2. **evaluate** — the banked Eq. 1-17 physics runs on the decoded block
    through the coefficient-form compute function
-   (``repro.core.batch.build_coeff_compute``), the chunk's fused ``(W,)``
-   coefficient row broadcasting across the block;
+   (``repro.core.batch.build_coeff_compute``): one tile per slot, each
+   coefficient a scalar read from the chunk's fused ``(W,)`` row in SMEM
+   and broadcast across the tile, a traced slot index resolved by
+   selects, so the kernel body holds no matmul;
 3. **reduce** — the block folds to its masked metric sum / feasible
    count and its k smallest candidates (iterative min-extract, branchless
    — ``lax.top_k`` has no Mosaic lowering) before anything is written.
@@ -38,18 +43,18 @@ in a tiny O(k) second pass at sweep finalization.
 
 Masking follows the streaming driver's contract: a point is valid iff
 ``low <= off < limit`` AND it lies inside this call's ``chunk`` span
-(blocks are padded up to ``block_points``; the spillover positions would
-otherwise double-count the next shard's points).  ``start``, ``low`` and
-``limit`` are int32 offsets inside the chunk's variant: the driver cuts a
-sweep into per-variant segments on the host, so a space may pass 2**31
-points while every value the kernel holds stays int32.
+(blocks are padded up to :func:`kernel_block`; the spillover positions
+would otherwise double-count the next shard's points).  ``start``,
+``low`` and ``limit`` are int32 offsets inside the chunk's variant: the
+driver cuts a sweep into per-variant segments on the host, so a space
+may pass 2**31 points while every value the kernel holds stays int32.
 
 Mosaic's rules shape the layout: ``start`` / ``low`` / ``limit`` arrive
-as one SMEM vector and the chunk's axis table as another (its scalars
-broadcast into the selects), each block writes whole ``(1, n)`` rows of
-``(G, 1, n)`` outputs (the block's trailing dims then equal the array's),
-and the kernel holds no 64-bit value.  ``tests/test_tpu_compile.py``
-compiles it for a TPU v5e.
+as one SMEM vector, the chunk's axis table and its coefficient row as two
+more (their scalars broadcast into the tiles), each block writes whole
+``(1, n)`` rows of ``(G, 1, n)`` outputs (the block's trailing dims then
+equal the array's), and the kernel holds no 64-bit value.
+``tests/test_tpu_compile.py`` compiles it for a TPU v5e.
 Compiled and interpreted kernels run the same decode, so the CPU tests
 exercise the code the chip runs.
 """
@@ -70,6 +75,29 @@ from .runtime import resolve_interpret
 KERNEL_NAME = "camj_megakernel"
 
 
+#: sublane rows of a block's tile: a block of ``block`` points is laid
+#: out as one dense ``(ROWS, block // ROWS)`` array, so a per-point value
+#: fills whole vregs instead of one sublane row of each
+ROWS = 8
+
+
+def kernel_block(block_points: int, chunk: int) -> int:
+    """The kernel's block size for a ``chunk``-point call: at most
+    ``block_points`` (and the chunk), rounded up to whole tile rows.
+    Positions past the chunk are masked, so rounding adds no point."""
+    bp = max(min(block_points, chunk), 1)
+    return -(-bp // ROWS) * ROWS
+
+
+def block_positions(block):
+    """Each tile element's position in its block: row ``r`` and column
+    ``c`` of the ``(ROWS, block // ROWS)`` tile hold ``r * (block //
+    ROWS) + c``."""
+    tile = (ROWS, block // ROWS)
+    return (jax.lax.broadcasted_iota(jnp.int32, tile, 0) * (block // ROWS)
+            + jax.lax.broadcasted_iota(jnp.int32, tile, 1))
+
+
 def decode_block(bounds_ref, tab_ref, *, shape, strides, lmax, chunk,
                  block):
     """The validity mask and decoded axis values of this grid step's block.
@@ -77,19 +105,19 @@ def decode_block(bounds_ref, tab_ref, *, shape, strides, lmax, chunk,
     ``bounds_ref`` holds ``start``, ``low`` and ``limit`` (int32 offsets
     inside the chunk's variant); ``tab_ref`` the chunk variant's axis
     table flattened to ``(n_axes * lmax,)`` (axis ``a`` holds its values
-    at ``a * lmax`` onwards; padding is never read).  Returns the
-    ``(block,)`` mask and a list of ``(block,)`` f32 vectors in
-    :class:`~repro.core.sweep.ChunkedGrid` axis order.  The axis index
-    ``(off // stride) % size`` stays in range past the variant's end
-    (masked points) without a clamp.  Each value is a chain of selects
-    over the axis's static length: an exact copy of a table entry.
+    at ``a * lmax`` onwards; padding is never read).  ``block`` is a
+    multiple of :data:`ROWS` (:func:`kernel_block`).  Returns the mask
+    and a list of f32 values in :class:`~repro.core.sweep.ChunkedGrid`
+    axis order, each a ``(ROWS, block // ROWS)`` tile laid out by
+    :func:`block_positions`.  The axis index ``(off // stride) % size``
+    stays in range past the variant's end (masked points) without a
+    clamp.  Each value is a chain of selects over the axis's static
+    length: an exact copy of a table entry.
     """
-    i = pl.program_id(0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
-    pos = i * block + lane                      # position within the chunk
-    off = bounds_ref[0] + pos
+    pos = pl.program_id(0) * block + block_positions(block)
+    off = bounds_ref[0] + pos                   # offset inside the variant
     valid = ((off >= bounds_ref[1]) & (off < bounds_ref[2])
-             & (pos < chunk))[0]
+             & (pos < chunk))
     # lax, not jnp: off >= 0, so truncating div/rem equal the floored
     # ones without their sign fix-ups, and the ~100 selects trace as bare
     # primitives (jnp's wrappers made them a visible share of set-up)
@@ -103,39 +131,40 @@ def decode_block(bounds_ref, tab_ref, *, shape, strides, lmax, chunk,
                 val = jax.lax.select(
                     jax.lax.eq(idx, jnp.int32(j)),
                     jax.lax.broadcast(tab_ref[a * lmax + j], off.shape), val)
-        vals.append(val[0])
+        vals.append(val)
     return valid, vals
 
 
 def _fused_kernel(bounds_ref, tab_ref, row_ref, cv_ref, cl_ref, st_ref,
                   *, compute, metric, axis_names, kk, block, **decode):
     valid, vals = decode_block(bounds_ref, tab_ref, block=block, **decode)
-    out = compute(row_ref[0, :], dict(zip(axis_names, vals)))
-    ok = (out["feasible"] & valid)[None, :]
-    mv = out[metric].astype(jnp.float32)[None, :]
+    out = compute(row_ref, dict(zip(axis_names, vals)),
+                  keys=(metric, "feasible"))
+    ok = out["feasible"] & valid
+    mv = out[metric].astype(jnp.float32)
 
     # block-local top-k by iterative min extraction: k is tiny and static.
     # Each pass takes the block minimum and the LOWEST position holding it
-    # (a min over a masked iota, which is also the tie rule of lax.top_k
-    # in the XLA twin), then masks that position; the winners accumulate
-    # into (1, kk) vectors by lane select, so the kernel stores whole
-    # vectors only
+    # (a min over the masked positions, which is also the tie rule of
+    # lax.top_k in the XLA twin), both reduced over the whole tile to
+    # (1, 1), then masks that position; the winners accumulate into
+    # (1, kk) vectors by lane select, so the kernel stores whole vectors
+    # only
     masked = jnp.where(ok, mv, jnp.inf)
-    posi = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+    posi = block_positions(block)
     slot = jax.lax.broadcasted_iota(jnp.int32, (1, kk), 1)
     cand_v = jnp.full((1, kk), jnp.inf, jnp.float32)
     cand_l = jnp.zeros((1, kk), jnp.int32)
     for j in range(kk):
-        m = jnp.min(masked, axis=1, keepdims=True)
-        am = jnp.min(jnp.where(masked == m, posi, block), axis=1,
-                     keepdims=True)
+        m = jnp.min(masked, keepdims=True)
+        am = jnp.min(jnp.where(masked == m, posi, block), keepdims=True)
         cand_v = jnp.where(slot == j, m, cand_v)
         cand_l = jnp.where(slot == j, am, cand_l)
         masked = jnp.where(posi == am, jnp.inf, masked)
     cv_ref[...] = cand_v
     cl_ref[...] = cand_l
-    s = jnp.sum(jnp.where(ok, mv, 0.0), axis=1, keepdims=True)
-    n = jnp.sum(ok.astype(jnp.float32), axis=1, keepdims=True)
+    s = jnp.sum(jnp.where(ok, mv, 0.0), keepdims=True)
+    n = jnp.sum(ok.astype(jnp.float32), keepdims=True)
     st_ref[...] = jnp.where(
         jax.lax.broadcasted_iota(jnp.int32, (1, 2), 1) == 0, s, n)
 
@@ -150,17 +179,17 @@ def fused_sweep_block(table: jax.Array, row: jax.Array, start, low, limit,
     table (axis ``a`` holds its first ``shape[a]`` entries; chunks are
     variant-uniform), ``row`` the variant's ``(1, W)`` fused coefficient
     row and ``compute`` the coefficient-form evaluator from
-    :func:`repro.core.batch.build_coeff_compute` (its ``exact`` flag must
-    match this call's resolved ``interpret`` mode).  Returns ``(cand_v,
-    cand_l, sums, counts)``: per-block ascending candidate metric values
-    ``(G, kk)`` (+inf-padded), their block-LOCAL int32 indices ``(G,
-    kk)`` (offset = ``start + g * block_points + cand_l``),
-    and the masked per-block metric sums / valid counts ``(G,)``.
+    :func:`repro.core.batch.build_coeff_compute`.  Blocks hold
+    ``bp = kernel_block(block_points, chunk)`` points.  Returns
+    ``(cand_v, cand_l, sums, counts)``: per-block ascending candidate
+    metric values ``(G, kk)`` (+inf-padded), their block-LOCAL int32
+    indices ``(G, kk)`` (offset = ``start + g * bp + cand_l``), and the
+    masked per-block metric sums / valid counts ``(G,)``.
     """
     n_axes, lmax = table.shape
     assert n_axes == len(shape) == len(axis_names), (table.shape, shape)
     assert max(shape) <= lmax, (table.shape, shape)
-    bp = max(min(block_points, chunk), 1)
+    bp = kernel_block(block_points, chunk)
     nb = -(-chunk // bp)
     interpret = resolve_interpret(interpret)
 
@@ -178,7 +207,7 @@ def fused_sweep_block(table: jax.Array, row: jax.Array, start, low, limit,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, row.shape[-1]), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((None, 1, kk), lambda i: (i, 0, 0)),
@@ -192,5 +221,5 @@ def fused_sweep_block(table: jax.Array, row: jax.Array, start, low, limit,
         ],
         interpret=interpret,
         name=KERNEL_NAME,
-    )(bounds, table.reshape(-1), row.reshape(1, -1))
+    )(bounds, table.reshape(-1), row.reshape(-1))
     return cv[:, 0], cl[:, 0], st[:, 0, 0], st[:, 0, 1]

@@ -13,8 +13,9 @@ body re-expressed in pure ``jnp``:
    (the kernel's select chain is the Mosaic form of that gather, and the
    parity tests hold the two together);
 2. **evaluate** — the same coefficient-form Eq. 1-17 compute function
-   from ``repro.core.batch.build_coeff_compute(dims, exact=True)``, the
-   chunk's fused ``(W,)`` coefficient row broadcasting across the block;
+   from ``repro.core.batch.build_coeff_compute(dims)`` on ``(B,)``
+   points (the kernel runs it on ``(8, B/8)`` tiles), the chunk's fused
+   ``(W,)`` coefficient row broadcasting across the block;
 3. **reduce** — per block of ``block_points``, masked metric sums /
    feasible counts and the ``kk`` smallest candidates via
    ``jax.lax.top_k`` (ties break to the LOWEST flat index, matching the
@@ -65,9 +66,9 @@ def fused_sweep_block_xla(table: jax.Array, row: jax.Array, start, low,
 
     Same signature and return contract as
     :func:`repro.kernels.fused_sweep.fused_sweep_block`, minus the
-    ``interpret=`` knob (XLA has no interpreter mode) — ``compute`` must
-    come from ``build_coeff_compute(dims, exact=True)`` (plain gathers;
-    the one-hot ``exact=False`` form is a Mosaic-only idiom).
+    ``interpret=`` knob (XLA has no interpreter mode), and its blocks
+    hold ``min(block_points, chunk)`` points, unrounded — ``compute``
+    comes from ``build_coeff_compute(dims)``.
     """
     n_axes, lmax = table.shape
     assert n_axes == len(shape) == len(axis_names), (table.shape, shape)
@@ -83,7 +84,8 @@ def fused_sweep_block_xla(table: jax.Array, row: jax.Array, start, low,
     vals = [jnp.take(table[a], (off[0] // stride) % n)
             for a, (n, stride) in enumerate(zip(shape,
                                                 grid_strides(shape)))]
-    out = compute(row.reshape(-1), dict(zip(axis_names, vals)))
+    out = compute(row.reshape(-1), dict(zip(axis_names, vals)),
+                  keys=(metric, "feasible"))
     ok = out["feasible"] & valid
     mv = out[metric].astype(jnp.float32)
 

@@ -255,6 +255,17 @@ _PARITY_CASES = {
                                    "mem_tech": ["sram_hp", "stt"]},
                             algorithm=["edgaze", "rhythmic"],
                             chunk_size=8, k=6),
+    # the kernel's block is one shard on the interpreter: a 100-point
+    # chunk is shorter than a block and no whole number of 8-point tile
+    # rows (rounded to 104, 4 positions masked), and its (8, 13) tile's
+    # rows are no whole number of 128-lane vregs; 135-point variants, so
+    # each second chunk is partly past its variant's end
+    "chunk_100": dict(grids={"variant": ["2d_in", "3d_in"],
+                             "cis_node": [130.0, 65.0, 28.0],
+                             "frame_rate": [15.0, 30.0, 60.0, 90.0, 120.0],
+                             "sys_rows": [8.0, 16.0, 32.0],
+                             "active_fraction_scale": [0.25, 0.5, 1.0]},
+                      chunk_size=100, k=9),
 }
 #: index_range cuts landing inside chunks and inside variants: the fused
 #: path masks a chunk's low side (ordinals are span-aligned, the staged
@@ -404,7 +415,9 @@ def test_backend_staged_rejects_explicit_xla():
 # ---------------------------------------------------------------------------
 # coefficient-form compute == banked vmap evaluator (direct, no driver)
 # ---------------------------------------------------------------------------
-def _coeff_compute_case(exact):
+def _coeff_compute_case(shape):
+    """The compute on points of ``shape`` against the banked evaluator,
+    and bit for bit against itself on the same points as a vector."""
     import jax.numpy as jnp
     from repro.core.batch import build_coeff_compute, make_points
     from repro.core.plan_bank import build_plan_bank, evaluate_bank
@@ -413,7 +426,7 @@ def _coeff_compute_case(exact):
              for v in ("2d_in", "3d_in", "2d_in_mixed")]
     bank = build_plan_bank(plans)
     rng = np.random.default_rng(5)
-    n = 96
+    n = int(np.prod(shape))
     pts = make_points(
         plans[0], n,
         cis_node=rng.choice([130.0, 65.0, 28.0], n),
@@ -424,29 +437,72 @@ def _coeff_compute_case(exact):
         frame_rate=rng.choice([15.0, 60.0, 240.0], n),
         active_fraction_scale=rng.choice([0.25, 1.0], n),
         pixel_pitch_um=rng.choice([2.0, 5.0], n))
-    compute = build_coeff_compute(bank.dims, exact=exact)
+    compute = build_coeff_compute(bank.dims)
     for vi in range(len(plans)):
         ref = evaluate_bank(bank, np.full(n, vi, np.int32), pts)
-        got = compute(bank.arrays["fused"][vi],
-                      {ax: jnp.asarray(getattr(pts, ax), jnp.float32)
-                       for ax in pts._fields})
+        row = bank.arrays["fused"][vi]
+        got = compute(row, {ax: jnp.asarray(getattr(pts, ax),
+                                            jnp.float32).reshape(shape)
+                            for ax in pts._fields})
+        flat = compute(row, {ax: jnp.asarray(getattr(pts, ax), jnp.float32)
+                             for ax in pts._fields})
         assert sorted(got) == sorted(ref)
         for key in ref:
-            np.testing.assert_allclose(np.asarray(got[key]), ref[key],
-                                       rtol=_REL, atol=0,
+            assert got[key].shape == shape, (key, got[key].shape)
+            np.testing.assert_array_equal(
+                np.asarray(got[key]).reshape(-1), np.asarray(flat[key]),
+                err_msg=(vi, key))
+            np.testing.assert_allclose(np.asarray(got[key]).reshape(-1),
+                                       ref[key], rtol=_REL, atol=0,
                                        err_msg=(vi, key))
 
 
 def test_coeff_compute_matches_banked_eval():
     """The kernel-body physics matches the staged vmap evaluator on a
-    random mixed batch for every output key."""
-    _coeff_compute_case(exact=True)
+    random mixed batch for every output key (the XLA twin's ``(B,)``
+    points)."""
+    _coeff_compute_case((96,))
 
 
-def test_coeff_compute_one_hot_form_matches_banked_eval():
-    """The compiled-kernel form (one-hot matmul gathers and scatters in
-    place of ``take`` / ``.at[].add``) meets the same parity."""
-    _coeff_compute_case(exact=False)
+def test_coeff_compute_on_tiles_matches_banked_eval():
+    """The same physics on the megakernel's dense ``(8, B/8)`` tiles
+    meets the same parity, and gives each point the bits it gets as a
+    ``(B,)`` vector."""
+    _coeff_compute_case((8, 12))
+
+
+def test_megakernel_body_holds_no_matmul():
+    """The compiled kernel's body (traced, not compiled) holds no
+    ``dot_general``: every slot lookup and the category reduction run on
+    the VPU, so no one-hot MXU matmul comes back."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.batch import build_coeff_compute
+    from repro.core.shard_sweep import _prepare_stream
+    from repro.core.sweep import AXES
+    from repro.kernels.fused_sweep import fused_sweep_block
+    prep = _prepare_stream(["edgaze", "rhythmic"], _DECODE_GRIDS)
+    compute = build_coeff_compute(prep.bank.dims)
+    table = prep.table2[:, :prep.lmax]
+    row = prep.bank.arrays["fused"][:1]
+    jaxpr = jax.make_jaxpr(lambda t, r: fused_sweep_block(
+        t, r, 0, 0, 30, compute=compute, metric="density_mw_mm2",
+        axis_names=tuple(AXES), shape=tuple(prep.vgrids[0].shape),
+        chunk=1 << 14, block_points=4096, kk=16, interpret=False))(
+        table, row)
+
+    def prims(jx):
+        for eqn in jx.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from prims(sub)
+
+    calls = [e for e in prims(jaxpr.jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    body = [e.primitive.name for e in prims(calls[0].params["jaxpr"])]
+    assert "select_n" in body and "reduce_min" in body
+    assert "dot_general" not in body
 
 
 # ---------------------------------------------------------------------------
@@ -484,43 +540,52 @@ def test_megakernel_decode_matches_host_grid(case):
     ``ChunkedGrid``'s values bit for bit at every valid position of
     every chunk and shard the superchunk step walks, for every variant
     of Ed-Gaze and Rhythmic, and marks each point of the range valid
-    exactly once."""
+    exactly once.  Each block is a ``(8, block / 8)`` tile, its block
+    size rounded up to whole rows (the positions past the shard are
+    invalid), read back in position order."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     from repro.core.shard_sweep import _prepare_stream, split_index_range
     from repro.core.sweep import AXES
-    from repro.kernels.fused_sweep import decode_block
+    from repro.kernels.fused_sweep import ROWS, decode_block, kernel_block
     from repro.kernels.grid_decode import grid_strides
     kw = _DECODE_CASES[case]
-    chunk, ndev, block = kw["chunk"], kw["ndev"], kw["block"]
+    chunk, ndev = kw["chunk"], kw["ndev"]
     prep = _prepare_stream(["edgaze", "rhythmic"], kw["grids"])
     assert prep.n_variants == 8
     assert list(prep.vgrids[0].names) == list(AXES)
     shape, n_var, lmax = tuple(prep.vgrids[0].shape), prep.n_var, prep.lmax
     total, n_axes, shard = prep.total, len(shape), chunk // ndev
-    nb = -(-shard // block)
+    block = kernel_block(kw["block"], shard)
+    nb, tile = -(-shard // block), (ROWS, block // ROWS)
 
     def kernel(bounds_ref, tab_ref, vals_ref, valid_ref):
         valid, vals = decode_block(
             bounds_ref, tab_ref, shape=shape, strides=grid_strides(shape),
             lmax=lmax, chunk=shard, block=block)
         for a in range(n_axes):
-            vals_ref[a, :] = vals[a]
-        valid_ref[0, :] = valid.astype(jnp.int32)
+            assert vals[a].shape == tile
+            vals_ref[a] = vals[a]
+        valid_ref[...] = valid.astype(jnp.int32)
 
     @jax.jit
     def decode(bounds, table):
-        return pl.pallas_call(
+        vals, valid = pl.pallas_call(
             kernel, grid=(nb,),
             in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] * 2,
-            out_specs=[pl.BlockSpec((n_axes, block), lambda i: (0, i)),
-                       pl.BlockSpec((1, block), lambda i: (0, i))],
+            out_specs=[
+                pl.BlockSpec((None, n_axes) + tile, lambda i: (i, 0, 0, 0)),
+                pl.BlockSpec((None,) + tile, lambda i: (i, 0, 0))],
             out_shape=[
-                jax.ShapeDtypeStruct((n_axes, nb * block), jnp.float32),
-                jax.ShapeDtypeStruct((1, nb * block), jnp.int32)],
+                jax.ShapeDtypeStruct((nb, n_axes) + tile, jnp.float32),
+                jax.ShapeDtypeStruct((nb,) + tile, jnp.int32)],
             interpret=True)(bounds, table.reshape(-1))
+        # tile element (r, c) of block g is position g * block + r *
+        # (block / ROWS) + c: row-major order is position order
+        return (vals.transpose(1, 0, 2, 3).reshape(n_axes, nb * block),
+                valid.reshape(nb * block))
 
     lo, hi = kw.get("index_range", (0, total))
     cpv = -(-n_var // chunk)
@@ -536,7 +601,9 @@ def test_megakernel_decode_matches_host_grid(case):
                 vals, valid = decode(
                     jnp.asarray([s0, vlo, vhi], jnp.int32), table)
                 vals = np.asarray(vals)[:, :shard]
-                valid = np.asarray(valid)[0, :shard].astype(bool)
+                valid = np.asarray(valid).astype(bool)
+                assert not valid[shard:].any(), (vi, c, six)
+                valid = valid[:shard]
                 off = s0 + np.arange(shard)
                 want = (off >= vlo) & (off < vhi)
                 np.testing.assert_array_equal(valid, want,

@@ -116,7 +116,7 @@ def _kernel(prep, metric="total_j"):
     from repro.core.batch import build_coeff_compute
     from repro.core.sweep import AXES
     from repro.kernels.fused_sweep import fused_sweep_block
-    compute = build_coeff_compute(prep.bank.dims, exact=False)
+    compute = build_coeff_compute(prep.bank.dims)
 
     def f(table, row, start, low, limit):
         return fused_sweep_block(
